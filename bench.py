@@ -16,25 +16,23 @@ downloads) and report:
   number measures; one-time jit compile is reported separately as
   compile_s and included in vs_baseline_with_compile),
 - test_auc: held-out AUC on a fresh 500K-row sample of the same
-  distribution (the HIGGS protocol holds out 500K of 11M),
-- example_auc: AUC on the reference's own bundled
-  examples/binary_classification task, trained at its documented
-  train.conf settings (100 trees, 63 leaves, feature_fraction 0.8,
-  bagging 0.8/5) and scored on its binary.test split — real-data
-  quality evidence at the reference's own example config.
+  distribution (the HIGGS protocol holds out 500K of 11M).
 
-Robustness contract with the driver:
+Contract with the driver:
+- it refuses to run (exit 2, no result line) unless JAX's backend is a
+  TPU; a run in which nothing completed, or whose held-out AUC fails
+  its sanity floor, exits non-zero,
 - a JSON line is printed even on SIGTERM/SIGALRM (partial=true marks
   results cut short; completed iterations extrapolate the rest),
-- the first `update()` on the measured booster pays the compile;
-  the jit cache persists across processes via
-  jax_compilation_cache_dir=.jax_cache, so repeat runs skip compile.
+- the first `update()` on the measured booster pays the compile; the
+  compile cache is the one directory of lightgbm_tpu/compile/cachedir.py
+  ($JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache).
 
 Env knobs: BENCH_ROWS (default 10_485_760), BENCH_ITERS (default 500),
 BENCH_BUDGET_S (default 420), BENCH_LEAVES/BENCH_BIN (default 255),
-BENCH_EXAMPLE=0 to skip the real-data example run, BENCH_BIN63=0 to
-skip the max_bin=63 sidecar (written to BENCH_BIN63.json next to this
-file when budget allows — same one-line schema, never on stdout),
+BENCH_BIN63=0 to skip the max_bin=63 sidecar (written to
+BENCH_BIN63.json next to this file when budget allows — same one-line
+schema, never on stdout),
 BENCH_WIDE=0 to skip the wide-sparse sidecar (BENCH_WIDE.json — the
 Allstate-family one-hot shape driving the multival histogram layout;
 BENCH_WIDE_ROWS/BENCH_WIDE_VARS/BENCH_WIDE_ITERS size it,
@@ -71,6 +69,8 @@ import time
 
 import numpy as np
 
+from chip_smoke import auc as _auc, make_higgs_like
+
 ROWS = int(os.environ.get("BENCH_ROWS", 10_485_760))
 COLS = 28
 ITERS = int(os.environ.get("BENCH_ITERS", 500))
@@ -79,7 +79,6 @@ MAX_BIN = int(os.environ.get("BENCH_BIN", 255))
 BUDGET = float(os.environ.get("BENCH_BUDGET_S", 420))
 BASELINE_SECONDS = 130.094
 TEST_ROWS = 500_000
-REF_EXAMPLE = "/root/reference/examples/binary_classification"
 
 T0 = time.time()
 QUANT = os.environ.get("BENCH_QUANT", "0") != "0"
@@ -87,8 +86,7 @@ QUANT_BINS = int(os.environ.get("BENCH_QUANT_BINS", 64))
 TRACE = os.environ.get("BENCH_TRACE", "")
 STATE = {"compile_s": None, "train_s": None, "train_iters": 0,
          "iters_done": 0, "iter_times": [], "test_auc": None,
-         "example_auc": None, "predict_us_per_row": None,
-         "example_auc_reference": None, "hist_method": None,
+         "predict_us_per_row": None, "hist_method": None,
          "hot_loop_syncs": None, "overlap_share": None,
          "blocking_syncs_per_iter": None, "hist_layout": None,
          "row_nnz_mean": None, "obs_overhead_pct": None}
@@ -98,15 +96,14 @@ STATE = {"compile_s": None, "train_s": None, "train_iters": 0,
 REGISTRY = None
 
 
-def emit(partial: bool) -> None:
-    """Print the one-line JSON result from whatever has been measured."""
+def emit(partial: bool) -> bool:
+    """Print the one-line JSON result from whatever has been measured.
+    False (and no result line) when nothing was."""
     it = STATE["iter_times"]
     if STATE["compile_s"] is None and not it and STATE["train_s"] is None:
-        print(json.dumps({
-            "metric": "higgs_train_wallclock", "value": -1.0,
-            "unit": "seconds", "vs_baseline": 0.0, "partial": True,
-            "note": "nothing completed within budget"}), flush=True)
-        return
+        print("# nothing completed within budget: no result",
+              file=sys.stderr, flush=True)
+        return False
     compile_s = STATE["compile_s"] or 0.0
     # train_s covers train_iters SYNCED iterations (the first iteration
     # rode with the compile; queued-but-unconfirmed dispatches are not
@@ -138,18 +135,6 @@ def emit(partial: bool) -> None:
         # batch-predict throughput of the trained 500-tree model on the
         # held-out rows (models/pathforest.py MXU traversal)
         out["predict_us_per_row"] = round(STATE["predict_us_per_row"], 3)
-    if STATE["example_auc"] is not None:
-        out["example_auc"] = round(STATE["example_auc"], 5)
-        # real data: reference examples/binary_classification trained at
-        # its own train.conf (100 trees, 63 leaves, ff 0.8, bagging
-        # 0.8/5, min_data 50, min_hess 5.0), scored on binary.test.
-        # The measured comparator from the out-of-tree cmake build of
-        # the reference CLI on the same conf is recorded in
-        # docs/REFERENCE_COMPARATOR.json (stochastic conf: both sides
-        # sit inside each other's seed spread; deterministic variants
-        # agree to the 3rd-6th decimal)
-        out["example_conf"] = "reference train.conf, 7000 train/500 test"
-        out["example_auc_reference_measured"] = 0.831562
     if REGISTRY is not None:
         out.update(REGISTRY.bench_fields())
     try:
@@ -239,61 +224,11 @@ def emit(partial: bool) -> None:
           f"leaves={LEAVES} bin={MAX_BIN} compile={compile_s:.1f}s "
           f"train={train_s:.1f}s total_wall={time.time() - T0:.1f}s",
           file=sys.stderr)
+    return True
 
 
 def _on_signal(signum, frame):
-    emit(partial=True)
-    os._exit(0)
-
-
-def make_higgs_like(n, f, seed=0, scale=2.4):
-    """Synthetic stand-in calibrated to real HIGGS difficulty.
-
-    Labels are DRAWN from p = sigmoid(s(x)) with s standardized to
-    `scale`, giving a Bayes-optimal AUC of ~0.875 (measured on 400k
-    samples) — so held-out AUC is discriminative the way real HIGGS is
-    (reference reports 0.845724 after 500 iters, Experiments.rst:134;
-    our model reaches ~0.857 at 300 iters/1M rows). The round-3
-    generator saturated at AUC 0.98, where a broken split search could
-    hide; on this one it visibly loses."""
-    rng = np.random.RandomState(seed)
-    X = rng.randn(n, f).astype(np.float32)
-    s = (0.9 * X[:, 0] - 0.8 * X[:, 1] + 1.1 * X[:, 2] * X[:, 3]
-         + 0.8 * np.sin(2 * X[:, 4]) * X[:, 5] + 0.6 * (X[:, 6] ** 2 - 1)
-         + 0.7 * X[:, 7] * X[:, 8] * X[:, 9]
-         + 0.5 * np.tanh(X[:, 10]) * X[:, 11])
-    s = (s - s.mean()) / s.std() * scale
-    y = (rng.rand(n) < 1.0 / (1.0 + np.exp(-s))).astype(np.float32)
-    return X, y
-
-
-def _auc(y, p):
-    order = np.argsort(-p)
-    yy = y[order] > 0
-    pos, neg = yy.sum(), len(yy) - yy.sum()
-    ranks = np.arange(1, len(yy) + 1)
-    return float(1.0 - (np.sum(ranks[yy]) - pos * (pos + 1) / 2)
-                 / (pos * neg))
-
-
-def run_reference_example(lgb):
-    """Train the reference's bundled binary_classification example at its
-    documented train.conf settings; AUC on its test split."""
-    import pandas as pd
-    tr = pd.read_csv(f"{REF_EXAMPLE}/binary.train", sep="\t",
-                     header=None).values
-    te = pd.read_csv(f"{REF_EXAMPLE}/binary.test", sep="\t",
-                     header=None).values
-    params = {  # examples/binary_classification/train.conf
-        "objective": "binary", "max_bin": 255, "num_leaves": 63,
-        "learning_rate": 0.1, "feature_fraction": 0.8,
-        "bagging_freq": 5, "bagging_fraction": 0.8,
-        "min_data_in_leaf": 50, "min_sum_hessian_in_leaf": 5.0,
-        "verbose": -1,
-    }
-    bst = lgb.train(params, lgb.Dataset(tr[:, 1:], label=tr[:, 0]),
-                    num_boost_round=100)
-    return _auc(te[:, 0], bst.predict(te[:, 1:]))
+    os._exit(0 if emit(partial=True) else 1)
 
 
 def run_bin63_sidecar(lgb, X, y):
@@ -506,16 +441,12 @@ def main():
     # hard-stop safety only; the loop below self-limits to the budget
     signal.alarm(max(60, int(BUDGET * 2)))
 
-    # persistent jit cache: repeat runs (and the driver's run after this
-    # one) skip XLA compilation entirely
     import jax
-    cache_dir = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                             ".jax_cache")
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:
-        pass
+    if jax.default_backend() != "tpu":
+        print(f"# bench.py measures the chip; JAX initialised the "
+              f"{jax.default_backend()} backend — refusing to run",
+              file=sys.stderr)
+        sys.exit(2)
 
     import lightgbm_tpu as lgb
 
@@ -537,24 +468,8 @@ def main():
 
     # ONE draw of the generating function; the last TEST_ROWS are held
     # out (a different seed would draw different weights — a different
-    # concept — making held-out AUC meaningless). The draw is cached on
-    # disk: generation costs ~35-45 s of single-core host time per run,
-    # which is budget the 500-iteration contract needs (the generator
-    # is deterministic, so the cache changes nothing but wall-clock)
-    cache_np = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                            ".bench_cache",
-                            f"higgs_{ROWS + TEST_ROWS}x{COLS}_v2.npz")
-    if os.path.exists(cache_np):
-        blob = np.load(cache_np)
-        X_all, y_all = blob["X"], blob["y"]
-    else:
-        X_all, y_all = make_higgs_like(ROWS + TEST_ROWS, COLS)
-        try:
-            os.makedirs(os.path.dirname(cache_np), exist_ok=True)
-            np.savez(cache_np, X=X_all, y=y_all)
-        except OSError as exc:
-            print(f"# bench data cache write failed: {exc}",
-                  file=sys.stderr)
+    # concept — making held-out AUC meaningless)
+    X_all, y_all = make_higgs_like(ROWS + TEST_ROWS, COLS)
     X, y = X_all[:ROWS], y_all[:ROWS]
     Xte, yte = X_all[ROWS:], y_all[ROWS:]
     del X_all, y_all
@@ -609,7 +524,7 @@ def main():
         REGISTRY.observe("iter_s", dt)
         STATE["iters_done"] += 1
     # budget-adaptive iteration count: always leave room for the
-    # quality checks (test AUC + the reference-example run), reporting
+    # quality check (held-out AUC), reporting
     # partial + extrapolated timing rather than losing the AUC evidence
     per_iter = float(np.median(STATE["iter_times"])) \
         if STATE["iter_times"] else 1.0
@@ -658,25 +573,11 @@ def main():
 
     # held-out quality on the untouched tail split (+ batch predict
     # throughput: second call reuses the compiled path-forest program)
-    try:
-        p = bst.predict(Xte)
-        t0 = time.time()
-        p = bst.predict(Xte)
-        STATE["predict_us_per_row"] = (time.time() - t0) / len(Xte) * 1e6
-        STATE["test_auc"] = _auc(yte, p)
-    except Exception as exc:
-        print(f"# test AUC failed: {exc}", file=sys.stderr)
-    if STATE["test_auc"] is not None and STATE["test_auc"] < 0.80:
-        print("# WARNING: held-out AUC sanity check failed — the speed "
-              "number is from a broken model", file=sys.stderr)
-
-    # real-data parity evidence at the reference's own example config
-    if os.environ.get("BENCH_EXAMPLE", "1") != "0" \
-            and os.path.isdir(REF_EXAMPLE):
-        try:
-            STATE["example_auc"] = run_reference_example(lgb)
-        except Exception as exc:
-            print(f"# example run failed: {exc}", file=sys.stderr)
+    p = bst.predict(Xte)
+    t0 = time.time()
+    p = bst.predict(Xte)
+    STATE["predict_us_per_row"] = (time.time() - t0) / len(Xte) * 1e6
+    STATE["test_auc"] = _auc(yte, p)
 
     # obs-plane overhead A/B (schema minor 11, gated <= 2%)
     if os.environ.get("BENCH_OBS_AB", "1") != "0" \
@@ -688,7 +589,12 @@ def main():
         except Exception as exc:
             print(f"# obs overhead probe failed: {exc}", file=sys.stderr)
 
-    emit(partial=STATE["iters_done"] < ITERS)
+    if not emit(partial=STATE["iters_done"] < ITERS):
+        sys.exit(1)
+    if STATE["test_auc"] < 0.80 and STATE["iters_done"] >= 100:
+        print("# held-out AUC sanity check failed — the speed number is "
+              "from a broken model", file=sys.stderr)
+        sys.exit(1)
 
     # bin-63 sidecar AFTER the primary line is safely on stdout
     if os.environ.get("BENCH_BIN63", "1") != "0" \

@@ -18,10 +18,10 @@ time on a real TPU (or worse, silently pad):
   (`out_ref[...] = x.astype(...)`) must match the `ShapeDtypeStruct`
   dtype declared in `out_shape`; a mismatch means an implicit convert
   on every store.
-- **memspace** — raw `pltpu.HBM` / `pltpu.ANY` / `pltpu.TPUMemorySpace`
-  references outside `utils/compat.py`: the attribute moved across jax
-  releases, so all memory-space annotations go through
-  `compat.pallas_hbm_space`. (`SMEM`/`VMEM` never moved and are fine.)
+- **memspace** — `pltpu.ANY` / `pltpu.TPUMemorySpace` references: the
+  installed jax (0.9) no longer has them, so the kernel fails at import
+  of the attribute on the chip. Unblocked HBM operands are spelled
+  `pltpu.HBM`.
 - **bitcast-width** — `lax.bitcast_convert_type(x, T)` where `x`'s
   dtype is statically known (an `.astype(S)` wrap or a prior
   bitcast/astype assignment in the same function) and `S`/`T` have
@@ -48,8 +48,7 @@ _DTYPE_BITS = {
     "float8_e5m2": 8,
 }
 
-_RAW_MEMSPACES = ("HBM", "ANY", "TPUMemorySpace")
-_COMPAT_REL = "lightgbm_tpu/utils/compat.py"
+_REMOVED_MEMSPACES = ("ANY", "TPUMemorySpace")
 
 
 def _pallas_aliases(tree: ast.Module) -> Tuple[Set[str], Set[str]]:
@@ -236,15 +235,12 @@ class _FileChecker:
 
     # -- memory space ----------------------------------------------------
     def check_memspace(self, node: ast.Attribute) -> None:
-        if self.rel == _COMPAT_REL:
-            return
-        if node.attr in _RAW_MEMSPACES \
+        if node.attr in _REMOVED_MEMSPACES \
                 and isinstance(node.value, ast.Name) \
                 and node.value.id in self.pltpu:
             self._emit(node, f"memspace:{node.attr}",
-                       f"raw pltpu.{node.attr} — the attribute moved "
-                       "across jax releases; use "
-                       "utils.compat.pallas_hbm_space(pltpu)")
+                       f"pltpu.{node.attr} does not exist in the "
+                       "installed jax; use pltpu.HBM")
 
     # -- bitcast width ---------------------------------------------------
     def _source_dtype(self, expr: ast.AST,

@@ -14,9 +14,9 @@ kernel-contract, dtype-flow) all need, from `ast` alone:
   every body handed to `shard_map` / `pmap`, in any of the repo's
   spellings — decorator, `functools.partial(shard_map, ...)` decorator,
   direct `shard_map(f, ...)` call, and the
-  `functools.partial(shard_map, ...)(body)` call form. The
-  `utils/compat.py` alias is recognized by leaf name, the same
-  over-approximation trace_safety uses. Deliberately NOT recognized:
+  `functools.partial(shard_map, ...)(body)` call form. Any alias is
+  recognized by leaf name, the same over-approximation trace_safety
+  uses. Deliberately NOT recognized:
   `@lambda f: shard_map(f, ...)` decorators — an anonymous wrapper the
   call graph cannot see through; write the explicit call form instead.
 
@@ -107,7 +107,7 @@ def axis_inventory(pkg: Package) -> AxisInventory:
 
 def _is_mapping_name(node: ast.AST) -> Optional[str]:
     """'shard_map' | 'pmap' when `node` names that transform (any
-    alias/attribute spelling, including the utils/compat shim)."""
+    alias/attribute spelling)."""
     d = dotted(node)
     if d is None:
         return None

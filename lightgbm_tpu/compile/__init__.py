@@ -9,6 +9,7 @@ path.
 
 Quick map:
 
+- cachedir.py  — the one cache directory (JAX cache + AOT store root)
 - signature.py — buckets, signatures, cache keys
 - store.py     — on-disk serialized executables
 - manager.py   — registration + AOT-first dispatch + counters
@@ -16,6 +17,8 @@ Quick map:
 """
 from __future__ import annotations
 
+from .cachedir import (aot_store_root, compile_cache_dir,
+                       ensure_compile_cache)
 from .manager import (CompileManager, JitEntry, SharedEntry, get_manager,
                       reset_manager)
 from .signature import (bucket_rows, bucketing_enabled, bucket_min_rows,
@@ -26,6 +29,7 @@ from .warmup import (background_warmup, preload_store_async, run_warmup,
                      warmup_entries, warmup_wanted)
 
 __all__ = [
+    "aot_store_root", "compile_cache_dir", "ensure_compile_cache",
     "CompileManager", "JitEntry", "SharedEntry", "get_manager",
     "reset_manager", "bucket_rows", "bucketing_enabled", "bucket_min_rows",
     "cache_key", "config_signature", "environment_key", "shape_signature",

@@ -14,10 +14,15 @@ entries instead of calling `jax.jit` ad hoc, which buys three things:
   first training iteration (compile/warmup.py).
 
 Dispatch order per (entry, concrete shapes): in-memory executable →
-store deserialize → lower+compile (+ serialize) → plain jit fallback.
-Every transition is counted in `CompileManager.stats` and mirrored to
-the active obs registry under `compile.*` counters and the
-"compile"/"aot_load"/"aot_serialize" phase timers.
+store deserialize → lower+compile (+ serialize). A stored blob that
+cannot be loaded — or that loads and then fails at its first call, as
+XLA:CPU blobs can ("Function ... not found") — is dropped with a
+WARNING and recompiled; a compile error, or an executable compiled in
+this process that raises when called, propagates to the caller with
+the compiler's own message. Every transition is counted in
+`CompileManager.stats` and mirrored to the active obs registry under
+`compile.*` counters and the "compile"/"aot_load"/"aot_serialize"
+phase timers.
 
 Thread-safety: per-key locks serialize duplicate compiles (a warmup
 thread and the training thread asking for the same key compile once); a
@@ -33,22 +38,30 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
+from jax.experimental.serialize_executable import (deserialize_and_load,
+                                                   serialize)
 
 from ..utils import log
 from . import signature as S
 from .store import (CorruptBlobError, ExecutableStore, min_compile_s,
                     store_enabled)
 
-_FALLBACK = object()  # dispatch marker: this key uses plain jit forever
-
-
-def is_executable(exe: Any) -> bool:
-    """True only for a real compiled executable — not None and not the
-    plain-jit fallback marker (which means the compile FAILED)."""
-    return exe is not None and exe is not _FALLBACK
-
 _MAX_SHARED_ENTRIES = 32   # LRU cap: entries close over growers/datasets
 _MAX_EXECUTABLES = 128
+
+# jax reports each persistent-cache hit on the compiling thread; _compile
+# reads the per-thread count around .compile() to learn whether the
+# executable is fresh or was deserialized from jax's own cache
+_JAX_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_tls = threading.local()
+
+
+def _on_jax_event(event: str, **_: Any) -> None:
+    if event == _JAX_CACHE_HIT:
+        _tls.jax_cache_hits = getattr(_tls, "jax_cache_hits", 0) + 1
+
+
+jax.monitoring.register_event_listener(_on_jax_event)
 
 
 def _count_donated_bytes(donate_argnums: Tuple[int, ...],
@@ -73,12 +86,17 @@ def _count_donated_bytes(donate_argnums: Tuple[int, ...],
         reg.inc("pipeline.donated_bytes", total)
 
 
-def _aot_supported() -> bool:
-    try:
-        from jax.experimental import serialize_executable  # noqa: F401
-        return True
-    except Exception:
-        return False
+def load_executable(payload: Tuple[bytes, Any, Any, List[int]]) -> Any:
+    """jax.stages.Compiled from a store payload, loaded onto the devices
+    it was compiled for. `deserialize_and_load` defaults to ALL backend
+    devices, so in any process that sees more than one device a
+    single-device executable loaded without them rejects its first call
+    ("Expected args ... to have N shards")."""
+    blob, in_tree, out_tree, device_ids = payload
+    by_id = {d.id: d for d in jax.devices()}
+    return deserialize_and_load(
+        blob, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in device_ids])
 
 
 class SharedEntry:
@@ -142,18 +160,18 @@ class SharedEntry:
             exe = mgr.acquire(self, key, args, statics)
         else:
             mgr.count("cache_hits")
-        if exe is _FALLBACK:
-            return self.jit_fn()(*args, **statics)
-        try:
-            # static args are baked into the compiled executable: call
-            # positionally with the traced args only
+        # static args are baked into the compiled executable: call
+        # positionally with the traced args only
+        if key not in mgr.unproven:
             return exe(*args)
+        try:
+            out = exe(*args)
         except Exception as exc:
-            log.debug("AOT executable %s rejected args (%s); falling back "
-                      "to jit", self.name, exc)
-            mgr._remember(key, _FALLBACK)
-            mgr.count("exec_fallbacks")
-            return self.jit_fn()(*args, **statics)
+            # a bad BLOB, found late; anything compiled here raises above
+            mgr.drop_stored(self.name, key, exc)
+            return mgr.acquire(self, key, args, statics)(*args)
+        mgr.proven(key)
+        return out
 
 
 class JitEntry:
@@ -209,7 +227,10 @@ class CompileManager:
         # it through fused.py _bind_tables on the same thread
         self._trace_lock = threading.RLock()
         self._key_locks: Dict[str, threading.Lock] = {}
-        self.aot_enabled = store_enabled() and _aot_supported()
+        # keys whose executable came from the store and has not yet
+        # survived a call
+        self.unproven: set = set()
+        self.aot_enabled = store_enabled()
 
     # -- bookkeeping ----------------------------------------------------
     def count(self, name: str, value: float = 1) -> None:
@@ -281,80 +302,99 @@ class CompileManager:
     def acquire(self, entry: SharedEntry, key: str, args: Any,
                 statics: Dict[str, Any]) -> Any:
         """Executable for one concrete call: store load, else compile
-        (+persist), else the fallback marker. `args` may be avals."""
+        (+persist). `args` may be avals."""
         with self._key_lock(key):
             exe = self.executables.get(key)
             if exe is not None:
                 self.count("cache_hits")
                 return exe
-            exe = self._load_from_store(entry, key)
+            exe = self._load_from_store(entry.name, key) \
+                if entry.store else None
             if exe is None:
                 exe = self._compile(entry, key, args, statics)
             self._remember(key, exe)
             return exe
 
-    def _load_from_store(self, entry: SharedEntry, key: str) -> Any:
-        if not entry.store:
-            return None
+    def _load_from_store(self, name: str, key: str,
+                         counter: str = "store_loads") -> Any:
+        """The stored executable for `key`, or None. Tolerance here is
+        about a bad BLOB only (corrupt, truncated, or one the runtime
+        refuses to re-link): it is dropped with a warning and the
+        caller recompiles."""
         try:
             t0 = time.perf_counter()
-            triple = self.store.load(key)
-            if triple is None:
+            payload = self.store.load(key)
+            if payload is None:
                 return None
-            from jax.experimental.serialize_executable import \
-                deserialize_and_load
-            exe = deserialize_and_load(*triple)
+            exe = load_executable(payload)
             self.add_time("aot_load", time.perf_counter() - t0)
-            self.count("store_loads")
+            self.count(counter)
+            with self._lock:
+                self.unproven.add(key)
             return exe
-        except CorruptBlobError:
-            self.count("store_load_errors")
-            return None
         except Exception as exc:
-            log.debug("AOT deserialize failed for %s (%s)", entry.name, exc)
-            self.count("store_load_errors")
-            self.store.invalidate(key)
+            self.drop_stored(name, key, exc)
             return None
+
+    def proven(self, key: str) -> None:
+        with self._lock:
+            self.unproven.discard(key)
+
+    def drop_stored(self, name: str, key: str, exc: Exception) -> None:
+        log.warning("AOT store: dropping unusable executable for %s (%s); "
+                    "recompiling", name, exc)
+        self.count("store_load_errors")
+        with self._lock:
+            self.unproven.discard(key)
+            self.executables.pop(key, None)
+        if not isinstance(exc, CorruptBlobError):
+            self.store.invalidate(key)
 
     def _compile(self, entry: SharedEntry, key: str, args: Any,
                  statics: Dict[str, Any]) -> Any:
-        try:
-            from jax.experimental.serialize_executable import serialize
-            t0 = time.perf_counter()
-            with self._trace_lock:
-                lowered = entry.jit_fn().lower(*args, **statics)
-            t1 = time.perf_counter()
-            exe = lowered.compile()
-            elapsed = time.perf_counter() - t0
-            self.add_time("compile", elapsed)
-            # distinct-program accounting (obs schema v1.9): every real
-            # compile is one program; `lowering_s` isolates the
-            # trace+lower span (where the old per-width kernel unroll
-            # burned its 70 minutes) from XLA compile proper
-            self.count("programs")
-            self.count("lowering_s", t1 - t0)
-            self.count("cache_misses")
-            # persist (and pay the HLO-text stat) only for compiles
-            # slower than the threshold: sub-threshold programs cost
-            # more in serialize + blob + manifest traffic than their
-            # recompile, and `hlo_bytes` sizes what the store holds —
-            # the programs the compile window is actually made of
-            if entry.store and elapsed >= min_compile_s():
-                try:
-                    self.count("hlo_bytes", len(lowered.as_text()))
-                except Exception:
-                    pass
-                t0 = time.perf_counter()
-                triple = serialize(exe)
-                if self.store.save(key, triple):
-                    self.add_time("aot_serialize", time.perf_counter() - t0)
-                    self.count("store_saves")
+        t0 = time.perf_counter()
+        with self._trace_lock:
+            lowered = entry.jit_fn().lower(*args, **statics)
+        t1 = time.perf_counter()
+        hits = getattr(_tls, "jax_cache_hits", 0)
+        exe = lowered.compile()
+        from_jax_cache = getattr(_tls, "jax_cache_hits", 0) > hits
+        elapsed = time.perf_counter() - t0
+        self.add_time("compile", elapsed)
+        # distinct-program accounting (obs schema v1.9): every real
+        # compile is one program; `lowering_s` isolates the trace+lower
+        # span from XLA compile proper
+        self.count("programs")
+        self.count("lowering_s", t1 - t0)
+        self.count("cache_misses")
+        if from_jax_cache:
+            # jax's persistent cache already holds it — and XLA:CPU
+            # cannot re-serialize an executable it deserialized (the
+            # blob loads, then fails at its first call with "Function
+            # ... not found"), so the store keeps fresh compiles only
+            self.count("jax_cache_hits")
             return exe
-        except Exception as exc:
-            log.debug("AOT compile failed for %s (%s); using plain jit",
-                      entry.name, exc)
-            self.count("fallbacks")
-            return _FALLBACK
+        # persist (and pay the HLO-text stat) only for compiles slower
+        # than the threshold: sub-threshold programs cost more in
+        # serialize + blob + manifest traffic than their recompile, and
+        # `hlo_bytes` sizes what the store holds — the programs the
+        # compile window is actually made of
+        if entry.store and elapsed >= min_compile_s():
+            self.count("hlo_bytes", len(lowered.as_text()))
+            t0 = time.perf_counter()
+            device_ids = [d.id for d in
+                          exe.runtime_executable().local_devices()]
+            try:
+                triple = serialize(exe)
+            except Exception as exc:
+                # the program compiled and runs; only the store misses out
+                log.warning("AOT store: %s is not serializable (%s); not "
+                            "persisted", entry.name, exc)
+                return exe
+            if self.store.save(key, triple, device_ids):
+                self.add_time("aot_serialize", time.perf_counter() - t0)
+                self.count("store_saves")
+        return exe
 
     # -- store preload --------------------------------------------------
     def preload_keys(self) -> List[str]:
@@ -376,28 +416,11 @@ class CompileManager:
             with self._key_lock(key):
                 if key in self.executables:
                     continue
-                exe = self._preload_one(key)
+                exe = self._load_from_store(key, key, "store_preloads")
                 if exe is not None:
                     self._remember(key, exe)
                     n += 1
         return n
-
-    def _preload_one(self, key: str) -> Any:
-        try:
-            t0 = time.perf_counter()
-            triple = self.store.load(key)
-            if triple is None:
-                return None
-            from jax.experimental.serialize_executable import \
-                deserialize_and_load
-            exe = deserialize_and_load(*triple)
-            self.add_time("aot_load", time.perf_counter() - t0)
-            self.count("store_preloads")
-            return exe
-        except Exception:
-            self.count("store_load_errors")
-            self.store.invalidate(key)
-            return None
 
 
 _MANAGER: Optional[CompileManager] = None

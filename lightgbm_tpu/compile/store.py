@@ -20,7 +20,9 @@ environment, rsync/GCS-friendly):
 
 Each payload holds the `jax.export`-level serialization triple
 (blob, in_tree, out_tree) produced by
-`jax.experimental.serialize_executable.serialize`. The environment-key
+`jax.experimental.serialize_executable.serialize`, plus the ids of the
+devices the executable was compiled for (it must be loaded onto exactly
+those, manager.load_executable). The environment-key
 directory namespaces by (jax version, backend, device kind/count,
 process count, code fingerprint), so upgrading jax or moving between
 CPU/TPU can never deserialize a stale executable — it simply looks in
@@ -35,12 +37,13 @@ are touched on load, so the LRU order reflects use, not creation.
 Knobs: LGBM_TPU_AOT_CACHE_MB caps the per-environment directory size
 (default 2048; 0 disables the sweep).
 
-Root: $LGBM_TPU_AOT_CACHE, default ~/.cache/lightgbm_tpu/aot.
+Root: the ``aot`` subdirectory of the one compile-cache directory
+(cachedir.py: $JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache).
 LGBM_TPU_AOT=0 disables the store (and all AOT dispatch) entirely.
 
 Corrupt or undeserializable blobs are deleted and reported through the
 manager's counters; a corrupt manifest is treated as empty (recompile,
-then rewritten on the next save); callers fall back to plain jit.
+then rewritten on the next save); callers recompile.
 
 TRUST BOUNDARY: the cache directory must only be writable by the user
 (or pod service account) running training. Payloads are pickled (the
@@ -52,10 +55,9 @@ its directories 0700 and files 0600. Content addressing is an
 *integrity* check against corruption, not an authenticity check: the
 manifest and digests live in the same directory as the blobs, so
 anyone who can write a blob can write its digest. Do not point
-$LGBM_TPU_AOT_CACHE at a world- or group-writable path, and only
+$JAX_COMPILATION_CACHE_DIR at a world- or group-writable path, and only
 rsync/mount stores from pods you trust as much as the training user;
-the default is per-user, and its contents deserve the same trust as
-~/.cache/jax.
+its contents deserve the same trust as jax's own persistent cache.
 """
 from __future__ import annotations
 
@@ -71,8 +73,9 @@ import jax
 
 from ..utils import log
 from . import signature as S
+from .cachedir import aot_store_root
 
-_PAYLOAD_VERSION = 1
+_PAYLOAD_VERSION = 2   # 2: payload carries device_ids
 _MANIFEST_VERSION = 1
 _MANIFEST_NAME = "manifest.json"
 _BLOB_PREFIX = "sha256-"
@@ -80,13 +83,6 @@ _BLOB_PREFIX = "sha256-"
 
 def store_enabled() -> bool:
     return os.environ.get("LGBM_TPU_AOT", "1") != "0"
-
-
-def default_root() -> str:
-    return os.environ.get(
-        "LGBM_TPU_AOT_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "lightgbm_tpu",
-                     "aot"))
 
 
 def cache_cap_bytes() -> int:
@@ -117,7 +113,7 @@ class ExecutableStore:
     (a broken disk must never break training)."""
 
     def __init__(self, root: Optional[str] = None) -> None:
-        self.root = root or default_root()
+        self.root = root or aot_store_root()
         self._env_dir: Optional[str] = None
 
     def env_dir(self) -> str:
@@ -149,8 +145,8 @@ class ExecutableStore:
         except FileNotFoundError:
             return {}
         except Exception as exc:
-            log.debug("AOT store: unreadable manifest %s (%s); treating "
-                      "as empty", self.manifest_path(), exc)
+            log.warning("AOT store: unreadable manifest %s (%s); treating "
+                        "as empty", self.manifest_path(), exc)
             return {}
 
     def _write_manifest(self, entries: Dict[str, Any]) -> None:
@@ -191,8 +187,9 @@ class ExecutableStore:
         return sorted(out)
 
     # -- load -----------------------------------------------------------
-    def load(self, key: str) -> Optional[Tuple[bytes, Any, Any]]:
-        """The serialized triple for `key`, or None. Manifest entries
+    def load(self, key: str) -> Optional[Tuple[bytes, Any, Any, List[int]]]:
+        """(blob, in_tree, out_tree, device_ids) for `key`, or None.
+        Manifest entries
         are probed first, then the legacy direct path. Corrupt payloads
         (unpicklable, wrong version, truncated) are deleted on sight;
         a manifest entry pointing at a missing/corrupt blob is dropped
@@ -226,7 +223,8 @@ class ExecutableStore:
                 raise ValueError("payload version mismatch")
             # LRU touch: GC evicts by mtime, so a loaded blob is "young"
             self._best_effort(os.utime, path)
-            return payload["blob"], payload["in_tree"], payload["out_tree"]
+            return (payload["blob"], payload["in_tree"], payload["out_tree"],
+                    payload["device_ids"])
         except FileNotFoundError:
             if via_manifest:
                 # manifest promised a blob that is gone (GC race on
@@ -266,7 +264,8 @@ class ExecutableStore:
             except OSError:
                 pass
 
-    def save(self, key: str, triple: Tuple[bytes, Any, Any]) -> bool:
+    def save(self, key: str, triple: Tuple[bytes, Any, Any],
+             device_ids: List[int]) -> bool:
         """Content-addressed atomic publish: blob first (tmp + rename,
         skipped when the digest already exists), manifest entry second.
         A concurrent reader that sees the entry sees the whole blob."""
@@ -277,7 +276,8 @@ class ExecutableStore:
             # same serialized triple share one blob on disk
             payload = {"v": _PAYLOAD_VERSION, "jax": jax.__version__,
                        "blob": triple[0],
-                       "in_tree": triple[1], "out_tree": triple[2]}
+                       "in_tree": triple[1], "out_tree": triple[2],
+                       "device_ids": list(device_ids)}
             raw = pickle.dumps(payload, protocol=pickle.HIGHEST_PROTOCOL)
             blob_name = (_BLOB_PREFIX
                          + hashlib.sha256(raw).hexdigest()[:32] + ".aotx")
@@ -299,7 +299,7 @@ class ExecutableStore:
             self._best_effort(self.gc)
             return True
         except Exception as exc:
-            log.debug("AOT store: save failed for %s (%s)", key, exc)
+            log.warning("AOT store: save failed for %s (%s)", key, exc)
             return False
 
     # -- invalidate / GC ------------------------------------------------

@@ -29,8 +29,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from ..utils import log
 from . import signature as S
-from .manager import (CompileManager, SharedEntry, get_manager,
-                      is_executable)
+from .manager import CompileManager, SharedEntry, get_manager
 
 # Background threads must never be mid-XLA-call while the interpreter
 # tears down its C++ state (PJRT client destruction aborts the process
@@ -102,10 +101,10 @@ def warmup_entries(jobs: Optional[int] = None) -> Dict[str, Any]:
             return mgr.acquire(entry, key, args, statics)
 
         with ThreadPoolExecutor(max_workers=workers) as pool:
+            # a failed compile raises out of map() with the compiler's
+            # message; only a shutdown skip yields None
             for exe in pool.map(_one, pending):
-                # a _FALLBACK result means the compile FAILED — only
-                # real executables count toward the warmup summary
-                compiled += is_executable(exe)
+                compiled += exe is not None
     return {"entries": len(pending), "compiled": compiled,
             "seconds": time.perf_counter() - t0,
             "stats": mgr.snapshot()}
@@ -152,7 +151,9 @@ def background_warmup(jobs: Optional[int] = None
                           "%.1fs", summary["compiled"], summary["entries"],
                           summary["seconds"])
         except Exception as exc:
-            log.debug("Background warmup failed: %s", exc)
+            # the training thread compiles the same entry itself and
+            # raises there; this thread only reports
+            log.warning("Background warmup failed: %s", exc)
 
     th = threading.Thread(target=_run, name="lgbm-aot-warmup", daemon=True)
     _track(th)

@@ -683,6 +683,10 @@ class Config:
 
     def _finalize(self) -> None:
         """Inter-parameter checks (reference Config::CheckParamConflict)."""
+        if self.device_type not in ("tpu", "cpu"):
+            log.fatal("device_type must be 'tpu' (Pallas kernels; needs a "
+                      "TPU backend) or 'cpu' (portable XLA paths), got %r",
+                      self.device_type)
         if self.tpu_hist_dtype not in ("bfloat16", "float32"):
             log.fatal("tpu_hist_dtype must be 'bfloat16' or 'float32', "
                       "got %r", self.tpu_hist_dtype)

@@ -38,25 +38,6 @@ def _resolve_early_stopping(params: Dict[str, Any],
     return explicit
 
 
-def _ensure_jit_cache() -> None:
-    """Persistent XLA compile cache shared by every entry point (train,
-    cv, bench): fold 2..k of a cv() and repeat runs of the same shapes
-    skip compilation entirely. Respects a user-configured cache dir."""
-    import jax
-    try:
-        if jax.config.jax_compilation_cache_dir:
-            return
-        cache = os.environ.get(
-            "LGBM_TPU_JIT_CACHE",
-            os.path.join(os.path.expanduser("~"), ".cache",
-                         "lightgbm_tpu", "xla"))
-        os.makedirs(cache, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:
-        pass
-
-
 def _telemetry_end_iteration(telemetry, booster, iteration: int,
                              evals) -> None:
     """Snapshot one iteration into the telemetry session: sync the
@@ -137,8 +118,8 @@ def train(params: Dict[str, Any], train_set: Dataset,
     `checkpoint_interval` iterations, and auto-resume from the latest
     valid checkpoint when one exists (docs/ROBUSTNESS.md)."""
     params = copy.deepcopy(params) if params else {}
-    _ensure_jit_cache()
-    from .compile import preload_store_async
+    from .compile import ensure_compile_cache, preload_store_async
+    ensure_compile_cache()
     preload_store_async()
     # multi-host process wiring BEFORE any dataset construction, so the
     # distributed bin-mapper allgather and the training mesh see the
@@ -204,6 +185,17 @@ def train(params: Dict[str, Any], train_set: Dataset,
 
     with global_timer.scope("dataset construction + learner build"):
         booster = Booster(params=params, train_set=train_set)
+    plan = booster._gbdt.execution_plan()
+    log.info("Training on backend=%s (%s x%d): tier=%s learner=%s "
+             "hist=%s partition=%s", plan["backend"], plan["device_kind"],
+             plan["device_count"], plan["tier"], plan["learner"],
+             plan["hist"], plan["partition"])
+    if booster._gbdt.config.device_type == "tpu" \
+            and plan["backend"] != "tpu":
+        log.warning("device_type=tpu but JAX initialised the %s backend, "
+                    "where the TPU kernels cannot be compiled: training "
+                    "runs hist=%s partition=%s there", plan["backend"],
+                    plan["hist"], plan["partition"])
     from .compile import background_warmup, warmup_wanted
     if warmup_wanted(booster._gbdt.config, train_set.num_data()):
         # compile the registered entry specs on a thread pool while the
@@ -683,8 +675,8 @@ def cv(params: Dict[str, Any], train_set: Dataset, num_boost_round: int = 100,
        callbacks=None, eval_train_metric: bool = False,
        return_cvbooster: bool = False):
     """reference engine.py:394."""
-    _ensure_jit_cache()
-    from .compile import preload_store_async
+    from .compile import ensure_compile_cache, preload_store_async
+    ensure_compile_cache()
     preload_store_async()
     params = copy.deepcopy(params) if params else {}
     num_boost_round = _resolve_num_boost_round(params, num_boost_round)

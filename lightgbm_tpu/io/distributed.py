@@ -125,7 +125,7 @@ def allgather_bytes(shard_bufs: np.ndarray, mesh=None) -> np.ndarray:
     owned by rank r; returns the replicated [world, L]."""
     import jax
     import jax.numpy as jnp
-    from ..utils.compat import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     if mesh is None:

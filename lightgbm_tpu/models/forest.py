@@ -6,8 +6,8 @@ chasing, src/c_api.cpp:60 SingleRowPredictor, and
 src/boosting/prediction_early_stop.cpp margin-based early stop).
 
 The host-side per-tree loop in GBDT.predict_raw costs one device
-dispatch per tree (~500 dispatches for a full model — fatal over a
-remote-accelerator tunnel). Here every tree's flat node arrays are
+dispatch per tree (~500 dispatches for a full model). Here every
+tree's flat node arrays are
 stacked into [T, Nmax] device tensors once, and a single jitted
 program either scans over trees (no early stop) or runs a
 `lax.while_loop` over boosting iterations with a per-row `done` mask

@@ -4,25 +4,42 @@ shared object — no pybind11 dependency).
 The TPU compute path is JAX/XLA; these kernels cover the host-side
 runtime work the reference implements in C++ (bin boundary search,
 column bin conversion — src/io/bin.cpp) where Python-loop cost is
-material at load time. Falls back to the pure-Python implementations
-when no compiler is available (set LIGHTGBM_TPU_NO_NATIVE=1 to force
-the fallback).
+material at load time. The built library is named by a hash of
+binning.cpp, so only the committed source decides what is loaded — a
+stale or foreign .so in the tree is never picked up. Without a working
+compiler the pure-Python implementations are used, with a warning
+(LIGHTGBM_TPU_NO_NATIVE=1 selects them deliberately).
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 from typing import Optional
 
 import numpy as np
 
+from ..utils import log
+
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "binning.cpp")
-_SO = os.path.join(_DIR, "_native.so")
 
 _lib = None
 _tried = False
+
+
+def library_path() -> str:
+    """`_native_<sha256(binning.cpp)[:12]>.so` next to the source."""
+    with open(_SRC, "rb") as fh:
+        digest = hashlib.sha256(fh.read()).hexdigest()[:12]
+    return os.path.join(_DIR, f"_native_{digest}.so")
+
+
+def implementation() -> str:
+    """Which binning implementation this process uses."""
+    return f"native ({os.path.basename(library_path())})" \
+        if _load() is not None else "python"
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -31,22 +48,24 @@ def _load() -> Optional[ctypes.CDLL]:
         return _lib
     _tried = True
     if os.environ.get("LIGHTGBM_TPU_NO_NATIVE"):
+        log.info("Host binning: python (LIGHTGBM_TPU_NO_NATIVE is set)")
         return None
     try:
-        if (not os.path.exists(_SO)
-                or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
+        so = library_path()
+        if not os.path.exists(so):
+            tmp = f"{so}.{os.getpid()}.tmp"
             try:
                 subprocess.run(
                     ["g++", "-O3", "-std=c++17", "-fopenmp", "-shared",
-                     "-fPIC", _SRC, "-o", _SO + ".tmp"],
+                     "-fPIC", _SRC, "-o", tmp],
                     check=True, capture_output=True, timeout=120)
             except subprocess.CalledProcessError:
                 subprocess.run(  # toolchains without libgomp
                     ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", _SRC,
-                     "-o", _SO + ".tmp"],
+                     "-o", tmp],
                     check=True, capture_output=True, timeout=120)
-            os.replace(_SO + ".tmp", _SO)
-        lib = ctypes.CDLL(_SO)
+            os.replace(tmp, so)
+        lib = ctypes.CDLL(so)
         lib.lgbt_greedy_find_bin.restype = ctypes.c_int
         lib.lgbt_greedy_find_bin.argtypes = [
             ctypes.POINTER(ctypes.c_double), ctypes.POINTER(ctypes.c_int64),
@@ -58,7 +77,12 @@ def _load() -> Optional[ctypes.CDLL]:
             ctypes.POINTER(ctypes.c_double), ctypes.c_int32,
             ctypes.POINTER(ctypes.c_uint16)]
         _lib = lib
-    except Exception:  # no compiler / bad toolchain: fall back silently
+        log.info("Host binning: native (%s)", os.path.basename(so))
+    except (OSError, subprocess.SubprocessError) as exc:
+        detail = getattr(exc, "stderr", b"") or b""
+        log.warning("Host binning: python loops — building %s failed: %s %s",
+                    os.path.basename(_SRC), exc,
+                    detail.decode(errors="replace")[-400:])
         _lib = None
     return _lib
 

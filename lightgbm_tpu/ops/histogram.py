@@ -767,10 +767,13 @@ def hist_method(config, dataset=None) -> Optional[str]:
     row-wise multi-value layout for this dataset (wide-sparse shapes —
     requires the dataset handle with construct-time occupancy stats;
     callers without one, e.g. the host-loop parallel learners, keep
-    planar). Other backends keep the exact scatter path (the oracle)
-    regardless. Note "multival_pallas" does NOT encode a dtype suffix:
-    the multival kernels read precision from tpu_hist_dtype directly."""
-    if not _use_tpu():
+    planar). ``None`` selects the portable XLA paths — scatter
+    histogram and argsort partition, the oracles: on any backend that
+    is not a TPU (Mosaic lowers nowhere else; engine.train warns), and
+    on a TPU when the user asks for ``device_type=cpu``. Note
+    "multival_pallas" does NOT encode a dtype suffix: the multival
+    kernels read precision from tpu_hist_dtype directly."""
+    if config.device_type != "tpu" or not _use_tpu():
         return None
     occ = getattr(dataset, "occupancy", None) if dataset is not None \
         else None
@@ -785,7 +788,8 @@ def hist_method(config, dataset=None) -> Optional[str]:
 
 def histogram(bins: jax.Array, grad: jax.Array, hess: jax.Array,
               num_bins: int, method: Optional[str] = None) -> jax.Array:
-    """Backend-dispatched histogram [F, B, 2]."""
+    """Histogram [F, B, 2] by ``method`` (see hist_method); None is the
+    scatter oracle."""
     if method == "multival_pallas":
         # the multival kernels take packed row-wise codes, not [n, F]
         # bin matrices — learners route them through ops/multival.py
@@ -793,8 +797,6 @@ def histogram(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         raise ValueError(
             "multival_pallas is not a column-major histogram method; "
             "use ops.multival.leaf_histogram_multival")
-    if method is None:
-        method = "radix_pallas" if _use_tpu() else "scatter"
     if method == "radix_pallas":
         return histogram_radix_pallas(bins, grad, hess, num_bins)
     if method == "radix_pallas_bf16":

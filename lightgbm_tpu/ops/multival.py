@@ -496,8 +496,7 @@ def gh_planes(grad: jax.Array, hess: jax.Array,
 def leaf_histogram_multival(codes: jax.Array, perm: jax.Array, start,
                             count, grad: jax.Array, hess: jax.Array,
                             capacity: int, total_bins: int, *,
-                            use_pallas: Optional[bool] = None,
-                            dtype=jnp.float32,
+                            use_pallas: bool, dtype=jnp.float32,
                             rows_per_block: Optional[int] = None,
                             interpret: bool = False) -> jax.Array:
     """Row-wise flat histogram of a permuted leaf window — the
@@ -512,8 +511,6 @@ def leaf_histogram_multival(codes: jax.Array, perm: jax.Array, start,
     zero = jnp.zeros((), grad.dtype)
     g = jnp.where(valid, grad[rows], zero)
     h = jnp.where(valid, hess[rows], zero)
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
     if not use_pallas:
         return histogram_multival_xla(c, g, h, total_bins)
     quant = jnp.issubdtype(grad.dtype, jnp.integer)

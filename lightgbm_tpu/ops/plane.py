@@ -591,8 +591,6 @@ def partition_pallas(data: jax.Array, layout: PlaneLayout, start, count,
     static `cap//S + 1` sweep for shape-stable callers."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    from ..utils.compat import pallas_hbm_space
-    _HBM = pallas_hbm_space(pltpu)
 
     P, R = data.shape
     S = tile if tile is not None else layout.tile
@@ -627,8 +625,8 @@ def partition_pallas(data: jax.Array, layout: PlaneLayout, start, count,
             lambda side, t, scal: (0, scal[2] + jnp.clip(t, scal[3],
                                                          scal[4])))],
         out_specs=[
-            pl.BlockSpec(memory_space=_HBM),
-            pl.BlockSpec(memory_space=_HBM),
+            pl.BlockSpec(memory_space=pltpu.HBM),
+            pl.BlockSpec(memory_space=pltpu.HBM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         scratch_shapes=[
@@ -927,8 +925,6 @@ def partition_pallas2(data: jax.Array, layout: PlaneLayout, start, count,
     in place."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    from ..utils.compat import pallas_hbm_space
-    _HBM = pallas_hbm_space(pltpu)
 
     P, R = data.shape
     S = tile if tile is not None else layout.tile
@@ -963,8 +959,8 @@ def partition_pallas2(data: jax.Array, layout: PlaneLayout, start, count,
             lambda side, t, scal: (0, scal[2] + jnp.where(
                 side == 0, jnp.clip(t, scal[3], scal[4]), scal[3])))],
         out_specs=[
-            pl.BlockSpec(memory_space=_HBM),
-            pl.BlockSpec(memory_space=_HBM),
+            pl.BlockSpec(memory_space=pltpu.HBM),
+            pl.BlockSpec(memory_space=pltpu.HBM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         scratch_shapes=[
@@ -1005,10 +1001,8 @@ def partition_pallas2(data: jax.Array, layout: PlaneLayout, start, count,
     return dout, nleft[0, 0]
 
 
-def partition_window(data, layout, start, count, rscal, *, cap=None,
-                     method="auto", tile=None, interpret=False):
-    if method == "auto":
-        method = "pallas" if jax.default_backend() == "tpu" else "ref"
+def partition_window(data, layout, start, count, rscal, *, method,
+                     cap=None, tile=None, interpret=False):
     if cap is None and method == "ref":
         raise ValueError("partition_ref slices with a STATIC capacity — "
                          "the dynamic cap=None mode is pallas-only")

@@ -33,8 +33,8 @@ from typing import Dict, Optional
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ..utils.compat import shard_map
 
 from ..config import Config
 from ..io.dataset import BinnedDataset
@@ -115,8 +115,7 @@ class DataParallelTreeGrower(SerialTreeGrower):
             self._shard_valid_rows[-1] -= pad
         sharded = bins_np.reshape(d, self.rows_per_shard, -1)
         self.bins_sharded = jax.device_put(
-            jnp.asarray(sharded),
-            NamedSharding(self.mesh, P("data", None, None)))
+            sharded, NamedSharding(self.mesh, P("data", None, None)))
         self._spec_rows = NamedSharding(self.mesh, P("data", None))
 
     # -- sharded kernels ------------------------------------------------
@@ -702,49 +701,50 @@ class FusedDataParallelGrower(FusedSerialGrower):
         return sig, shareable
 
     # -- sharded state construction ------------------------------------
-    def _shard_lane_pad(self, v, fill=0.0, dtype=jnp.float32):
-        """[n] global -> [D * num_lanes] with per-shard lane padding."""
-        D, sr, Ly = self.num_shards, self.shard_rows, self.layout
-        v = jnp.asarray(v, dtype)
-        v = jnp.pad(v, (0, D * sr - v.shape[0]), constant_values=fill)
-        v = v.reshape(D, sr)
-        v = jnp.pad(v, ((0, 0), (0, Ly.num_lanes - sr)),
-                    constant_values=fill)
-        return v.reshape(-1)
-
     def init_persistent_state(self, score_vec) -> jax.Array:
+        """[P, D * num_lanes] planar state, lanes sharded over "data".
+        Every shard is packed ON the device that owns it from host
+        slices, so no device ever holds more than its own share (the
+        global state assembled on the default device first is 4x one
+        chip's share on a four-chip host)."""
         assert self.persistent_capable
         from ..ops import plane
         D, sr, Ly = self.num_shards, self.shard_rows, self.layout
         aux_label, aux_weight = self.objective.persistent_aux()
         n = self.global_rows
-        # host-side pad: reading the lazy `self.bins` property would
-        # upload + CACHE the full global row-major matrix on one device
-        # (the HBM waste the lazy property exists to avoid)
-        bins_pad = np.pad(np.asarray(self.dataset.bins),
-                          ((0, D * sr - n), (0, 0)))
-        shards = []
-        for d in range(D):
+        bins = np.asarray(self.dataset.bins)
+        # one device->host copy each, sliced per shard below
+        label, score, weight = (
+            None if v is None else np.asarray(v, np.float32)
+            for v in (aux_label, score_vec, aux_weight))
+
+        def host_rows(v, d):
+            """Shard d's slice of a global [n] host vector; build_data
+            zero-pads it to the lane count."""
+            return jnp.asarray(v[d * sr:(d + 1) * sr])
+
+        def build_shard(d):
             cp = plane.build_codes_planes(
-                bins_pad[d * sr:(d + 1) * sr], Ly)
-            rowid = jnp.arange(d * sr, (d + 1) * sr, dtype=jnp.int32)
+                jnp.asarray(bins[d * sr:(d + 1) * sr]), Ly)
             # pad rows alias row id n -> dropped by the sync scatter
-            rowid = jnp.where(rowid < n, rowid, n)
-            rowid = jnp.pad(rowid, (0, Ly.num_lanes - sr),
-                            constant_values=n)
-            zero = jnp.zeros(Ly.num_lanes, jnp.float32)
-            shards.append(plane.build_data(
-                Ly, cp, zero, zero, rowid=rowid))
-        data = jnp.concatenate(shards, axis=1)
-        lab = self._shard_lane_pad(aux_label)
-        sc = self._shard_lane_pad(jnp.asarray(score_vec, jnp.float32))
-        data = data.at[Ly.label].set(plane.f32_as_i32(lab))
-        data = data.at[Ly.score].set(plane.f32_as_i32(sc))
-        if Ly.weight >= 0:
-            data = data.at[Ly.weight].set(
-                plane.f32_as_i32(self._shard_lane_pad(aux_weight)))
-        return jax.device_put(
-            data, NamedSharding(self.mesh, P(None, "data")))
+            rowid = np.minimum(np.arange(d * sr, (d + 1) * sr), n)
+            rowid = np.pad(rowid, (0, Ly.num_lanes - sr),
+                           constant_values=n).astype(np.int32)
+            zero = jnp.zeros(sr, jnp.float32)
+            return plane.build_data(
+                Ly, cp, zero, zero, rowid=jnp.asarray(rowid),
+                label=host_rows(label, d), score=host_rows(score, d),
+                weight=None if weight is None else host_rows(weight, d))
+
+        shape = (Ly.num_planes, D * Ly.num_lanes)
+        sharding = NamedSharding(self.mesh, P(None, "data"))
+        shards = []
+        for dev, idx in sharding.addressable_devices_indices_map(
+                shape).items():
+            with jax.default_device(dev):
+                shards.append(build_shard(idx[1].start // Ly.num_lanes))
+        return jax.make_array_from_single_device_arrays(
+            shape, sharding, shards)
 
     # -- sharded iteration ---------------------------------------------
     # NOTE on quantized training: the in-graph per-split child-histogram
@@ -860,7 +860,7 @@ class FusedDataParallelGrower(FusedSerialGrower):
             if pad:
                 bins_np = np.pad(bins_np, ((0, pad), (0, 0)), mode="edge")
             self._bins_sh = jax.device_put(
-                jnp.asarray(bins_np.reshape(D, sr, -1)),
+                bins_np.reshape(D, sr, -1),
                 NamedSharding(self.mesh, P("data", None, None)))
         return self._bins_sh
 

@@ -271,7 +271,7 @@ class SerialTreeGrower:
                 # present-code view instead of the [n, G] bin matrix
                 flat = MV.leaf_histogram_multival(
                     codes_dev, perm, start, count, grad, hess,
-                    capacity, total_bins)
+                    capacity, total_bins, use_pallas=True)
                 ghist = MV.group_hist_from_flat(flat, tables)
                 if efb_hist is None:
                     return ghist

@@ -52,13 +52,6 @@ def steady_iter_time(bst, iters):
 
 def main():
     import jax
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    try:
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(repo, ".jax_cache"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:
-        pass
     import lightgbm_tpu as lgb
 
     results = {}

@@ -75,10 +75,6 @@ def allreduce_bytes(mlir_text: str):
 def main_body():
     import numpy as np
     import jax
-    try:
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:
-        pass
     import jax.numpy as jnp
     sys.path.insert(0, REPO)
     from lightgbm_tpu.config import Config

@@ -20,16 +20,8 @@ LEAVES = int(os.environ.get("BENCH_LEAVES", 255))
 
 def main():
     import jax
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    try:
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(repo, ".jax_cache"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:
-        pass
     import lightgbm_tpu as lgb
-    sys.path.insert(0, repo)
-    from bench import make_higgs_like
+    from chip_smoke import make_higgs_like
 
     X, y = make_higgs_like(ROWS, 28)
     params = {"objective": "binary", "num_leaves": LEAVES, "max_bin": 255,

@@ -82,13 +82,6 @@ def main():
                         help="pin tpu_hist_layout (default: %(default)s)")
     ns = parser.parse_args()
     T0 = time.time()
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    try:
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(repo, ".jax_cache"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:
-        pass
     import lightgbm_tpu as lgb
 
     t0 = time.time()
@@ -203,6 +196,7 @@ def main():
         f"Generated by scripts/sparse_scale.py; total wall "
         f"{time.time() - T0:.0f}s.",
     ]
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     out = os.path.join(repo, "docs", "SPARSE_SCALE.md")
     # preserve hand-authored analysis across regeneration: everything
     # from the FIRST second-level heading onward (the generated part

@@ -34,13 +34,8 @@ BINS = 16
 
 def main():
     import jax
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    try:
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(repo, ".jax_cache"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:
-        pass
+    from lightgbm_tpu.compile import ensure_compile_cache
+    ensure_compile_cache()
     from lightgbm_tpu.config import Config
     from lightgbm_tpu.io.dataset import BinnedDataset, Metadata
     from lightgbm_tpu.io.binning import BinMapper
@@ -91,32 +86,33 @@ def main():
 
 
 def lower_proof():
-    """Bounded trace+lower+compile of the full-width histogram program.
-
-    On TPU this is the real Mosaic lowering the 70-minute cliff lived
-    in; on CPU the interpret-mode lowering exercises the same traced
-    program (same equation count, same width-independence). Shapes are
-    abstract — no 13M-row buffer is materialized."""
+    """Bounded trace+lower+compile of the full-width histogram program
+    through Mosaic — the lowering the 70-minute cliff lived in, so it
+    needs a TPU and refuses to run without one (the width-independence
+    of the traced program itself is pinned on the CPU tier by
+    tests/test_compile_collapse.py). Shapes are abstract — no 13M-row
+    buffer is materialized."""
     import jax
     import jax.numpy as jnp
     from lightgbm_tpu.ops.histogram import (histogram_planar_pallas,
                                             planar_grid_dims)
 
+    if jax.default_backend() != "tpu":
+        raise SystemExit(
+            f"--lower-proof is the Mosaic proof and needs a TPU; JAX "
+            f"initialised the {jax.default_backend()} backend")
     code_bits = 4
-    interpret = jax.default_backend() != "tpu"
     budget = float(os.environ.get("REPRO_LOWER_BUDGET_S", 300))
     Fc, SP, CC, CS = planar_grid_dims(BINS, code_bits, COLS)
     gp = -(-CS * SP // 8) * 8
     R = -(-ROWS // 1024) * 1024
     print(f"geometry: {COLS} cols -> {CC * CS} feature chunks "
-          f"(Fc={Fc} CC={CC} CS={CS}), R={R}, "
-          f"{'interpret' if interpret else 'mosaic'} lowering", flush=True)
+          f"(Fc={Fc} CC={CC} CS={CS}), R={R}, mosaic lowering", flush=True)
 
     def fn(d, start, cnt):
         return histogram_planar_pallas(
             d, start, cnt, num_bins=BINS, num_cols=COLS,
-            code_bits=code_bits, grad_plane=gp, cap=None,
-            interpret=interpret)
+            code_bits=code_bits, grad_plane=gp, cap=None)
 
     spec = (jax.ShapeDtypeStruct((gp + 8, R), jnp.int32),
             jax.ShapeDtypeStruct((), jnp.int32),
@@ -131,7 +127,7 @@ def lower_proof():
     assert t2 - t0 < budget, (
         f"full-width lowering took {t2 - t0:.0f}s > {budget:.0f}s "
         f"budget — the compile-window cliff is back")
-    print("OK", flush=True)
+    print("OK (Mosaic lower+compile proved)", flush=True)
 
 
 if __name__ == "__main__":

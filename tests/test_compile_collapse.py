@@ -158,8 +158,7 @@ def test_capacity_ladder_geometry():
 # -- content-addressed store: GC + corrupt-manifest fallback ------------
 
 @pytest.fixture
-def store(tmp_path, monkeypatch):
-    monkeypatch.setenv("LGBM_TPU_AOT_CACHE", str(tmp_path / "aot"))
+def store(tmp_path):
     return ExecutableStore(str(tmp_path / "aot"))
 
 
@@ -172,7 +171,7 @@ def test_store_content_addressed_dedup(store):
     """Identical triples under different cache keys share ONE blob (the
     payload excludes the key), so pod-syncing N aliases moves one file."""
     t = _fake_triple(1)
-    assert store.save("k1", t) and store.save("k2", t)
+    assert store.save("k1", t, [0]) and store.save("k2", t, [0])
     blobs = [f for f in os.listdir(store.env_dir())
              if f.startswith("sha256-") and f.endswith(".aotx")]
     assert len(blobs) == 1
@@ -182,7 +181,7 @@ def test_store_content_addressed_dedup(store):
 
 def test_store_gc_evicts_oldest_first(store):
     for i in range(5):
-        assert store.save(f"k{i}", _fake_triple(i))
+        assert store.save(f"k{i}", _fake_triple(i), [0])
     # age the blobs oldest-first by key order
     man = store._read_manifest()
     for i in range(5):
@@ -200,25 +199,25 @@ def test_store_gc_evicts_oldest_first(store):
 def test_store_gc_disabled_by_zero_cap(store, monkeypatch):
     monkeypatch.setenv("LGBM_TPU_AOT_CACHE_MB", "0")
     for i in range(3):
-        assert store.save(f"k{i}", _fake_triple(i))
+        assert store.save(f"k{i}", _fake_triple(i), [0])
     assert all(store.load(f"k{i}") is not None for i in range(3))
 
 
 def test_store_corrupt_manifest_is_empty_not_fatal(store):
-    assert store.save("k1", _fake_triple(1))
+    assert store.save("k1", _fake_triple(1), [0])
     with open(store.manifest_path(), "w") as fh:
         fh.write("{ not json")
     # reads fall back to recompile (None), never crash
     assert store.load("k1") is None
     assert store.keys() == []
     # the next save rewrites a valid manifest and the store heals
-    assert store.save("k2", _fake_triple(2))
+    assert store.save("k2", _fake_triple(2), [0])
     assert store.load("k2") is not None
     assert "k2" in store._read_manifest()
 
 
 def test_store_malformed_manifest_entry_recovers(store):
-    assert store.save("k1", _fake_triple(1))
+    assert store.save("k1", _fake_triple(1), [0])
     entries = store._read_manifest()
     entries["k1"] = {"typo": True}  # entry without a blob name
     store._write_manifest(entries)
@@ -228,7 +227,7 @@ def test_store_malformed_manifest_entry_recovers(store):
 
 
 def test_store_manifest_entry_without_blob_recovers(store):
-    assert store.save("k1", _fake_triple(1))
+    assert store.save("k1", _fake_triple(1), [0])
     os.unlink(os.path.join(store.env_dir(),
                            store._read_manifest()["k1"]["blob"]))
     with pytest.raises(CorruptBlobError):
@@ -239,7 +238,7 @@ def test_store_manifest_entry_without_blob_recovers(store):
 def test_store_blob_digest_mismatch_recovers(store):
     """A partially-synced blob (name no longer matches content) must be
     detected before unpickling and fall back to recompile."""
-    assert store.save("k1", _fake_triple(1))
+    assert store.save("k1", _fake_triple(1), [0])
     blob = os.path.join(store.env_dir(), store._read_manifest()["k1"]["blob"])
     with open(blob, "r+b") as fh:
         fh.truncate(1000)

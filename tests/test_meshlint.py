@@ -331,10 +331,10 @@ def test_kernel_contract_memspace_bitcast_negatives(tmp_path):
         # tpulint: tile-ok(deliberate plane split for the packed layout)
         return jax.lax.bitcast_convert_type(x.astype(jnp.uint16),
                                             jnp.uint8)
-        """, "utils/compat.py": """\
 
-    def pallas_hbm_space(pltpu):
-        return getattr(pltpu, "HBM", getattr(pltpu, "ANY", None))
+    def hbm_is_the_name():
+        from jax.experimental.pallas import tpu as pltpu
+        return pltpu.HBM
         """})
     assert kernel_contract.check(pkg) == []
 

@@ -388,7 +388,7 @@ def test_serial_train_parity_multival_vs_planar(monkeypatch):
 
     def counted(*a, **kw):
         calls.append(1)
-        return real(*a, **kw)
+        return real(*a, **dict(kw, use_pallas=False))
 
     monkeypatch.setattr(H, "hist_method",
                         lambda config, dataset=None: "multival_pallas")
@@ -422,7 +422,7 @@ def test_fused_leaf_hist_multival_matches_scatter(monkeypatch):
     fbins = jnp.asarray(ds.feature_bins().astype(np.int32))
     for start, count in ((0, X.shape[0]), (64, 200)):
         out = fl._leaf_hist_multival(data, jnp.int32(start),
-                                     jnp.int32(count), interpret=True)
+                                     jnp.int32(count))
         sel = slice(start, start + count)
         oracle = H.histogram_scatter(fbins[sel], g[sel], h[sel],
                                      ds.max_num_bin)

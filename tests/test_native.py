@@ -1,6 +1,8 @@
 """Native C++ binning kernels must be bit-identical to the Python
 reference implementations (the package's GPU_DEBUG_COMPARE analogue
 for host kernels)."""
+import os
+
 import numpy as np
 import pytest
 
@@ -85,3 +87,42 @@ def test_full_binning_parity_native_vs_python(monkeypatch):
         native._lib, native._tried = saved
     np.testing.assert_array_equal(m1.bin_upper_bound, m2.bin_upper_bound)
     assert m1.num_bin == m2.num_bin
+
+
+def test_loader_keys_library_by_source_hash(tmp_path, monkeypatch):
+    """Only binning.cpp decides what is loaded: a stale `_native.so`, or
+    a library built from another source, is never picked up."""
+    import hashlib
+    import shutil
+    import lightgbm_tpu.native as native
+
+    if native._load() is None:
+        pytest.skip("no native toolchain")
+    src = tmp_path / "binning.cpp"
+    shutil.copy(native._SRC, src)
+    monkeypatch.setattr(native, "_DIR", str(tmp_path))
+    monkeypatch.setattr(native, "_SRC", str(src))
+    # decoys: loading either would raise (not an ELF file)
+    (tmp_path / "_native.so").write_bytes(b"stale")
+    (tmp_path / "_native_000000000000.so").write_bytes(b"foreign")
+
+    def fresh_load():
+        monkeypatch.setattr(native, "_lib", None)
+        monkeypatch.setattr(native, "_tried", False)
+        return native._load()
+
+    want = hashlib.sha256(src.read_bytes()).hexdigest()[:12]
+    assert fresh_load() is not None
+    assert native.library_path() == str(tmp_path / f"_native_{want}.so")
+    assert os.path.exists(native.library_path())
+    assert native.implementation() == f"native (_native_{want}.so)"
+
+    # the source changes: the old build no longer matches and a new
+    # library is built under the new hash
+    with open(src, "a") as fh:
+        fh.write("\n// edited\n")
+    want2 = hashlib.sha256(src.read_bytes()).hexdigest()[:12]
+    assert want2 != want
+    assert fresh_load() is not None
+    assert os.path.basename(native.library_path()) == f"_native_{want2}.so"
+    assert os.path.exists(native.library_path())

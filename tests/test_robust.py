@@ -321,11 +321,6 @@ _CHILD = textwrap.dedent("""
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
             flags + " --xla_force_host_platform_device_count=8").strip()
-    import jax
-    try:
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:
-        pass
     import numpy as np
     import lightgbm_tpu as lgb
     rng = np.random.RandomState(3)
@@ -505,7 +500,7 @@ class TestStoreFallback:
     def test_truncated_pickle_invalidated(self, tmp_path):
         from lightgbm_tpu.compile.store import CorruptBlobError
         st = self._store(tmp_path)
-        assert st.save("k", (b"blob-bytes", {"in": 1}, {"out": 2}))
+        assert st.save("k", (b"blob-bytes", {"in": 1}, {"out": 2}), [0])
         assert st.load("k")[0] == b"blob-bytes"
         install_plan("store.load:truncate")
         with pytest.raises(CorruptBlobError, match="truncated or corrupt"):
@@ -516,7 +511,7 @@ class TestStoreFallback:
     def test_corrupt_pickle_invalidated(self, tmp_path):
         from lightgbm_tpu.compile.store import CorruptBlobError
         st = self._store(tmp_path)
-        assert st.save("k", (b"blob-bytes", None, None))
+        assert st.save("k", (b"blob-bytes", None, None), [0])
         install_plan("store.load:corrupt")
         with pytest.raises(CorruptBlobError):
             st.load("k")

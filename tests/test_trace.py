@@ -357,12 +357,15 @@ def test_perf_regress_passes_within_tolerance(tmp_path, capsys):
     assert "OK" in out and "skipped" in out
 
 
-def test_perf_regress_latest_baseline_discovery():
+def test_perf_regress_latest_baseline_discovery(tmp_path):
     import scripts.check_perf_regress as cpr
-    latest = cpr.latest_baseline()
-    # the repo ships BENCH_r*.json artifacts; the newest parseable one
-    # must be picked
-    assert latest is not None and "BENCH_r" in os.path.basename(latest)
+    rec = {"metric": "higgs_train_wallclock", "value": 1.0, "unit": "s"}
+    (tmp_path / "BENCH_r01.json").write_text(json.dumps({"parsed": rec}))
+    (tmp_path / "BENCH_r02.json").write_text(json.dumps({"parsed": rec}))
+    (tmp_path / "BENCH_r03.json").write_text(json.dumps({"tail": "crash"}))
+    # the newest PARSEABLE artifact is picked
+    latest = cpr.latest_baseline(str(tmp_path))
+    assert os.path.basename(latest) == "BENCH_r02.json"
     assert cpr.load_bench(latest)["metric"].startswith("higgs")
 
 
