@@ -15,6 +15,7 @@ from typing import Any, Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
+from . import obs
 from .config import Config
 from .io.dataset import BinnedDataset, _is_sparse
 from .utils import log
@@ -316,6 +317,8 @@ class Dataset:
 class Booster:
     """Gradient-boosting model handle (reference basic.py:1930)."""
 
+    _setup_reported = False     # the one `set-up:` line of this booster
+
     def __init__(self, params: Optional[Dict[str, Any]] = None,
                  train_set: Optional[Dataset] = None,
                  model_file: Optional[str] = None,
@@ -422,10 +425,18 @@ class Booster:
         (reference basic.py:2315)."""
         if train_set is not None and train_set is not self._train_set:
             raise LightGBMError("Replacing train_set is not supported yet")
-        if fobj is None:
-            return self._gbdt.train_one_iter()
-        grad, hess = fobj(self._curr_pred_for_fobj(), self._train_set)
-        return self.__boost(grad, hess)
+        with obs.span("update"):
+            if fobj is None:
+                stopped = self._gbdt.train_one_iter()
+            else:
+                grad, hess = fobj(self._curr_pred_for_fobj(),
+                                  self._train_set)
+                stopped = self.__boost(grad, hess)
+        if not self._setup_reported:
+            # the first iteration paid for the state and the compile
+            self._setup_reported = True
+            log.info("%s", obs.setup_line())
+        return stopped
 
     def _curr_pred_for_fobj(self):
         """Raw training scores handed to a custom fobj: [N] for

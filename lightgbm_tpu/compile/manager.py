@@ -33,11 +33,13 @@ from __future__ import annotations
 
 import atexit
 import collections
+import contextlib
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
+from jax._src import config as _jax_config
 from jax.experimental.serialize_executable import (deserialize_and_load,
                                                    serialize)
 
@@ -106,7 +108,7 @@ class SharedEntry:
     def __init__(self, manager: "CompileManager", name: str,
                  digest: str, build: Callable[[], Callable],
                  donate_argnums: Tuple[int, ...] = (),
-                 store: bool = True) -> None:
+                 store: bool = True, profiled: bool = False) -> None:
         self.manager = manager
         self.name = name
         self.digest = digest
@@ -116,6 +118,9 @@ class SharedEntry:
         # (io/dataset.py trace_signature), which would pollute the
         # on-disk store with keys no later process can ever hit
         self.store = bool(store)
+        # profiled=True: the program a profile is read by (`lgbm.*`
+        # scopes); its persistent-cache key carries its metadata
+        self.profiled = bool(profiled)
         self._build = build
         self._jfn: Optional[Callable] = None
         # guards _jfn / _key_cache / specs: entries are shared across
@@ -172,6 +177,21 @@ class SharedEntry:
             return mgr.acquire(self, key, args, statics)(*args)
         mgr.proven(key)
         return out
+
+
+def _metadata_in_cache_key():
+    """jax's persistent cache leaves metadata out of its key by default,
+    so a hit hands back the `lgbm.*` scopes and source lines of whichever
+    build compiled the program first, and a profile then names ops by
+    code that is not running. A `profiled` entry is keyed with its
+    metadata, as the manager's own store is by the code fingerprint; the
+    price is one compile of that program per build where jax's cache
+    would have hit, so the programs nobody reads a profile by keep the
+    default. For this thread only (the context-manager form of the
+    flag; jax exports none): an eager op the training thread compiles
+    while a warmup thread is in here keeps the default key and its hit —
+    ops/plane.py's packing, minutes of compile, is one."""
+    return _jax_config.compilation_cache_include_metadata_in_key(True)
 
 
 class JitEntry:
@@ -258,7 +278,8 @@ class CompileManager:
     def shared_entry(self, name: str, sig: Any,
                      build: Callable[[], Callable],
                      donate_argnums: Tuple[int, ...] = (),
-                     store: bool = True) -> SharedEntry:
+                     store: bool = True,
+                     profiled: bool = False) -> SharedEntry:
         """The entry for (name, signature), creating it on first use.
         A pre-existing entry keeps ITS builder: signatures are defined
         precisely so equal digests trace identical programs.
@@ -266,7 +287,7 @@ class CompileManager:
         program donates; it refines the digest (and hence every AOT key
         under it), so toggling donation can never replay an executable
         with the wrong aliasing — and can never retrace one that has
-        the right aliasing."""
+        the right aliasing. `profiled`: see `_metadata_in_cache_key`."""
         digest = S.signature_digest(name, sig, donate_argnums)
         with self._lock:
             entry = self.shared.get(digest)
@@ -274,7 +295,7 @@ class CompileManager:
                 self.shared.move_to_end(digest)
                 return entry
             entry = SharedEntry(self, name, digest, build, donate_argnums,
-                                store=store)
+                                store=store, profiled=profiled)
             self.shared[digest] = entry
             while len(self.shared) > _MAX_SHARED_ENTRIES:
                 self.shared.popitem(last=False)
@@ -357,7 +378,9 @@ class CompileManager:
             lowered = entry.jit_fn().lower(*args, **statics)
         t1 = time.perf_counter()
         hits = getattr(_tls, "jax_cache_hits", 0)
-        exe = lowered.compile()
+        with (_metadata_in_cache_key() if entry.profiled
+              else contextlib.nullcontext()):
+            exe = lowered.compile()
         from_jax_cache = getattr(_tls, "jax_cache_hits", 0) > hits
         elapsed = time.perf_counter() - t0
         self.add_time("compile", elapsed)

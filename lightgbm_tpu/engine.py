@@ -15,6 +15,7 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from . import callback as callback_mod
+from . import obs
 from .basic import Booster, Dataset, LightGBMError
 from .config import Config, _ALIASES
 from .utils import log
@@ -45,7 +46,6 @@ def _telemetry_end_iteration(telemetry, booster, iteration: int,
     pays this) so the wall time is honest, then attach model stats and
     eval metrics."""
     import jax
-    from . import obs
     gbdt = booster._gbdt
     extra: Dict[str, Any] = {}
     if not telemetry.record_consumers_active():
@@ -183,7 +183,7 @@ def train(params: Dict[str, Any], train_set: Dataset,
         train_set.init_score = init_score.T.reshape(-1) if init_score.ndim == 2 \
             else init_score
 
-    with global_timer.scope("dataset construction + learner build"):
+    with obs.span("dataset construction + learner build"):
         booster = Booster(params=params, train_set=train_set)
     plan = booster._gbdt.execution_plan()
     log.info("Training on backend=%s (%s x%d): tier=%s learner=%s "
@@ -282,7 +282,6 @@ def train(params: Dict[str, Any], train_set: Dataset,
                         "init_model was given (checkpoints will still "
                         "be written)", ckpt_dir)
 
-    from . import obs
     telemetry = obs.TelemetrySession.from_config(booster._gbdt.config)
     if telemetry is not None:
         telemetry.start()
@@ -544,7 +543,7 @@ def train(params: Dict[str, Any], train_set: Dataset,
     # fused path trains blind between periodic stop checks; drop any
     # trailing all-degenerate iterations it may have accumulated
     if getattr(booster._gbdt, "_fused", None) is not None:
-        with global_timer.scope("degenerate-tail check (device sync)"):
+        with obs.span("degenerate-tail check (device sync)"):
             booster._gbdt._trim_degenerate_tail()
     if global_timer.enabled and global_timer.acc:
         from .utils import log as _log

@@ -28,6 +28,7 @@ from typing import Any, Dict, List, Optional, Sequence, Union
 import numpy as np
 
 from ..config import Config
+from ..obs.spans import span
 from ..utils import log
 from .binning import (BIN_CATEGORICAL, BIN_NUMERICAL, K_ZERO_THRESHOLD,
                       MISSING_NAN, MISSING_NONE, MISSING_ZERO, BinMapper)
@@ -340,17 +341,19 @@ class BinnedDataset:
         # --- sampling for bin finding (dataset_loader.cpp:120-165) ---
         sample_cnt = min(config.bin_construct_sample_cnt, n)
         rng = np.random.RandomState(config.data_random_seed)
-        if sample_cnt < n:
-            sample_idx = np.sort(rng.choice(n, size=sample_cnt, replace=False))
-            if sparse_input:
-                rows = data_csr if data_csr is not None else data.tocsr()
-                sample = rows[sample_idx].tocsc()
+        with span("dataset/sample", stage="construct/sample"):
+            if sample_cnt < n:
+                sample_idx = np.sort(rng.choice(n, size=sample_cnt,
+                                                replace=False))
+                if sparse_input:
+                    rows = data_csr if data_csr is not None else data.tocsr()
+                    sample = rows[sample_idx].tocsc()
+                else:
+                    sample = data[sample_idx]
             else:
-                sample = data[sample_idx]
-        else:
-            sample = data
-        if not sparse_input:
-            sample = np.asarray(sample, dtype=np.float64)
+                sample = data
+            if not sparse_input:
+                sample = np.asarray(sample, dtype=np.float64)
 
         def sample_col_nonzeros(f):
             """(row_indices, values) of the sample column's stored
@@ -362,24 +365,25 @@ class BinnedDataset:
             return np.arange(sample_cnt), col
 
         # --- per-feature bin finding ---
-        if config.num_machines > 1:
-            # distributed construction protocol: per-rank owned-feature
-            # binning + mapper allgather over the mesh (reference
-            # dataset_loader.cpp:917-990). Single-controller mode bins
-            # over the full in-process sample, so boundaries are
-            # bit-identical to single-machine construction. Sparse
-            # samples stay CSC end-to-end (round-5: the dense-only
-            # restriction is gone — column slices come from the CSC
-            # structure inside find_bins_for_features)
-            from .distributed import distributed_find_bin_mappers
-            mappers = distributed_find_bin_mappers(
-                sample if sparse_input
-                else np.asarray(sample, dtype=np.float64),
-                config, cat_set)
-        else:
-            mappers = cls._find_bin_mappers_local(
-                sample_col_nonzeros, total_features, sample_cnt, config,
-                cat_set)
+        with span("dataset/find_bins", stage="construct/find_bins"):
+            if config.num_machines > 1:
+                # distributed construction protocol: per-rank owned-feature
+                # binning + mapper allgather over the mesh (reference
+                # dataset_loader.cpp:917-990). Single-controller mode bins
+                # over the full in-process sample, so boundaries are
+                # bit-identical to single-machine construction. Sparse
+                # samples stay CSC end-to-end (round-5: the dense-only
+                # restriction is gone — column slices come from the CSC
+                # structure inside find_bins_for_features)
+                from .distributed import distributed_find_bin_mappers
+                mappers = distributed_find_bin_mappers(
+                    sample if sparse_input
+                    else np.asarray(sample, dtype=np.float64),
+                    config, cat_set)
+            else:
+                mappers = cls._find_bin_mappers_local(
+                    sample_col_nonzeros, total_features, sample_cnt, config,
+                    cat_set)
 
         used = [f for f in range(total_features) if not mappers[f].is_trivial]
         if not used:
@@ -393,28 +397,30 @@ class BinnedDataset:
                 for f in used]
 
         # --- EFB bundling decision over the sample (dataset.cpp:50-302) ---
-        if config.enable_bundle and len(used) > 1:
-            from .efb import bundle_eligible
-            nonzero_rows: List[np.ndarray] = []
-            bundle_ok: List[bool] = []
-            empty = np.empty(0, dtype=np.int64)
-            for i, f in enumerate(used):
-                m = ds.bin_mappers[i]
-                ok = bundle_eligible(m) and m.sparse_rate >= 0.5
-                bundle_ok.append(ok)
-                if not ok:
-                    nonzero_rows.append(empty)
-                    continue
-                idx, vals = sample_col_nonzeros(f)
-                b = m.values_to_bins(vals)
-                nonzero_rows.append(np.asarray(idx)[b != m.most_freq_bin])
-            ds.bundles = build_bundles(
-                nonzero_rows, ds.bin_mappers, sample_cnt, True,
-                bundle_ok=bundle_ok,
-                max_bundle_bins=config.efb_max_bundle_bins,
-                max_conflict_rate=config.efb_max_conflict_rate)
-            if ds.bundles.is_trivial:
-                ds.bundles = None
+        with span("dataset/bundle", stage="construct/bundle"):
+            if config.enable_bundle and len(used) > 1:
+                from .efb import bundle_eligible
+                nonzero_rows: List[np.ndarray] = []
+                bundle_ok: List[bool] = []
+                empty = np.empty(0, dtype=np.int64)
+                for i, f in enumerate(used):
+                    m = ds.bin_mappers[i]
+                    ok = bundle_eligible(m) and m.sparse_rate >= 0.5
+                    bundle_ok.append(ok)
+                    if not ok:
+                        nonzero_rows.append(empty)
+                        continue
+                    idx, vals = sample_col_nonzeros(f)
+                    b = m.values_to_bins(vals)
+                    nonzero_rows.append(
+                        np.asarray(idx)[b != m.most_freq_bin])
+                ds.bundles = build_bundles(
+                    nonzero_rows, ds.bin_mappers, sample_cnt, True,
+                    bundle_ok=bundle_ok,
+                    max_bundle_bins=config.efb_max_bundle_bins,
+                    max_conflict_rate=config.efb_max_conflict_rate)
+                if ds.bundles.is_trivial:
+                    ds.bundles = None
         ds._apply_mappers(data)
         return ds
 
@@ -424,6 +430,13 @@ class BinnedDataset:
         [N, num_groups] bundle codes otherwise (reference
         FeatureGroup::PushData / Bin::Push; sparse inputs touch only
         their stored entries — never densified)."""
+        with span("dataset/bin_rows", stage="construct/bin_rows"):
+            self.bins = self._bin_rows(data)
+        self.num_data = data.shape[0]
+        with span("dataset/occupancy", stage="construct/occupancy"):
+            self._measure_occupancy()
+
+    def _bin_rows(self, data: np.ndarray) -> np.ndarray:
         n = data.shape[0]
         sparse = _is_sparse(data)
         mappers = self.bin_mappers
@@ -482,9 +495,7 @@ class BinnedDataset:
                         slot = b - (b > mfb)
                         code[rows] = (bt.offset_of[i] + slot).astype(dtype)
                     bins[:, g] = code
-        self.bins = bins
-        self.num_data = n
-        self._measure_occupancy()
+        return bins
 
     def _measure_occupancy(self) -> None:
         """Record construct-time row-occupancy statistics (mean/max

@@ -6,8 +6,10 @@ Layers:
   + per-iteration snapshots; one process-global active registry that
   instrumentation reads with a single `is None` check.
 - `span` / `instrument_kernel` / `step_span` (obs/spans.py): scopes
-  that feed the utils/timer.py table, the registry,
-  jax.profiler trace annotations, and the runtime tracer at once.
+  that feed the utils/timer.py table, the registry, jax.profiler trace
+  annotations (`lgbm:<name>`, on every call), the runtime tracer, and
+  the always-on set-up stage table (`stage_seconds`, `setup_line`) at
+  once.
 - `Tracer` (obs/trace.py): bounded ring buffer of phase/sync/memory/
   collective events, exported as a Perfetto-loadable trace.json;
   `obs/report.py` summarizes one (also `python -m lightgbm_tpu
@@ -30,9 +32,10 @@ keeps the pipelined dispatch-ahead loop — no per-iteration stream
 sync, no device stat fetches — and the one blocking sync the plane is
 allowed per iteration is the fleet allgather it piggybacks on.
 
-Everything is off by default: with no active registry, no timer, no
-tracer, and no profile dir, the instrumentation fast paths reduce to a
-global load per call.
+Everything but the profiler annotation and the set-up stage table is
+off by default: with no active registry, no timer, no tracer, and no
+profile dir, the instrumentation fast paths reduce to a global load and
+a TraceMe (a flag test while no profiler session runs) per call.
 """
 from __future__ import annotations
 
@@ -46,8 +49,8 @@ from .registry import (LatencyHistogram, MetricsRegistry, activate, active,
                        deactivate)
 from .sink import (SCHEMA_MINOR, SCHEMA_VERSION, JsonlSink, read_jsonl,
                    validate_bench_record, validate_record)
-from .spans import (instrument_kernel, span, start_profiler, step_span,
-                    stop_profiler)
+from .spans import (instrument_kernel, setup_line, span, stage_seconds,
+                    start_profiler, step_span, stop_profiler)
 from .trace import (Tracer, activate_tracer, active_tracer,
                     deactivate_tracer, install_sync_tracing,
                     live_array_bytes, merge_trace_events, merge_trace_files,
@@ -59,6 +62,7 @@ __all__ = [
     "SCHEMA_VERSION", "SCHEMA_MINOR", "JsonlSink", "read_jsonl",
     "validate_record",
     "validate_bench_record", "span", "step_span", "instrument_kernel",
+    "stage_seconds", "setup_line",
     "start_profiler", "stop_profiler", "TelemetrySession",
     "Tracer", "activate_tracer", "active_tracer", "deactivate_tracer",
     "install_sync_tracing", "uninstall_sync_tracing", "live_array_bytes",

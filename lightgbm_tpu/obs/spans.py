@@ -1,65 +1,112 @@
-"""span(): one scope, four consumers.
+"""span(): one scope, five consumers.
 
 A `span` feeds (a) the `utils/timer.py` global table — same names, so
 the LGBM_TPU_TIMETAG phase table is unchanged, (b) the active
 `MetricsRegistry` phase times when a `phase=` is given, (c) a
-`jax.profiler.TraceAnnotation` range, so host scopes line up with
-device traces in XProf when `profile_dir` is set, and (d) a complete
-event in the active runtime `Tracer` (obs/trace.py), so the Perfetto
-timeline shows every instrumented scope in order. When none of the
-consumers is enabled, a span is a bare `yield` — no annotation, no
-clock read.
+`jax.profiler.TraceAnnotation` range named `lgbm:<name>`, on every
+call, so a profiler session started by anyone (`profile_dir`, a
+benchmark, an operator) shows the program's host scopes on the clock of
+the device ops, (d) a complete event in the active runtime `Tracer`
+(obs/trace.py), so the Perfetto timeline shows every instrumented scope
+in order, and (e) the process-global set-up stage table when a
+`stage=` is given (`stage_seconds()`; `setup_line()` is the operator's
+view of it). When none of (a), (b), (d), (e) is on, a span is the
+annotation around a bare `yield` — a TraceMe with no profiler session
+running is a flag test, and no clock is read.
 
-Exception safety: the consumer writes in the finally block run inside
-their own try/finally chain, so a raising consumer (or a raising body)
-can never leak an open profiler annotation or corrupt the timeline —
-the annotation ALWAYS closes, and a tracer event is only appended as a
-fully-formed [t0, t1] tuple. Spans nest re-entrantly: all pairing
-state lives in the generator's locals.
+Exception safety: the annotation is the outermost `with`, so it ALWAYS
+closes; the consumer writes run in the finally block inside their own
+try/finally, and a tracer event is only appended as a fully-formed
+[t0, t1] tuple. Spans nest re-entrantly: all pairing state lives in the
+generator's locals.
 
 `instrument_kernel` wraps a jitted callable once (at lru-cache build
-time) so every dispatch call site is timed without editing each call;
-the disabled fast path is one global load + one `is None` check.
+time) so every dispatch call site is annotated and timed without editing
+each call; the disabled fast path is the annotation, one global load
+and one `is None` check.
 """
 from __future__ import annotations
 
 import contextlib
+import threading
 import time
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
+
+import jax.profiler as _profiler
 
 from ..utils import timer as _timer
 from . import registry as _registry
 from . import trace as _trace
 
+ANNOTATION_PREFIX = "lgbm:"
 
-def _trace_annotation(name: str):
-    try:
-        import jax.profiler
-        ann = jax.profiler.TraceAnnotation(name)
-        ann.__enter__()
-        return ann
-    except Exception:
-        return None
+# set-up stage -> [seconds, calls], for the life of the process. Written
+# at set-up sites only (O(10) per Booster, never inside update()).
+_stages: Dict[str, list] = {}
+_stages_lock = threading.Lock()
+# what the last `set-up:` line had seen, so that the next reports the gain
+_reported: Dict[str, float] = {}
+
+
+def stage_seconds() -> Dict[str, Tuple[float, int]]:
+    """{stage: (seconds, calls)} of every `span(..., stage=...)` this
+    process has closed: `construct/*` (io/dataset.py), `state/*` (the
+    fused growers). Always on; compile seconds are the compile manager's
+    (`compile.get_manager().snapshot()`)."""
+    with _stages_lock:
+        return {k: (v[0], v[1]) for k, v in _stages.items()}
+
+
+def _add_stage(stage: str, seconds: float) -> None:
+    with _stages_lock:
+        slot = _stages.setdefault(stage, [0.0, 0])
+        slot[0] += seconds
+        slot[1] += 1
+
+
+def setup_line() -> str:
+    """`set-up: construct … (sample …, …), state … (…), compile … (…)`:
+    what the stage table and the compile manager's always-on counters
+    gained since the last call, in seconds."""
+    from ..compile import get_manager
+    now = {k: v[0] for k, v in stage_seconds().items()}
+    snap = get_manager().snapshot()
+    lower = float(snap.get("lowering_s", 0.0))
+    now["compile/lower"] = lower
+    now["compile/xla"] = float(snap.get("compile_s", 0.0)) - lower
+    now["compile/aot_load"] = float(snap.get("aot_load_s", 0.0))
+    groups: Dict[str, list] = {}
+    with _stages_lock:
+        for key, total in now.items():
+            group, _, part = key.partition("/")
+            groups.setdefault(group, []).append(
+                (part, total - _reported.get(key, 0.0)))
+        _reported.update(now)
+    return "set-up: " + ", ".join(
+        f"{group} {sum(dt for _, dt in parts):.1f} s ("
+        + ", ".join(f"{part} {dt:.1f}" for part, dt in parts) + ")"
+        for group, parts in groups.items())
 
 
 @contextlib.contextmanager
-def span(name: str, phase: Optional[str] = None):
+def span(name: str, phase: Optional[str] = None,
+         stage: Optional[str] = None):
     reg = _registry.active()
     gt = _timer.global_timer
     tr = _trace.active_tracer()
-    if reg is None and not gt.enabled and tr is None:
-        yield
-        return
-    ann = _trace_annotation(name)
-    tr_t0 = tr.now_ns() if tr is not None else 0
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        # the annotation must close even when a consumer write raises
+    with _profiler.TraceAnnotation(ANNOTATION_PREFIX + name):
+        if reg is None and not gt.enabled and tr is None and stage is None:
+            yield
+            return
+        tr_t0 = tr.now_ns() if tr is not None else 0
+        t0 = time.perf_counter()
         try:
+            yield
+        finally:
             dt = time.perf_counter() - t0
             try:
+                if stage is not None:
+                    _add_stage(stage, dt)
                 if gt.enabled:
                     gt.acc[name] += dt
                     gt.cnt[name] += 1
@@ -70,9 +117,6 @@ def span(name: str, phase: Optional[str] = None):
                 if tr is not None:
                     tr.complete(name, "phase", tr_t0, tr.now_ns(),
                                 {"phase": phase} if phase else None)
-        finally:
-            if ann is not None:
-                ann.__exit__(None, None, None)
 
 
 @contextlib.contextmanager
@@ -80,19 +124,9 @@ def step_span(iteration: int):
     """StepTraceAnnotation wrapper: marks one boosting iteration as an
     XProf "step" so the trace viewer groups device activity per
     iteration, aligned with the JSONL records."""
-    ann = None
-    try:
-        import jax.profiler
-        ann = jax.profiler.StepTraceAnnotation("boosting_iteration",
-                                               step_num=int(iteration))
-        ann.__enter__()
-    except Exception:
-        ann = None
-    try:
+    with _profiler.StepTraceAnnotation("boosting_iteration",
+                                       step_num=int(iteration)):
         yield
-    finally:
-        if ann is not None:
-            ann.__exit__(None, None, None)
 
 
 def instrument_kernel(fn, phase: str, name: Optional[str] = None,
@@ -104,6 +138,7 @@ def instrument_kernel(fn, phase: str, name: Optional[str] = None,
     host-side dispatch latency: under async dispatch it covers enqueue,
     on the synchronous test path it covers the compute too."""
     label = name or f"kernel/{phase}"
+    annotation = ANNOTATION_PREFIX + label
     if collective is not None:
         coll_op, coll_bytes = collective[0], int(collective[1])
         coll_axis = collective[2] if len(collective) > 2 else ""
@@ -113,7 +148,8 @@ def instrument_kernel(fn, phase: str, name: Optional[str] = None,
         tr = _trace.active_tracer()
         if reg is None and not _timer.global_timer.enabled \
                 and tr is None:
-            return fn(*args, **kwargs)
+            with _profiler.TraceAnnotation(annotation):
+                return fn(*args, **kwargs)
         tr_t0 = tr.now_ns() if tr is not None else 0
         t0 = time.perf_counter()
         with span(label, phase=phase):
@@ -150,8 +186,7 @@ def start_profiler(profile_dir: str) -> bool:
     if _PROFILING or not profile_dir:
         return False
     try:
-        import jax.profiler
-        jax.profiler.start_trace(profile_dir)
+        _profiler.start_trace(profile_dir)
         _PROFILING = True
         return True
     except Exception as exc:
@@ -166,8 +201,7 @@ def stop_profiler() -> None:
     if not _PROFILING:
         return
     try:
-        import jax.profiler
-        jax.profiler.stop_trace()
+        _profiler.stop_trace()
     except Exception:
         pass
     _PROFILING = False

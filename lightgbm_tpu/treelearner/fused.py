@@ -54,6 +54,7 @@ from ..config import Config
 from ..io.dataset import BinnedDataset
 from ..io.binning import BIN_CATEGORICAL
 from ..models.tree import Tree
+from ..obs import instrument_kernel, span
 from ..ops import histogram as H
 from ..ops import plane
 from ..ops import quantize as Q
@@ -457,7 +458,6 @@ class FusedSerialGrower:
         self._caps = capacity_ladder(top, tile * 4, factor)
         self._dyn_tile = self._branch_tile(top)
         self._dyn_hist_rb = self._branch_hist_rb(top)
-        from ..obs import instrument_kernel
         # jit entry points go through the AOT compile manager
         # (lightgbm_tpu/compile): same-signature growers share one
         # executable, executables persist on disk, and warmup threads
@@ -479,7 +479,7 @@ class FusedSerialGrower:
             self._iter_entry = self._mgr.shared_entry(
                 "fused/train_iter", sig,
                 lambda: jax.jit(self._entry_train_iter, donate_argnums=1),
-                donate_argnums=(1,))
+                donate_argnums=(1,), profiled=True)
             self._sync_entry = self._mgr.shared_entry(
                 "fused/sync_scores", sig,
                 lambda: jax.jit(self._sync_scores))
@@ -511,22 +511,28 @@ class FusedSerialGrower:
     # ------------------------------------------------------------------
     def codes_planes(self) -> jax.Array:
         if self._codes_planes_dev is None:
-            if self._bins_dev is not None:
-                self._codes_planes_dev = plane.build_codes_planes(
-                    self._bins_dev, self.layout)
-            elif self.dataset.bins.nbytes > (1 << 31):
-                # chunked host->device packing: a one-shot row-major
-                # upload at wide-EFB scale (13.2M x 581 = 7.7 GB u8)
-                # OOMs HBM next to the planar state before the async
-                # free lands
-                self._codes_planes_dev = plane.build_codes_planes_chunked(
-                    self.dataset.bins, self.layout)
-            else:
-                # transient row-major upload; the persistent path never
-                # needs the row-major copy again
-                self._codes_planes_dev = plane.build_codes_planes(
-                    jnp.asarray(self.dataset.bins), self.layout)
+            # upload and pack are asynchronous: the stage is closed by
+            # one block
+            with span("fused/pack_codes", stage="state/pack_codes"):
+                # tpulint: sync-ok(set-up, once per state build, ahead of a compile-paying call that blocks anyway)
+                self._codes_planes_dev = jax.block_until_ready(
+                    self._build_codes_planes())
         return self._codes_planes_dev
+
+    def _build_codes_planes(self) -> jax.Array:
+        if self._bins_dev is not None:
+            return plane.build_codes_planes(self._bins_dev, self.layout)
+        if self.dataset.bins.nbytes > (1 << 31):
+            # chunked host->device packing: a one-shot row-major
+            # upload at wide-EFB scale (13.2M x 581 = 7.7 GB u8)
+            # OOMs HBM next to the planar state before the async
+            # free lands
+            return plane.build_codes_planes_chunked(
+                self.dataset.bins, self.layout)
+        # transient row-major upload; the persistent path never
+        # needs the row-major copy again
+        return plane.build_codes_planes(
+            jnp.asarray(self.dataset.bins), self.layout)
 
     # -- AOT compile manager integration -------------------------------
     def _tables(self) -> Dict:
@@ -718,14 +724,16 @@ class FusedSerialGrower:
         chip."""
         if self.psum_axis is None:
             return x
-        return jax.lax.psum(x, self.psum_axis)
+        with jax.named_scope("lgbm.allreduce"):
+            return jax.lax.psum(x, self.psum_axis)
 
     def _psum_max(self, x):
         """Cross-shard max — identity on one chip (the quantization
         scales must agree across shards before any int32 hist psum)."""
         if self.psum_axis is None:
             return x
-        return jax.lax.pmax(x, self.psum_axis)
+        with jax.named_scope("lgbm.allreduce"):
+            return jax.lax.pmax(x, self.psum_axis)
 
     def _window_hist(self, b, g, h):
         """Histogram of bin codes with masked weights; EFB bundle
@@ -952,63 +960,68 @@ class FusedSerialGrower:
         bynode = feature_mask.ndim == 2
         root_mask = feature_mask[0] if bynode else feature_mask
 
-        root_hist = self._psum(self._leaf_hist_switch(data, jnp.int32(0),
-                                                      bag_cnt))
-        bag_cnt_g = self._psum(jnp.asarray(bag_cnt, i32))
-        if quant:
-            sum_g = jnp.sum(root_hist[0, :, 0]).astype(f32) * qscales[0]
-            sum_h = jnp.sum(root_hist[0, :, 1]).astype(f32) * qscales[1]
-        else:
-            sum_g = jnp.sum(root_hist[0, :, 0])
-            sum_h = jnp.sum(root_hist[0, :, 1])
-        root_best = self._scan_leaf(root_hist, sum_g, sum_h, bag_cnt_g,
-                                    f32(0.0), f32(-jnp.inf), f32(jnp.inf),
-                                    root_mask, qscales=qscales)
+        with jax.named_scope("lgbm.root_hist"):
+            root_hist = self._psum(self._leaf_hist_switch(
+                data, jnp.int32(0), bag_cnt))
+            bag_cnt_g = self._psum(jnp.asarray(bag_cnt, i32))
+        with jax.named_scope("lgbm.split_scan"):
+            if quant:
+                sum_g = jnp.sum(root_hist[0, :, 0]).astype(f32) * qscales[0]
+                sum_h = jnp.sum(root_hist[0, :, 1]).astype(f32) * qscales[1]
+            else:
+                sum_g = jnp.sum(root_hist[0, :, 0])
+                sum_h = jnp.sum(root_hist[0, :, 1])
+            root_best = self._scan_leaf(
+                root_hist, sum_g, sum_h, bag_cnt_g, f32(0.0),
+                f32(-jnp.inf), f32(jnp.inf), root_mask, qscales=qscales)
 
         def arr(val, dtype=f32):
             return jnp.full((L,), val, dtype)
 
-        st = FusedTreeState(
-            data=data, n_leaves=i32(1),
-            leaf_start=arr(0, i32).at[0].set(0),
-            leaf_count=arr(0, i32).at[0].set(bag_cnt),
-            leaf_count_g=arr(0, i32).at[0].set(bag_cnt_g),
-            leaf_sum_g=arr(0.0).at[0].set(sum_g),
-            leaf_sum_h=arr(0.0).at[0].set(sum_h),
-            leaf_output=arr(0.0),
-            leaf_depth=arr(0, i32),
-            leaf_parent=arr(-1, i32),
-            leaf_cmin=arr(-jnp.inf), leaf_cmax=arr(jnp.inf),
-            best_gain=arr(NEG_INF).at[0].set(root_best["gain"]),
-            best_feature=arr(0, i32).at[0].set(root_best["feature"]),
-            best_thr=arr(0, i32).at[0].set(root_best["thr"]),
-            best_dl=arr(False, bool).at[0].set(root_best["dl"]),
-            best_lg=arr(0.0).at[0].set(root_best["lg"]),
-            best_lh=arr(0.0).at[0].set(root_best["lh"]),
-            best_lcnt=arr(0, i32).at[0].set(root_best["lcnt"]),
-            best_lout=arr(0.0).at[0].set(root_best["lout"]),
-            best_rg=arr(0.0).at[0].set(root_best["rg"]),
-            best_rh=arr(0.0).at[0].set(root_best["rh"]),
-            best_rcnt=arr(0, i32).at[0].set(root_best["rcnt"]),
-            best_rout=arr(0.0).at[0].set(root_best["rout"]),
-            best_cat=arr(False, bool).at[0].set(root_best["cat"]),
-            best_bits=jnp.zeros((L, 8), i32).at[0].set(root_best["bits"]),
-            hist_pool=(jnp.zeros((L, F, B, 2), i32 if quant else f32)
-                       .at[0].set(root_hist)
-                       if self._use_hist_pool
-                       else jnp.zeros((1, 1, 1, 2), i32 if quant else f32)),
-            t_feature=jnp.zeros((L - 1,), i32),
-            t_thr=jnp.zeros((L - 1,), i32),
-            t_dl=jnp.zeros((L - 1,), bool),
-            t_left=jnp.zeros((L - 1,), i32),
-            t_right=jnp.zeros((L - 1,), i32),
-            t_gain=jnp.zeros((L - 1,), f32),
-            t_ivalue=jnp.zeros((L - 1,), f32),
-            t_iweight=jnp.zeros((L - 1,), f32),
-            t_icount=jnp.zeros((L - 1,), i32),
-            t_cat=jnp.zeros((L - 1,), bool),
-            t_bits=jnp.zeros((L - 1, 8), i32),
-        )
+        with jax.named_scope("lgbm.bookkeeping"):
+            st = FusedTreeState(
+                data=data, n_leaves=i32(1),
+                leaf_start=arr(0, i32).at[0].set(0),
+                leaf_count=arr(0, i32).at[0].set(bag_cnt),
+                leaf_count_g=arr(0, i32).at[0].set(bag_cnt_g),
+                leaf_sum_g=arr(0.0).at[0].set(sum_g),
+                leaf_sum_h=arr(0.0).at[0].set(sum_h),
+                leaf_output=arr(0.0),
+                leaf_depth=arr(0, i32),
+                leaf_parent=arr(-1, i32),
+                leaf_cmin=arr(-jnp.inf), leaf_cmax=arr(jnp.inf),
+                best_gain=arr(NEG_INF).at[0].set(root_best["gain"]),
+                best_feature=arr(0, i32).at[0].set(root_best["feature"]),
+                best_thr=arr(0, i32).at[0].set(root_best["thr"]),
+                best_dl=arr(False, bool).at[0].set(root_best["dl"]),
+                best_lg=arr(0.0).at[0].set(root_best["lg"]),
+                best_lh=arr(0.0).at[0].set(root_best["lh"]),
+                best_lcnt=arr(0, i32).at[0].set(root_best["lcnt"]),
+                best_lout=arr(0.0).at[0].set(root_best["lout"]),
+                best_rg=arr(0.0).at[0].set(root_best["rg"]),
+                best_rh=arr(0.0).at[0].set(root_best["rh"]),
+                best_rcnt=arr(0, i32).at[0].set(root_best["rcnt"]),
+                best_rout=arr(0.0).at[0].set(root_best["rout"]),
+                best_cat=arr(False, bool).at[0].set(root_best["cat"]),
+                best_bits=jnp.zeros((L, 8), i32).at[0].set(
+                    root_best["bits"]),
+                hist_pool=(jnp.zeros((L, F, B, 2), i32 if quant else f32)
+                           .at[0].set(root_hist)
+                           if self._use_hist_pool
+                           else jnp.zeros((1, 1, 1, 2),
+                                          i32 if quant else f32)),
+                t_feature=jnp.zeros((L - 1,), i32),
+                t_thr=jnp.zeros((L - 1,), i32),
+                t_dl=jnp.zeros((L - 1,), bool),
+                t_left=jnp.zeros((L - 1,), i32),
+                t_right=jnp.zeros((L - 1,), i32),
+                t_gain=jnp.zeros((L - 1,), f32),
+                t_ivalue=jnp.zeros((L - 1,), f32),
+                t_iweight=jnp.zeros((L - 1,), f32),
+                t_icount=jnp.zeros((L - 1,), i32),
+                t_cat=jnp.zeros((L - 1,), bool),
+                t_bits=jnp.zeros((L - 1, 8), i32),
+            )
 
         max_depth = self.config.max_depth
         mono_dev = self.meta.monotone
@@ -1026,22 +1039,23 @@ class FusedSerialGrower:
             from the pooled histogram) — reference ForceSplits,
             serial_tree_learner.cpp:427."""
             if rec is None:
-                gains = st.best_gain
-                if max_depth > 0:
-                    gains = jnp.where(st.leaf_depth >= max_depth, NEG_INF,
-                                      gains)
-                leaf = jnp.argmax(gains).astype(i32)
-                feat = st.best_feature[leaf]
-                thr = st.best_thr[leaf]
-                dl = st.best_dl[leaf]
-                cat = st.best_cat[leaf]
-                bits = st.best_bits[leaf]
-                rec = dict(
-                    gain=st.best_gain[leaf],
-                    lg=st.best_lg[leaf], lh=st.best_lh[leaf],
-                    lout=st.best_lout[leaf],
-                    rg=st.best_rg[leaf], rh=st.best_rh[leaf],
-                    rout=st.best_rout[leaf])
+                with jax.named_scope("lgbm.pick_leaf"):
+                    gains = st.best_gain
+                    if max_depth > 0:
+                        gains = jnp.where(st.leaf_depth >= max_depth,
+                                          NEG_INF, gains)
+                    leaf = jnp.argmax(gains).astype(i32)
+                    feat = st.best_feature[leaf]
+                    thr = st.best_thr[leaf]
+                    dl = st.best_dl[leaf]
+                    cat = st.best_cat[leaf]
+                    bits = st.best_bits[leaf]
+                    rec = dict(
+                        gain=st.best_gain[leaf],
+                        lg=st.best_lg[leaf], lh=st.best_lh[leaf],
+                        lout=st.best_lout[leaf],
+                        rg=st.best_rg[leaf], rh=st.best_rh[leaf],
+                        rout=st.best_rout[leaf])
             else:
                 leaf = rec["leaf"]
                 feat, thr = rec["feature"], rec["threshold"]
@@ -1053,34 +1067,37 @@ class FusedSerialGrower:
             miss = self.feature_miss_bin[feat]
 
             # --- tree bookkeeping (Tree::Split semantics, tree.h:61) ---
-            parent = st.leaf_parent[leaf]
-            has_parent = parent >= 0
-            pl = st.t_left[jnp.maximum(parent, 0)]
-            fix_left = has_parent & (pl == ~leaf)
-            t_left = st.t_left.at[jnp.maximum(parent, 0)].set(
-                jnp.where(fix_left, node, st.t_left[jnp.maximum(parent, 0)]))
-            t_right = st.t_right.at[jnp.maximum(parent, 0)].set(
-                jnp.where(has_parent & ~fix_left, node,
-                          st.t_right[jnp.maximum(parent, 0)]))
-            t_feature = st.t_feature.at[node].set(feat)
-            t_thr = st.t_thr.at[node].set(thr)
-            t_dl = st.t_dl.at[node].set(dl)
-            t_left = t_left.at[node].set(~leaf)
-            t_right = t_right.at[node].set(~new_leaf)
-            t_gain = st.t_gain.at[node].set(rec["gain"])
-            t_ivalue = st.t_ivalue.at[node].set(st.leaf_output[leaf])
-            t_iweight = st.t_iweight.at[node].set(st.leaf_sum_h[leaf])
-            t_icount = st.t_icount.at[node].set(st.leaf_count_g[leaf])
-            t_cat = st.t_cat.at[node].set(cat)
-            t_bits = st.t_bits.at[node].set(bits)
+            with jax.named_scope("lgbm.bookkeeping"):
+                parent = st.leaf_parent[leaf]
+                has_parent = parent >= 0
+                pl = st.t_left[jnp.maximum(parent, 0)]
+                fix_left = has_parent & (pl == ~leaf)
+                t_left = st.t_left.at[jnp.maximum(parent, 0)].set(
+                    jnp.where(fix_left, node,
+                              st.t_left[jnp.maximum(parent, 0)]))
+                t_right = st.t_right.at[jnp.maximum(parent, 0)].set(
+                    jnp.where(has_parent & ~fix_left, node,
+                              st.t_right[jnp.maximum(parent, 0)]))
+                t_feature = st.t_feature.at[node].set(feat)
+                t_thr = st.t_thr.at[node].set(thr)
+                t_dl = st.t_dl.at[node].set(dl)
+                t_left = t_left.at[node].set(~leaf)
+                t_right = t_right.at[node].set(~new_leaf)
+                t_gain = st.t_gain.at[node].set(rec["gain"])
+                t_ivalue = st.t_ivalue.at[node].set(st.leaf_output[leaf])
+                t_iweight = st.t_iweight.at[node].set(st.leaf_sum_h[leaf])
+                t_icount = st.t_icount.at[node].set(st.leaf_count_g[leaf])
+                t_cat = st.t_cat.at[node].set(cat)
+                t_bits = st.t_bits.at[node].set(bits)
 
             # --- shard-local partition; counts reduced globally ---
             start = st.leaf_start[leaf]
             count = st.leaf_count[leaf]
             count_g = st.leaf_count_g[leaf]
-            new_data, nleft = self._split_step(
-                st.data, start, count, feat, thr, dl, miss,
-                cat=cat, bits=bits)
+            with jax.named_scope("lgbm.partition"):
+                new_data, nleft = self._split_step(
+                    st.data, start, count, feat, thr, dl, miss,
+                    cat=cat, bits=bits)
             nright = count - nleft
             nleft_g = self._psum(nleft)
             nright_g = count_g - nleft_g
@@ -1090,103 +1107,115 @@ class FusedSerialGrower:
             left_smaller = nleft_g <= nright_g
             s_start = jnp.where(left_smaller, start, start + nleft)
             s_count = jnp.where(left_smaller, nleft, nright)
-            hist_small = self._psum(
-                self._leaf_hist_switch(new_data, s_start, s_count))
+            with jax.named_scope("lgbm.hist"):
+                hist_small = self._psum(
+                    self._leaf_hist_switch(new_data, s_start, s_count))
 
             # --- children bookkeeping ---
             lout, rout = rec["lout"], rec["rout"]
-            depth = st.leaf_depth[leaf] + 1
-            cmin, cmax = st.leaf_cmin[leaf], st.leaf_cmax[leaf]
-            if self.use_monotone:
-                monof = mono_dev[feat]
-                mid = (lout + rout) / 2.0
-                lcmax = jnp.where(monof > 0, jnp.minimum(cmax, mid), cmax)
-                rcmin = jnp.where(monof > 0, jnp.maximum(cmin, mid), cmin)
-                lcmin = jnp.where(monof < 0, jnp.maximum(cmin, mid), cmin)
-                rcmax = jnp.where(monof < 0, jnp.minimum(cmax, mid), cmax)
-            else:
-                lcmin, lcmax, rcmin, rcmax = cmin, cmax, cmin, cmax
+            with jax.named_scope("lgbm.bookkeeping"):
+                depth = st.leaf_depth[leaf] + 1
+                cmin, cmax = st.leaf_cmin[leaf], st.leaf_cmax[leaf]
+                if self.use_monotone:
+                    monof = mono_dev[feat]
+                    mid = (lout + rout) / 2.0
+                    lcmax = jnp.where(monof > 0, jnp.minimum(cmax, mid), cmax)
+                    rcmin = jnp.where(monof > 0, jnp.maximum(cmin, mid), cmin)
+                    lcmin = jnp.where(monof < 0, jnp.maximum(cmin, mid), cmin)
+                    rcmax = jnp.where(monof < 0, jnp.minimum(cmax, mid), cmax)
+                else:
+                    lcmin, lcmax, rcmin, rcmax = cmin, cmax, cmin, cmax
 
-            leaf_start = st.leaf_start.at[new_leaf].set(start + nleft)
-            leaf_count = st.leaf_count.at[leaf].set(nleft)\
-                                       .at[new_leaf].set(nright)
-            leaf_count_g = st.leaf_count_g.at[leaf].set(nleft_g)\
-                                          .at[new_leaf].set(nright_g)
-            leaf_sum_g = st.leaf_sum_g.at[leaf].set(rec["lg"])\
-                                      .at[new_leaf].set(rec["rg"])
-            leaf_sum_h = st.leaf_sum_h.at[leaf].set(rec["lh"])\
-                                      .at[new_leaf].set(rec["rh"])
-            leaf_output = st.leaf_output.at[leaf].set(lout)\
-                                        .at[new_leaf].set(rout)
-            leaf_depth = st.leaf_depth.at[leaf].set(depth)\
-                                      .at[new_leaf].set(depth)
-            leaf_parent = st.leaf_parent.at[leaf].set(node)\
-                                        .at[new_leaf].set(node)
-            leaf_cmin = st.leaf_cmin.at[leaf].set(lcmin).at[new_leaf].set(rcmin)
-            leaf_cmax = st.leaf_cmax.at[leaf].set(lcmax).at[new_leaf].set(rcmax)
+                leaf_start = st.leaf_start.at[new_leaf].set(start + nleft)
+                leaf_count = st.leaf_count.at[leaf].set(nleft)\
+                                           .at[new_leaf].set(nright)
+                leaf_count_g = st.leaf_count_g.at[leaf].set(nleft_g)\
+                                              .at[new_leaf].set(nright_g)
+                leaf_sum_g = st.leaf_sum_g.at[leaf].set(rec["lg"])\
+                                          .at[new_leaf].set(rec["rg"])
+                leaf_sum_h = st.leaf_sum_h.at[leaf].set(rec["lh"])\
+                                          .at[new_leaf].set(rec["rh"])
+                leaf_output = st.leaf_output.at[leaf].set(lout)\
+                                            .at[new_leaf].set(rout)
+                leaf_depth = st.leaf_depth.at[leaf].set(depth)\
+                                          .at[new_leaf].set(depth)
+                leaf_parent = st.leaf_parent.at[leaf].set(node)\
+                                            .at[new_leaf].set(node)
+                leaf_cmin = st.leaf_cmin.at[leaf].set(lcmin)\
+                                        .at[new_leaf].set(rcmin)
+                leaf_cmax = st.leaf_cmax.at[leaf].set(lcmax)\
+                                        .at[new_leaf].set(rcmax)
 
             # --- larger child: subtraction from the pooled parent (or a
             # second contiguous-slice histogram when pool-less) ---
             if self._use_hist_pool:
-                hist_large = st.hist_pool[leaf] - hist_small
-                hist_left = jnp.where(left_smaller, hist_small, hist_large)
-                hist_right = jnp.where(left_smaller, hist_large, hist_small)
-                hist_pool = st.hist_pool.at[leaf].set(hist_left)\
-                                        .at[new_leaf].set(hist_right)
+                with jax.named_scope("lgbm.pool"):
+                    hist_large = st.hist_pool[leaf] - hist_small
+                    hist_left = jnp.where(left_smaller, hist_small,
+                                          hist_large)
+                    hist_right = jnp.where(left_smaller, hist_large,
+                                           hist_small)
+                    hist_pool = st.hist_pool.at[leaf].set(hist_left)\
+                                            .at[new_leaf].set(hist_right)
             else:
                 l_start = jnp.where(left_smaller, start + nleft, start)
                 l_count = jnp.where(left_smaller, nright, nleft)
-                hist_large = self._psum(
-                    self._leaf_hist_switch(new_data, l_start, l_count))
+                with jax.named_scope("lgbm.hist"):
+                    hist_large = self._psum(
+                        self._leaf_hist_switch(new_data, l_start, l_count))
                 hist_left = jnp.where(left_smaller, hist_small, hist_large)
                 hist_right = jnp.where(left_smaller, hist_large, hist_small)
                 hist_pool = st.hist_pool
 
             # --- best splits for both children (one vmapped scan) ---
-            if bynode:
-                mask2 = jnp.stack([feature_mask[2 * new_leaf - 1],
-                                   feature_mask[2 * new_leaf]])
-            else:
-                mask2 = jnp.stack([feature_mask, feature_mask])
-            bl, br = self._scan_two_leaves(
-                jnp.stack([hist_left, hist_right]),
-                jnp.stack([rec["lg"], rec["rg"]]),
-                jnp.stack([rec["lh"], rec["rh"]]),
-                jnp.stack([nleft_g, nright_g]),
-                jnp.stack([lout, rout]),
-                jnp.stack([lcmin, rcmin]),
-                jnp.stack([lcmax, rcmax]), mask2, qscales=qscales)
+            with jax.named_scope("lgbm.split_scan"):
+                if bynode:
+                    mask2 = jnp.stack([feature_mask[2 * new_leaf - 1],
+                                       feature_mask[2 * new_leaf]])
+                else:
+                    mask2 = jnp.stack([feature_mask, feature_mask])
+                bl, br = self._scan_two_leaves(
+                    jnp.stack([hist_left, hist_right]),
+                    jnp.stack([rec["lg"], rec["rg"]]),
+                    jnp.stack([rec["lh"], rec["rh"]]),
+                    jnp.stack([nleft_g, nright_g]),
+                    jnp.stack([lout, rout]),
+                    jnp.stack([lcmin, rcmin]),
+                    jnp.stack([lcmax, rcmax]), mask2, qscales=qscales)
 
             def upd(a, key, cast=lambda x: x):
                 return a.at[leaf].set(cast(bl[key])).at[new_leaf].set(cast(br[key]))
 
-            return FusedTreeState(
-                data=new_data, n_leaves=st.n_leaves + 1,
-                leaf_start=leaf_start, leaf_count=leaf_count,
-                leaf_count_g=leaf_count_g,
-                leaf_sum_g=leaf_sum_g, leaf_sum_h=leaf_sum_h,
-                leaf_output=leaf_output, leaf_depth=leaf_depth,
-                leaf_parent=leaf_parent, leaf_cmin=leaf_cmin,
-                leaf_cmax=leaf_cmax,
-                best_gain=upd(st.best_gain, "gain"),
-                best_feature=upd(st.best_feature, "feature"),
-                best_thr=upd(st.best_thr, "thr"),
-                best_dl=upd(st.best_dl, "dl"),
-                best_lg=upd(st.best_lg, "lg"), best_lh=upd(st.best_lh, "lh"),
-                best_lcnt=upd(st.best_lcnt, "lcnt"),
-                best_lout=upd(st.best_lout, "lout"),
-                best_rg=upd(st.best_rg, "rg"), best_rh=upd(st.best_rh, "rh"),
-                best_rcnt=upd(st.best_rcnt, "rcnt"),
-                best_rout=upd(st.best_rout, "rout"),
-                best_cat=upd(st.best_cat, "cat"),
-                best_bits=st.best_bits.at[leaf].set(bl["bits"])
-                                      .at[new_leaf].set(br["bits"]),
-                hist_pool=hist_pool,
-                t_feature=t_feature, t_thr=t_thr, t_dl=t_dl, t_left=t_left,
-                t_right=t_right, t_gain=t_gain, t_ivalue=t_ivalue,
-                t_iweight=t_iweight, t_icount=t_icount,
-                t_cat=t_cat, t_bits=t_bits,
-            )
+            with jax.named_scope("lgbm.bookkeeping"):
+                return FusedTreeState(
+                    data=new_data, n_leaves=st.n_leaves + 1,
+                    leaf_start=leaf_start, leaf_count=leaf_count,
+                    leaf_count_g=leaf_count_g,
+                    leaf_sum_g=leaf_sum_g, leaf_sum_h=leaf_sum_h,
+                    leaf_output=leaf_output, leaf_depth=leaf_depth,
+                    leaf_parent=leaf_parent, leaf_cmin=leaf_cmin,
+                    leaf_cmax=leaf_cmax,
+                    best_gain=upd(st.best_gain, "gain"),
+                    best_feature=upd(st.best_feature, "feature"),
+                    best_thr=upd(st.best_thr, "thr"),
+                    best_dl=upd(st.best_dl, "dl"),
+                    best_lg=upd(st.best_lg, "lg"),
+                    best_lh=upd(st.best_lh, "lh"),
+                    best_lcnt=upd(st.best_lcnt, "lcnt"),
+                    best_lout=upd(st.best_lout, "lout"),
+                    best_rg=upd(st.best_rg, "rg"),
+                    best_rh=upd(st.best_rh, "rh"),
+                    best_rcnt=upd(st.best_rcnt, "rcnt"),
+                    best_rout=upd(st.best_rout, "rout"),
+                    best_cat=upd(st.best_cat, "cat"),
+                    best_bits=st.best_bits.at[leaf].set(bl["bits"])
+                                          .at[new_leaf].set(br["bits"]),
+                    hist_pool=hist_pool,
+                    t_feature=t_feature, t_thr=t_thr, t_dl=t_dl,
+                    t_left=t_left, t_right=t_right, t_gain=t_gain,
+                    t_ivalue=t_ivalue, t_iweight=t_iweight,
+                    t_icount=t_icount, t_cat=t_cat, t_bits=t_bits,
+                )
 
         # --- user-forced splits first (BFS schedule precomputed on the
         # host; reference SerialTreeLearner::ForceSplits,
@@ -1596,15 +1625,17 @@ class FusedSerialGrower:
         score_vec: [n] f32 current raw scores in ORIGINAL row order."""
         assert self.persistent_capable
         aux_label, aux_weight = self.objective.persistent_aux()
-        data = plane.build_data(
-            self.layout, self.codes_planes(),
-            jnp.zeros(self.layout.num_rows, jnp.float32),
-            jnp.zeros(self.layout.num_rows, jnp.float32),
-            label=jnp.asarray(aux_label, jnp.float32),
-            score=jnp.asarray(score_vec, jnp.float32),
-            weight=(None if aux_weight is None
-                    else jnp.asarray(aux_weight, jnp.float32)),
-            mv=self._mv_dev)
+        codes_planes = self.codes_planes()
+        with span("fused/build_data", stage="state/build_data"):
+            data = plane.build_data(
+                self.layout, codes_planes,
+                jnp.zeros(self.layout.num_rows, jnp.float32),
+                jnp.zeros(self.layout.num_rows, jnp.float32),
+                label=jnp.asarray(aux_label, jnp.float32),
+                score=jnp.asarray(score_vec, jnp.float32),
+                weight=(None if aux_weight is None
+                        else jnp.asarray(aux_weight, jnp.float32)),
+                mv=self._mv_dev)
         # the persistent program carries the codes INSIDE `data`; the
         # cached planes copy would sit in HBM for nothing (3.9 GB at
         # the Allstate shape, next to the state and the partition
@@ -1627,57 +1658,62 @@ class FusedSerialGrower:
         lanes = jnp.arange(Ly.num_lanes, dtype=jnp.int32)
         realm = lanes < n  # pad lanes never enter any window
 
-        score = plane.get_f32(data, Ly.score)
-        label = plane.get_f32(data, Ly.label)
-        weight = plane.get_f32(data, Ly.weight) if Ly.weight >= 0 else None
-        g, h = self.objective.persistent_grads(score, label, weight)
-        g = jnp.where(realm, g, 0.0)
-        h = jnp.where(realm, h, 0.0)
         qscales = None
-        if self._quant:
-            # per-iteration device quantization pass: the grad plane
-            # carries the packed (qg << 16 | qh) words bitcast through
-            # the f32 lanes, the hess plane zeros (the kernels unpack
-            # both levels from the one word). Scales psum-max across
-            # shards so every shard quantizes on the same grid and the
-            # int32 histogram psums stay coherent.
-            gmax = self._psum_max(jnp.max(jnp.abs(g)))
-            hmax = self._psum_max(jnp.max(h))
-            qg, qh, gs, hs = Q.quantize_gradients(
-                g, h, self.config.num_grad_quant_bins, key,
-                stochastic=self.config.stochastic_rounding,
-                grad_max=gmax, hess_max=hmax)
-            qscales = (gs, hs)
-            packed = plane.i32_as_f32(Q.pack_gh(qg, qh))
-            data = plane.set_gh_packed(data, Ly, packed)
-        else:
-            data = plane.set_gh(data, Ly, g, h)
+        with jax.named_scope("lgbm.grad"):
+            score = plane.get_f32(data, Ly.score)
+            label = plane.get_f32(data, Ly.label)
+            weight = (plane.get_f32(data, Ly.weight) if Ly.weight >= 0
+                      else None)
+            g, h = self.objective.persistent_grads(score, label, weight)
+            g = jnp.where(realm, g, 0.0)
+            h = jnp.where(realm, h, 0.0)
+            if self._quant:
+                # per-iteration device quantization pass: the grad plane
+                # carries the packed (qg << 16 | qh) words bitcast
+                # through the f32 lanes, the hess plane zeros (the
+                # kernels unpack both levels from the one word). Scales
+                # psum-max across shards so every shard quantizes on the
+                # same grid and the int32 histogram psums stay coherent.
+                gmax = self._psum_max(jnp.max(jnp.abs(g)))
+                hmax = self._psum_max(jnp.max(h))
+                qg, qh, gs, hs = Q.quantize_gradients(
+                    g, h, self.config.num_grad_quant_bins, key,
+                    stochastic=self.config.stochastic_rounding,
+                    grad_max=gmax, hess_max=hmax)
+                qscales = (gs, hs)
+                packed = plane.i32_as_f32(Q.pack_gh(qg, qh))
+                data = plane.set_gh_packed(data, Ly, packed)
+            else:
+                data = plane.set_gh(data, Ly, g, h)
 
         ta, st = self._grow_tree_core(data, n, feature_mask,
                                       qscales=qscales)
 
         renew = (self.objective.persistent_renew_spec()
                  if self.objective is not None else None)
-        if renew is not None:
-            # leaf refit BEFORE shrinkage, like the reference's
-            # RenewTreeOutput -> Shrinkage order (gbdt.cpp:379-386)
-            alpha, weighted = renew
-            ta = dict(ta, leaf_value=self._renew_leaf_outputs(
-                st, n, alpha, weighted))
-        elif self._quant and self.config.quant_train_renew_leaf:
-            # RenewIntGradTreeOutput (gradient_discretizer.cpp): leaf
-            # values recomputed from the RAW f32 gradient sums so the
-            # rounding error of the quantized split search never enters
-            # the model output. The raw grads are recomputed from the
-            # (permuted, but value-unchanged) score/label planes of the
-            # FINAL state — pre-growth g/h are in pre-partition lane
-            # order and would pair with the wrong windows.
-            ta = dict(ta, leaf_value=self._renew_quant_leaves(st, n))
+        with jax.named_scope("lgbm.renew"):
+            if renew is not None:
+                # leaf refit BEFORE shrinkage, like the reference's
+                # RenewTreeOutput -> Shrinkage order (gbdt.cpp:379-386)
+                alpha, weighted = renew
+                ta = dict(ta, leaf_value=self._renew_leaf_outputs(
+                    st, n, alpha, weighted))
+            elif self._quant and self.config.quant_train_renew_leaf:
+                # RenewIntGradTreeOutput (gradient_discretizer.cpp): leaf
+                # values recomputed from the RAW f32 gradient sums so the
+                # rounding error of the quantized split search never
+                # enters the model output. The raw grads are recomputed
+                # from the (permuted, but value-unchanged) score/label
+                # planes of the FINAL state — pre-growth g/h are in
+                # pre-partition lane order and would pair with the wrong
+                # windows.
+                ta = dict(ta, leaf_value=self._renew_quant_leaves(st, n))
 
-        vals = ta["leaf_value"] * shrinkage
-        add = self._score_add_by_pos(st, vals.astype(jnp.float32))
-        score2 = plane.get_f32(st.data, Ly.score) + add + bias
-        data = plane.set_f32(st.data, Ly.score, score2)
+        with jax.named_scope("lgbm.score_update"):
+            vals = ta["leaf_value"] * shrinkage
+            add = self._score_add_by_pos(st, vals.astype(jnp.float32))
+            score2 = plane.get_f32(st.data, Ly.score) + add + bias
+            data = plane.set_f32(st.data, Ly.score, score2)
         return data, ta
 
     def _next_quant_keys(self, k: int):
@@ -1720,7 +1756,6 @@ class FusedSerialGrower:
                 xs = (masks, keys) if quant else masks
                 return jax.lax.scan(step, data, xs, length=k)
 
-        from ..obs import instrument_kernel
         if self._mgr is not None:
             entry = self._mgr.shared_entry(
                 f"fused/train_iters_k{k}", self._compile_signature(),

@@ -40,7 +40,7 @@ from ..config import Config
 from ..io.dataset import BinnedDataset
 from ..models.tree import Tree
 from ..network import collective_span
-from ..obs import instrument_kernel
+from ..obs import instrument_kernel, span
 from ..ops import histogram as H
 from ..ops import quantize as Q
 from ..ops import split as S
@@ -723,9 +723,11 @@ class FusedDataParallelGrower(FusedSerialGrower):
             zero-pads it to the lane count."""
             return jnp.asarray(v[d * sr:(d + 1) * sr])
 
-        def build_shard(d):
-            cp = plane.build_codes_planes(
+        def pack_shard(d):
+            return plane.build_codes_planes(
                 jnp.asarray(bins[d * sr:(d + 1) * sr]), Ly)
+
+        def build_shard(d, cp):
             # pad rows alias row id n -> dropped by the sync scatter
             rowid = np.minimum(np.arange(d * sr, (d + 1) * sr), n)
             rowid = np.pad(rowid, (0, Ly.num_lanes - sr),
@@ -738,11 +740,22 @@ class FusedDataParallelGrower(FusedSerialGrower):
 
         shape = (Ly.num_planes, D * Ly.num_lanes)
         sharding = NamedSharding(self.mesh, P(None, "data"))
+        owned = [(dev, idx[1].start // Ly.num_lanes) for dev, idx in
+                 sharding.addressable_devices_indices_map(shape).items()]
+        # every device's pack is enqueued before the one block, so the
+        # chips pack side by side and the stage reads their longest
+        with span("fused/pack_codes", stage="state/pack_codes"):
+            packed = []
+            for dev, d in owned:
+                with jax.default_device(dev):
+                    packed.append(pack_shard(d))
+            # tpulint: sync-ok(set-up, once per state build, after every device's pack is enqueued)
+            jax.block_until_ready(packed)
         shards = []
-        for dev, idx in sharding.addressable_devices_indices_map(
-                shape).items():
-            with jax.default_device(dev):
-                shards.append(build_shard(idx[1].start // Ly.num_lanes))
+        with span("fused/build_data", stage="state/build_data"):
+            for (dev, d), cp in zip(owned, packed):
+                with jax.default_device(dev):
+                    shards.append(build_shard(d, cp))
         return jax.make_array_from_single_device_arrays(
             shape, sharding, shards)
 
@@ -777,7 +790,7 @@ class FusedDataParallelGrower(FusedSerialGrower):
             self._iter_mc_jit = get_manager().shared_entry(
                 "mc/train_iter", sig,
                 lambda: jax.jit(f, donate_argnums=0),  # tpulint: jit-ok(inside a shared_entry builder; the manager dispatches this jit)
-                donate_argnums=(0,), store=ok)
+                donate_argnums=(0,), store=ok, profiled=True)
         args = (data, self._n_per_shard, mask, jnp.float32(shrinkage),
                 jnp.float32(bias))
         if quant:

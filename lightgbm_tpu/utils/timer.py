@@ -3,9 +3,9 @@
 Equivalent of the reference's Timer/FunctionTimer + global_timer
 (reference: include/LightGBM/utils/common.h:1054-1138 — RAII scopes
 around every hot function, aggregated by name, printed at exit when
-built with -DUSE_TIMETAG). Here the same scopes also emit
-jax.profiler.TraceAnnotation ranges so device traces line up with the
-host-side phase table.
+built with -DUSE_TIMETAG). The table only: the program's scopes go
+through `obs.span`, which feeds this table and is the one place that
+writes jax.profiler annotations.
 """
 from __future__ import annotations
 
@@ -40,20 +40,12 @@ class Timer:
         if not self.enabled:
             yield
             return
-        try:
-            import jax.profiler
-            ann = jax.profiler.TraceAnnotation(name)
-            ann.__enter__()
-        except Exception:
-            ann = None
         t0 = time.perf_counter()
         try:
             yield
         finally:
             self.acc[name] += time.perf_counter() - t0
             self.cnt[name] += 1
-            if ann is not None:
-                ann.__exit__(None, None, None)
 
     def report(self) -> str:
         lines = ["LightGBM-TPU timer table:"]
