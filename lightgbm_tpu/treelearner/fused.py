@@ -1155,6 +1155,13 @@ class FusedSerialGrower:
                                           hist_large)
                     hist_right = jnp.where(left_smaller, hist_large,
                                            hist_small)
+                    # both child rows are finished before the first write,
+                    # so the carry is updated in place; else XLA:TPU fuses
+                    # the subtraction into the writes, the second reads the
+                    # pre-write pool, and each split copies the pool twice
+                    # (L*F*B*8 bytes each) — tests/test_pool_inplace.py
+                    hist_left, hist_right = jax.lax.optimization_barrier(
+                        (hist_left, hist_right))
                     hist_pool = st.hist_pool.at[leaf].set(hist_left)\
                                             .at[new_leaf].set(hist_right)
             else:

@@ -1,0 +1,151 @@
+"""The loop-carried histogram pool is written in place on the TPU.
+
+Each split step writes two `[F, B, 2]` rows of the `[L, F, B, 2]` pool
+(`lgbm.pool` in treelearner/fused.py). If a write's fusion still reads the
+pre-write pool, XLA:TPU copies the whole carry before the first write and back
+after the second: two copies of L*F*B*8 bytes on each of the L-1 steps, a third
+of `epsilon63.train`'s device time before PR 26. XLA:CPU keeps such copies
+either way, so only the TPU's compiler can hold this: the programs are compiled
+here for a described v5e (no chip, nothing runs) and their text is searched.
+
+Every compile of this file happens inside a test, in this process: the TPU's
+library is loaded by the one worker that is given the file.
+"""
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+import lightgbm_tpu as lgb
+from lightgbm_tpu.compile import reset_manager
+from lightgbm_tpu.ops import histogram as H
+
+# the reduced wide shape: the parent's body copies f32[31,256,63,2] twice here
+ROWS, COLS, LEAVES, MAX_BIN = 4096, 256, 31, 63
+CASES = {
+    # case -> (parameters, pool dtype in the compiled text)
+    "persistent_f32": ({}, "f32"),
+    "persistent_quantized_i32": ({"use_quantized_grad": True}, "s32"),
+    "per_tree": ({"objective": "multiclass", "num_class": 3}, "f32"),
+    "data_parallel": ({"tree_learner": "data", "tpu_mesh_shape": [4]}, "f32"),
+}
+
+
+def _describe(name, **kwargs):
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name=name, **kwargs)
+    except Exception as e:  # no libtpu, or it cannot describe the chip
+        pytest.skip(f"no {name} topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    return _describe("v5e:1x1", chip_config_name="default",
+                     chips_per_host_bounds=(1, 1, 1), num_slices=1).devices
+
+
+@pytest.fixture(scope="module")
+def four_chips():
+    return _describe("v5e:2x2").devices
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    """Growers dispatch as on a TPU (the Pallas histogram and partition
+    kernels); what is compiled for the described chip stays out of the
+    persistent cache, which could not hand it back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(H, "_use_tpu", lambda: True)
+    monkeypatch.setenv("LGBM_TPU_WARMUP", "0")
+    reset_manager()
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+    reset_manager()
+
+
+def _grower(extra):
+    rng = np.random.RandomState(1)
+    X = rng.randn(ROWS, COLS).astype(np.float32)
+    if extra.get("objective") == "multiclass":
+        y = rng.randint(0, extra["num_class"], ROWS).astype(np.float64)
+    else:
+        y = (X[:, 0] + X[:, 1] > 0).astype(np.float64)
+    params = dict({"objective": "binary", "num_leaves": LEAVES,
+                   "max_bin": MAX_BIN, "min_data_in_leaf": 1, "verbose": -1},
+                  **extra)
+    g = lgb.Booster(params, lgb.Dataset(X, label=y, params=params))._gbdt._fused
+    assert g._hist_method == "radix_pallas_bf16" and g._use_hist_pool
+    assert g._part_method.startswith("pallas")
+    g._interpret = False    # Mosaic, not the interpreter
+    return g
+
+
+def _on(avals, sharding):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        avals)
+
+
+def _lowered(case, g, request):
+    """The program of `case`, lowered on the avals its warm-up would use."""
+    if case == "data_parallel":
+        mesh = Mesh(np.asarray(request.getfixturevalue("four_chips")),
+                    ("data",))
+
+        def body(data_l, nvalid_l, mask, shrinkage, bias):
+            return g._train_iter(data_l, mask, shrinkage, bias,
+                                 n_valid=nvalid_l[0])
+
+        f = shard_map(body, mesh=mesh, check_vma=False,
+                      in_specs=(P(None, "data"), P("data"), P(), P(), P()),
+                      out_specs=(P(None, "data"), P()))
+        aval, Ly, D = jax.ShapeDtypeStruct, g.layout, g.num_shards
+
+        def on(*spec):
+            return NamedSharding(mesh, P(*spec))
+
+        return jax.jit(f, donate_argnums=0).lower(
+            aval((Ly.num_planes, D * Ly.num_lanes), jnp.int32,
+                 sharding=on(None, "data")),
+            aval((D,), jnp.int32, sharding=on("data")),
+            aval((g.num_features,), jnp.bool_, sharding=on()),
+            aval((), jnp.float32, sharding=on()),
+            aval((), jnp.float32, sharding=on()))
+    chip = SingleDeviceSharding(request.getfixturevalue("one_chip")[0])
+    if case == "per_tree":
+        (args, statics), = g._grow_entry.specs
+        return jax.jit(g._entry_grow_tree,
+                       static_argnames=tuple(statics)).lower(
+            *_on(args, chip), **statics)
+    (args, _), = g._iter_entry.specs
+    return jax.jit(g._entry_train_iter, donate_argnums=1).lower(
+        *_on(args, chip))
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_no_pool_shaped_copy_in_the_v5e_program(case, as_on_tpu, request):
+    extra, dtype = CASES[case]
+    if case == "data_parallel" and len(jax.devices()) < 4:
+        pytest.skip("needs 4 (virtual) devices to build the grower")
+    g = _grower(extra)
+    text = _lowered(case, g, request).compile().as_text()
+    pool = re.escape(f"{dtype}[{LEAVES},{COLS},{MAX_BIN},2]")
+    ops = re.findall(rf"(%\S+) = {pool}\S* ([\w-]+)\(", text)
+    kinds = {kind for _, kind in ops}
+    # the search sees the pool: its two row writes are there, fused
+    assert "dynamic-update-slice" in kinds and "fusion" in kinds, kinds
+    assert "tpu_custom_call" in text, "the Pallas kernels are not in it"
+    copies = [name for name, kind in ops if kind == "copy"]
+    assert not copies, (
+        f"{copies}: the whole pool is copied on every split step; a pool "
+        f"write reads the pre-write pool again (fused.py, `lgbm.pool`)")
