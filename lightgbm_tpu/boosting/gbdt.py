@@ -240,7 +240,21 @@ class GBDT:
                 "device_kind": dev[0].device_kind, "device_count": len(dev),
                 "tier": tier, "learner": type(grower).__name__,
                 "hist": hist or "scatter",
-                "partition": getattr(self._fused, "_part_method", "xla")}
+                "partition": getattr(self._fused, "_part_method", "xla"),
+                "sampling": self._sampling_plan()}
+
+    def _sampling_plan(self) -> Optional[str]:
+        """The row sampling each tree is grown under, None without."""
+        cfg = self.config
+        by_label = (cfg.pos_bagging_fraction < 1.0
+                    or cfg.neg_bagging_fraction < 1.0)
+        if cfg.bagging_freq <= 0 or not (cfg.bagging_fraction < 1.0
+                                         or by_label):
+            return None
+        if by_label:
+            return (f"bagging(pos {cfg.pos_bagging_fraction:g}, neg "
+                    f"{cfg.neg_bagging_fraction:g}/{cfg.bagging_freq})")
+        return f"bagging({cfg.bagging_fraction:g}/{cfg.bagging_freq})"
 
     def _create_tree_learner(self, config: Config, train_data: BinnedDataset):
         if config.tree_learner in ("serial", "feature", "data", "voting"):
@@ -625,12 +639,13 @@ class GBDT:
             pending = PendingTree(self._fused, ta)
             pending.apply_shrinkage(self.shrinkage_rate)
             vals = pending.leaf_values_device()
-            self.train_score.score = \
-                self.train_score.score.at[c].add(vals[leaf_of_row])
+            self.train_score.score = _score_add_entry()(
+                self.train_score.score, vals, leaf_of_row, class_id=c)
             for vs in self.valid_score:
                 vleaf = self._fused._valid_traverse_jit(
                     ta, vs.dataset.device_bins())
-                vs.score = vs.score.at[c].add(vals[vleaf])
+                vs.score = _score_add_entry()(vs.score, vals, vleaf,
+                                              class_id=c)
             if abs(init_scores[c]) > K_EPSILON:
                 pending.add_bias(init_scores[c])
             self.models.append(pending)
@@ -1712,33 +1727,70 @@ class DART(GBDT):
                     self.tree_weight[j] *= k_drop / (k_drop + cfg.learning_rate)
 
 
+def _score_add_device(score, leaf_values, leaf_of_row, *, class_id: int):
+    """score[class_id] += leaf_values[leaf_of_row] (GBDT::UpdateScore
+    for one tree of the per-tree fused tier). The lookup is a compare
+    against every leaf id, summed: it fuses on the VPU, where a [N]-sized
+    gather from the leaf table pays a per-row toll."""
+    with jax.named_scope("lgbm.score_update"):
+        leaf_ids = jnp.arange(leaf_values.shape[0], dtype=jnp.int32)
+        add = jnp.sum(jnp.where(leaf_of_row[:, None] == leaf_ids[None, :],
+                                leaf_values[None, :], 0.0), axis=1)
+        return score.at[class_id].add(add.astype(score.dtype))
+
+
+def _manager_entry(name: str, fn, **jit_kwargs):
+    """`fn` jitted, registered with the compile manager (its compiles
+    land in the manager's counters) and called under an `lgbm:` span."""
+    from ..compile import get_manager
+    from ..obs import instrument_kernel
+    jitted = jax.jit(fn, **jit_kwargs)  # tpulint: jit-ok(registered by jit_entry on the next line; the manager counts its compiles)
+    return instrument_kernel(get_manager().jit_entry(name, jitted),
+                             "boost", name=name)
+
+
+@functools.lru_cache(maxsize=1)
+def _score_add_entry():
+    return _manager_entry("boosting/score_add", _score_add_device,
+                          static_argnames=("class_id",))
+
+
+def _largest_k_mask(x, k: int):
+    """[n] bool: the k largest of x, equal values to the lower index —
+    the rows `jax.lax.top_k(x, k)` picks, found from the exact k-th
+    largest value (one sort) instead of by scattering top_k's indices:
+    on a TPU a [n]-sized scatter costs seconds at 10^7 rows, a sort tens
+    of milliseconds."""
+    n = x.shape[0]
+    kth = jnp.sort(x)[n - k]
+    above = x > kth
+    tie = x == kth
+    need = k - jnp.sum(above, dtype=jnp.int32)
+    return above | (tie & (jnp.cumsum(tie.astype(jnp.int32)) <= need))
+
+
 def _goss_sample_device(grad, hess, seed, *, top_k: int, other_k: int):
     """Device-side GOSS round (reference goss.hpp:111-147): top_k rows
     by sum_c |g*h|, other_k uniform from the rest upweighted by
     (n - top_k) / other_k, and the stable [bag | oob] permutation —
-    all without host round-trips of [C, N] arrays. The permutation is
-    built by destination ranks (two prefix sums + one scatter), not an
-    argsort: both sides keep ascending row order, exactly the host
-    path's sorted-bag/oob layout."""
-    n = grad.shape[1]
-    weight = jnp.sum(jnp.abs(grad * hess), axis=0)            # [n]
-    _, top_rows = jax.lax.top_k(weight, top_k)
-    is_top = jnp.zeros(n, jnp.bool_).at[top_rows].set(True)
-    # uniform sample WITHOUT replacement from the rest: random keys,
-    # top rows masked below every real key, take the other_k largest
-    r = jax.random.uniform(jax.random.PRNGKey(seed), (n,))
-    _, sampled = jax.lax.top_k(jnp.where(is_top, -1.0, r), other_k)
-    multiply = jnp.float32((n - top_k) / other_k)
-    grad = grad.at[:, sampled].multiply(multiply)
-    hess = hess.at[:, sampled].multiply(multiply)
-    in_bag = is_top.at[sampled].set(True)
-    # stable two-way partition of row ids by destination rank
-    bag_rank = jnp.cumsum(in_bag.astype(jnp.int32)) - 1
-    oob_rank = (top_k + other_k
-                + jnp.cumsum((~in_bag).astype(jnp.int32)) - 1)
-    dest = jnp.where(in_bag, bag_rank, oob_rank)
-    perm = jnp.zeros(n, jnp.int32).at[dest].set(
-        jnp.arange(n, dtype=jnp.int32))
+    all without host round-trips of [C, N] arrays, and without a
+    [N]-sized scatter or gather: both selections are masks from an
+    exact k-th value, the weighting is a select, and the permutation is
+    one stable sort of the row ids by the mask, so both sides keep
+    ascending row order, exactly the host path's sorted-bag/oob
+    layout."""
+    with jax.named_scope("lgbm.goss_sample"):
+        n = grad.shape[1]
+        weight = jnp.sum(jnp.abs(grad * hess), axis=0)            # [n]
+        is_top = _largest_k_mask(weight, top_k)
+        # uniform sample WITHOUT replacement from the rest: random keys,
+        # top rows masked below every real key, take the other_k largest
+        r = jax.random.uniform(jax.random.PRNGKey(seed), (n,))
+        sampled = _largest_k_mask(jnp.where(is_top, -1.0, r), other_k)
+        multiply = jnp.float32((n - top_k) / other_k)
+        grad = jnp.where(sampled[None, :], grad * multiply, grad)
+        hess = jnp.where(sampled[None, :], hess * multiply, hess)
+        perm = jnp.argsort(~(is_top | sampled), stable=True).astype(jnp.int32)
     return grad, hess, perm
 
 
@@ -1747,10 +1799,8 @@ def _goss_sample_entry():
     """Manager-registered entry for the GOSS sampling kernel, so its
     (re)compiles land in the same compile counters as the rest of the
     stack instead of hiding behind an ad-hoc module-level jit."""
-    from ..compile import get_manager
-    return get_manager().jit_entry(
-        "boosting/goss_sample",
-        jax.jit(_goss_sample_device, static_argnames=("top_k", "other_k")))
+    return _manager_entry("boosting/goss_sample", _goss_sample_device,
+                          static_argnames=("top_k", "other_k"))
 
 
 class GOSS(GBDT):
@@ -1765,6 +1815,10 @@ class GOSS(GBDT):
             log.fatal("Invalid top_rate/other_rate for GOSS")
         log.info("Using GOSS")
 
+    def _sampling_plan(self) -> Optional[str]:
+        cfg = self.config
+        return f"goss(top_rate={cfg.top_rate:g}, other_rate={cfg.other_rate:g})"
+
     def _bagging(self, iteration: int) -> None:
         cfg = self.config
         n = self.num_data
@@ -1774,6 +1828,10 @@ class GOSS(GBDT):
             return
         top_k = max(1, int(n * cfg.top_rate))
         other_k = max(1, min(int(n * cfg.other_rate), n - top_k))
+        if self._perm is self._full_perm:
+            log.info("GOSS sampling starts at iteration %d: top_k=%d "
+                     "other_k=%d, %d of %d rows a tree", iteration, top_k,
+                     other_k, top_k + other_k, n)
         seed = jnp.int32(self._bag_rng.randint(1 << 31))
         self._grad, self._hess, self._perm = _goss_sample_entry()(
             self._grad, self._hess, seed, top_k=top_k, other_k=other_k)

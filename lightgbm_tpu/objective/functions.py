@@ -69,6 +69,18 @@ def _np_weighted_percentile(values: np.ndarray, weights: Optional[np.ndarray],
     return float(v2)
 
 
+def _gradient_kernel(fn):
+    """The jit of a pointwise `get_gradients(self, score)` (static self),
+    its ops under `lgbm.grad`: the scope the persistent program gives the
+    same step, so one reader serves the per-tree tiers too."""
+    @functools.wraps(fn)
+    def scoped(self, score):
+        with jax.named_scope("lgbm.grad"):
+            return fn(self, score)
+    # tpulint: jit-ok(per-objective gradient kernel; static self, stable arity)
+    return jax.jit(scoped, static_argnums=0)
+
+
 class ObjectiveFunction:
     name = "custom"
     num_tree_per_iteration = 1
@@ -153,7 +165,7 @@ class RegressionL2(ObjectiveFunction):
         self.is_constant_hessian = self.weights is None
 
     # tpulint: jit-ok(per-objective gradient kernel; static self, stable arity)
-    @functools.partial(jax.jit, static_argnums=0)
+    @_gradient_kernel
     def get_gradients(self, score):
         g = score.astype(jnp.float32) - self._label_dev
         h = jnp.ones_like(g)
@@ -188,7 +200,7 @@ class RegressionL1(RegressionL2):
     is_renew_tree_output = True
 
     # tpulint: jit-ok(per-objective gradient kernel; static self, stable arity)
-    @functools.partial(jax.jit, static_argnums=0)
+    @_gradient_kernel
     def get_gradients(self, score):
         diff = score.astype(jnp.float32) - self._label_dev
         g = jnp.sign(diff)
@@ -229,7 +241,7 @@ class RegressionHuber(RegressionL2):
             log.fatal("alpha should be greater than 0 in huber")
 
     # tpulint: jit-ok(per-objective gradient kernel; static self, stable arity)
-    @functools.partial(jax.jit, static_argnums=0)
+    @_gradient_kernel
     def get_gradients(self, score):
         diff = score.astype(jnp.float32) - self._label_dev
         g = jnp.where(jnp.abs(diff) <= self.alpha, diff,
@@ -255,7 +267,7 @@ class RegressionFair(RegressionL2):
         self.c = config.fair_c
 
     # tpulint: jit-ok(per-objective gradient kernel; static self, stable arity)
-    @functools.partial(jax.jit, static_argnums=0)
+    @_gradient_kernel
     def get_gradients(self, score):
         x = score.astype(jnp.float32) - self._label_dev
         c = self.c
@@ -290,7 +302,7 @@ class RegressionPoisson(RegressionL2):
             log.fatal("[poisson]: at least one target label is negative")
 
     # tpulint: jit-ok(per-objective gradient kernel; static self, stable arity)
-    @functools.partial(jax.jit, static_argnums=0)
+    @_gradient_kernel
     def get_gradients(self, score):
         s = score.astype(jnp.float32)
         g = jnp.exp(s) - self._label_dev
@@ -323,7 +335,7 @@ class RegressionQuantile(RegressionL2):
             log.fatal("alpha should be in (0, 1) for quantile")
 
     # tpulint: jit-ok(per-objective gradient kernel; static self, stable arity)
-    @functools.partial(jax.jit, static_argnums=0)
+    @_gradient_kernel
     def get_gradients(self, score):
         delta = score.astype(jnp.float32) - self._label_dev
         g = jnp.where(delta >= 0, 1.0 - self.alpha, -self.alpha)
@@ -367,7 +379,7 @@ class RegressionMAPE(RegressionL1):
         self.is_constant_hessian = self.weights is None
 
     # tpulint: jit-ok(per-objective gradient kernel; static self, stable arity)
-    @functools.partial(jax.jit, static_argnums=0)
+    @_gradient_kernel
     def get_gradients(self, score):
         diff = score.astype(jnp.float32) - self._label_dev
         g = jnp.sign(diff) * self._label_weight_dev
@@ -405,7 +417,7 @@ class RegressionGamma(RegressionPoisson):
     name = "gamma"
 
     # tpulint: jit-ok(per-objective gradient kernel; static self, stable arity)
-    @functools.partial(jax.jit, static_argnums=0)
+    @_gradient_kernel
     def get_gradients(self, score):
         s = score.astype(jnp.float32)
         g = 1.0 - self._label_dev / jnp.exp(s)
@@ -428,7 +440,7 @@ class RegressionTweedie(RegressionPoisson):
         self.rho = config.tweedie_variance_power
 
     # tpulint: jit-ok(per-objective gradient kernel; static self, stable arity)
-    @functools.partial(jax.jit, static_argnums=0)
+    @_gradient_kernel
     def get_gradients(self, score):
         s = score.astype(jnp.float32)
         y = self._label_dev
@@ -485,7 +497,7 @@ class BinaryLogloss(ObjectiveFunction):
         self.is_constant_hessian = False
 
     # tpulint: jit-ok(per-objective gradient kernel; static self, stable arity)
-    @functools.partial(jax.jit, static_argnums=0)
+    @_gradient_kernel
     def get_gradients(self, score):
         s = score.astype(jnp.float32)
         response = -self._sign * self.sigmoid / \
@@ -556,7 +568,7 @@ class MulticlassSoftmax(ObjectiveFunction):
         self.factor = self.num_class / max(self.num_class - 1, 1)
 
     # tpulint: jit-ok(per-objective gradient kernel; static self, stable arity)
-    @functools.partial(jax.jit, static_argnums=0)
+    @_gradient_kernel
     def get_gradients(self, score):
         """score: [num_class, N] raw scores; returns [num_class, N] each."""
         p = jax.nn.softmax(score.astype(jnp.float32), axis=0)
@@ -619,7 +631,7 @@ class CrossEntropy(ObjectiveFunction):
             log.fatal("[%s]: label must be in [0, 1]", self.name)
 
     # tpulint: jit-ok(per-objective gradient kernel; static self, stable arity)
-    @functools.partial(jax.jit, static_argnums=0)
+    @_gradient_kernel
     def get_gradients(self, score):
         z = 1.0 / (1.0 + jnp.exp(-score.astype(jnp.float32)))
         g = z - self._label_dev
@@ -658,7 +670,7 @@ class CrossEntropyLambda(ObjectiveFunction):
             log.fatal("[%s]: label must be in [0, 1]", self.name)
 
     # tpulint: jit-ok(per-objective gradient kernel; static self, stable arity)
-    @functools.partial(jax.jit, static_argnums=0)
+    @_gradient_kernel
     def get_gradients(self, score):
         """Reference xentropy_objective.hpp:185-213: unweighted variant
         equals plain cross-entropy; the weighted variant treats the score

@@ -43,6 +43,7 @@ is named by fused_reject_reason and warned about loudly.
 from __future__ import annotations
 
 import contextlib
+import math
 import os
 from typing import Dict, NamedTuple, Optional
 
@@ -210,12 +211,12 @@ class FusedSerialGrower:
         self._num_rows_override = num_rows_override
         self.config = config
         self.objective = objective
-        # HBM budgeting at wide-EFB scale: the row-major bin matrix is
-        # only needed by the traverse paths (OOB scores, valid sets,
-        # the bagging repack) — upload it LAZILY so the persistent path
-        # does not hold [N, G] u8 in HBM next to the planar state
-        # (13.2M x 500 groups = 6.6 GB that the training loop never
-        # reads)
+        # HBM budgeting: the row-major bin matrix is only needed to
+        # rebuild a checkpointed persistent state — upload it LAZILY so
+        # no training loop holds [N, G] u8 in HBM next to the planar
+        # state (13.2M x 500 groups = 6.6 GB; at G < 128 the TPU pads
+        # every row to a 128-lane tile). Row sampling gathers and
+        # traverses the resident code planes instead (_grow_tree)
         self._bins_dev = None
         self.num_features = dataset.num_features
         mappers = dataset.bin_mappers
@@ -434,6 +435,7 @@ class FusedSerialGrower:
         # bagging the out-of-bag rows are never partitioned and the
         # fallback is the tree re-traversal
         self._score_from_partition = not bag_active(config)
+        self._bag_cap = None      # _bag_capacity
 
         # multi-chip: name of the mesh axis to psum histograms/counts
         # over (set by the data-parallel wrapper; None on one chip)
@@ -475,7 +477,8 @@ class FusedSerialGrower:
                 "fused/grow_tree", sig,
                 lambda: jax.jit(
                     self._entry_grow_tree,
-                    static_argnames=("compute_score_update",)))
+                    static_argnames=("compute_score_update", "bag_cap")),
+                profiled=True)
             self._iter_entry = self._mgr.shared_entry(
                 "fused/train_iter", sig,
                 lambda: jax.jit(self._entry_train_iter, donate_argnums=1),
@@ -497,7 +500,8 @@ class FusedSerialGrower:
         else:
             self._grow_jit = instrument_kernel(
                 jax.jit(self._entry_grow_tree,  # tpulint: jit-ok(manager-disabled fallback branch)
-                        static_argnames=("compute_score_update",)),
+                        static_argnames=("compute_score_update",
+                                         "bag_cap")),
                 "fused", name="fused/grow_tree")
             self._iter_jit = instrument_kernel(
                 jax.jit(self._entry_train_iter, donate_argnums=1),  # tpulint: jit-ok(manager-disabled fallback branch)
@@ -627,12 +631,13 @@ class FusedSerialGrower:
         }
 
     def _entry_grow_tree(self, tables, codes_planes, grad, hess, perm,
-                         bag_cnt, feature_mask, bins_rowmajor=None,
-                         mv=None, compute_score_update: bool = True):
+                         bag_cnt, feature_mask, mv=None,
+                         compute_score_update: bool = True,
+                         bag_cap: Optional[int] = None):
         with self._bind_tables(tables):
             return self._grow_tree(codes_planes, grad, hess, perm,
-                                   bag_cnt, feature_mask, bins_rowmajor,
-                                   mv, compute_score_update)
+                                   bag_cnt, feature_mask, mv,
+                                   compute_score_update, bag_cap)
 
     def _entry_train_iter(self, tables, data, feature_mask, shrinkage,
                           bias, n_valid, key=None):
@@ -679,7 +684,7 @@ class FusedSerialGrower:
                        if self._mv_dev is not None else None)
             self._grow_entry.add_spec(
                 (t_avals, cp_aval, fvec, fvec, perm_aval, i32s, mask_aval,
-                 None, mv_aval), {"compute_score_update": True})
+                 mv_aval), {"compute_score_update": True})
 
     def _branch_tile(self, cap: int) -> int:
         """Per-branch partition processing tile: the kernels are
@@ -1571,55 +1576,92 @@ class FusedSerialGrower:
 
     # ------------------------------------------------------------------
     def _grow_tree(self, codes_planes, grad, hess, perm, bag_cnt,
-                   feature_mask, bins_rowmajor=None, mv=None,
-                   compute_score_update: bool = True):
+                   feature_mask, mv=None,
+                   compute_score_update: bool = True,
+                   bag_cap: Optional[int] = None):
         """Per-tree program for the non-persistent path. Returns
         (tree arrays dict, leaf_of_row [n] in ORIGINAL row order or
-        None). ``bins_rowmajor`` is passed as a jit ARGUMENT on the
-        bagging path — a self.bins closure would embed the full bin
-        matrix as an HLO constant (hundreds of MB at HIGGS scale).
-        ``mv``: slot-major
-        [K, n] multi-value code planes, already in the same lane order
-        as ``codes_planes`` (bag-permuted on the bagging path)."""
+        None). ``codes_planes`` / ``mv`` are the RESIDENT row-order
+        planes ([code_planes, R] / slot-major [K, n]) and ``grad`` /
+        ``hess`` are in row order. ``bag_cap`` None: every row is in
+        the bag, the tree grows on the resident planes as they lie and
+        the partition's own leaf assignment serves the score update.
+        Otherwise (bagging, GOSS, RF with rows left out) the first
+        ``bag_cap`` rows of ``perm`` — a static capacity that holds the
+        bag (_bag_capacity) — are gathered into lane order here, once
+        per TREE, and every row's leaf comes from replaying the tree's
+        splits over the resident planes. The data-parallel grower runs
+        this same function per shard."""
         n = self.layout.num_rows
-        data = plane.build_data(self.layout, codes_planes, grad, hess,
-                                rowid=perm, mv=mv)
+        if bag_cap is None:
+            data = plane.build_data(self.layout, codes_planes, grad, hess,
+                                    rowid=perm, mv=mv)
+        else:
+            with jax.named_scope("lgbm.bag_gather"):
+                # ONE gather for codes, gradients and hessians: a [N]-
+                # sized gather pays its toll per index, not per plane
+                rows = perm[:bag_cap]
+                C, R = codes_planes.shape
+                src = jnp.concatenate(
+                    [codes_planes[:, :grad.shape[0]],
+                     plane.f32_as_i32(grad)[None],
+                     plane.f32_as_i32(hess)[None]], axis=0)
+                bag = jnp.take(src, rows, axis=1)
+                data = plane.build_data(
+                    self.layout,
+                    jnp.pad(bag[:C], ((0, 0), (0, R - bag_cap))),
+                    plane.i32_as_f32(bag[C]), plane.i32_as_f32(bag[C + 1]),
+                    rowid=rows,
+                    mv=None if mv is None else jnp.take(mv, rows, axis=1))
         ta, st = self._grow_tree_core(data, bag_cnt, feature_mask)
 
         leaf_of_row = None
         if compute_score_update:
-            if self._score_from_partition:
+            if bag_cap is None:
                 pos_leaf = self._pos_leaf(st)
                 rowids = st.data[self.layout.rowid][:n]
                 leaf_of_row = jnp.zeros(n, jnp.int32).at[rowids].set(
                     pos_leaf[:n], unique_indices=True)
             else:
-                leaf_of_row = self.traverse_bins(ta, bins_rowmajor)
+                with jax.named_scope("lgbm.row_traverse"):
+                    leaf_of_row = self.traverse_planes(ta, codes_planes)[:n]
         return ta, leaf_of_row
+
+    def _bag_capacity(self, bag_cnt: int) -> int:
+        """Static row capacity of the bag's gather. The first bag sets
+        it with a slack of eight standard deviations of a by-label draw
+        (a binomial's is at most sqrt(n) / 2), and it is kept while the
+        bag fits it with less than two slacks to spare: a bag whose
+        size is drawn anew each round (pos/neg bagging) keeps ONE grow
+        program. Rows between the bag and the capacity lie outside
+        every window (``bag_cnt`` is traced), so the capacity never
+        shows in a result."""
+        n = self.actual_rows
+        tile = self.layout.max_tile
+        slack = max(tile, 4 * math.isqrt(n))
+        cap = self._bag_cap
+        if cap is None or not cap - 2 * slack - tile <= bag_cnt <= cap:
+            cap = self._bag_cap = min(
+                -(-(int(bag_cnt) + slack) // tile) * tile, n)
+        return cap
 
     def grow_device(self, grad, hess, perm, bag_cnt,
                     compute_score_update=True):
         """Returns (tree_arrays dict of device arrays, leaf_of_row)."""
-        if self._score_from_partition:
-            cp = self.codes_planes()
+        if bag_cnt >= self.actual_rows:
+            # no row left out (also GOSS before sampling starts, a
+            # bagging round that keeps every row): perm is the identity
+            bag_cap = None
             perm_dev = jnp.arange(self.layout.num_rows, dtype=jnp.int32)
-            g, h = grad, hess
-            bins_arg = None
-            mv_arg = self._mv_dev
         else:
-            # bagging: one row gather per TREE (not per split) to build
-            # the bag-ordered planar pack
+            bag_cap = self._bag_capacity(bag_cnt)
             perm_dev = jnp.asarray(perm, jnp.int32)
-            cp = plane.build_codes_planes(self.bins[perm_dev], self.layout)
-            g, h = grad[perm_dev], hess[perm_dev]
-            bins_arg = self.bins
-            mv_arg = (None if self._mv_dev is None
-                      else self._mv_dev[:, perm_dev])
-        ta, leaf = self._grow_jit(self._tables(), cp, g, h, perm_dev,
-                                  jnp.int32(bag_cnt),
-                                  self.feature_masks_for_tree(), bins_arg,
-                                  mv_arg,
-                                  compute_score_update=compute_score_update)
+        ta, leaf = self._grow_jit(self._tables(), self.codes_planes(),
+                                  grad, hess, perm_dev, jnp.int32(bag_cnt),
+                                  self.feature_masks_for_tree(),
+                                  self._mv_dev,
+                                  compute_score_update=compute_score_update,
+                                  bag_cap=bag_cap)
         if leaf is not None and leaf.shape[0] != self.actual_rows:
             # row-bucketed layout: pad lanes scattered into positions
             # >= actual_rows (build_data's arange rowid continuation)
@@ -1846,13 +1888,11 @@ class FusedSerialGrower:
         return data
 
     # ------------------------------------------------------------------
-    def _traverse_device(self, ta) -> jax.Array:
-        return self.traverse_bins(ta, self.bins)
-
     def traverse_bins(self, ta, bins) -> jax.Array:
-        """Leaf index for every row (incl. out-of-bag) via bin-space
-        traversal of the freshly built tree (handles the OOB score path
-        of GBDT::UpdateScore and validation-set score updates)."""
+        """Leaf index for every row of a ROW-MAJOR bin table via
+        bin-space traversal of the freshly built tree: validation-set
+        score updates, whose tables are not planar. The training rows'
+        leaves come from traverse_planes."""
         n = bins.shape[0]
         node = jnp.where(ta["n_leaves"] > 1, 0, -1) * jnp.ones(n, jnp.int32)
         miss_tbl = self.feature_miss_bin
@@ -1894,6 +1934,47 @@ class FusedSerialGrower:
 
         node = jax.lax.while_loop(cond, body, node)
         return -node - 1
+
+    def traverse_planes(self, ta, codes_planes) -> jax.Array:
+        """Leaf index of every lane of the resident planar codes (lane
+        r = row r, out-of-bag rows included): the score update's side
+        of GBDT::UpdateScore under row sampling. The tree's splits are
+        replayed in the order they were made, each with the partition's
+        own routing (plane.route_scalars: EFB decode, missing bin,
+        categorical bitset), as one elementwise pass over the split
+        column's plane — no per-row gather and no row-major table.
+        Node k split leaf slot s: the left child keeps s, the right
+        child is leaf k + 1 (Tree::Split numbering, tree.h:61), and a
+        child that is split later inherits its slot."""
+        L = self.num_leaves
+
+        def step(k, carry):
+            leaf_of_lane, slot_of_node = carry
+            slot = slot_of_node[k]
+            f = ta["split_feature"][k]
+            rs = plane.route_scalars(
+                self.layout, f, ta["threshold_bin"][k],
+                ta["default_left"][k], self.feature_miss_bin[f],
+                self._efb_dev, is_cat=ta["split_cat"][k],
+                cat_bitset=ta["split_bits"][k])
+            col32 = jax.lax.dynamic_index_in_dim(codes_planes, rs[0],
+                                                 axis=0, keepdims=False)
+            go_right = ~plane._route_from_col32(col32, rs)
+            leaf_of_lane = jnp.where((leaf_of_lane == slot) & go_right,
+                                     k + 1, leaf_of_lane)
+            lc, rc = ta["left_child"][k], ta["right_child"][k]
+            # a leaf child (negative) indexes past the end and is dropped
+            slot_of_node = slot_of_node.at[jnp.where(lc >= 0, lc, L)].set(
+                slot, mode="drop")
+            slot_of_node = slot_of_node.at[jnp.where(rc >= 0, rc, L)].set(
+                k + 1, mode="drop")
+            return leaf_of_lane, slot_of_node
+
+        leaf_of_lane, _ = jax.lax.fori_loop(
+            0, ta["n_leaves"] - 1, step,
+            (jnp.zeros(codes_planes.shape[1], jnp.int32),
+             jnp.zeros(L - 1, jnp.int32)))
+        return leaf_of_lane
 
     # ------------------------------------------------------------------
     def _tree_mask_np(self) -> np.ndarray:

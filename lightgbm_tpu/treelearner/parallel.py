@@ -701,6 +701,28 @@ class FusedDataParallelGrower(FusedSerialGrower):
         return sig, shareable
 
     # -- sharded state construction ------------------------------------
+    def _pack_codes_per_device(self, sharding, shape):
+        """([(device, shard)], [shard's [code_planes, R] codes on its
+        device]) for a lane-sharded array of ``shape``: every shard is
+        packed ON the device that owns it from a host slice of the bin
+        matrix, so no device ever holds more than its own share."""
+        from ..ops import plane
+        sr, Ly = self.shard_rows, self.layout
+        bins = np.asarray(self.dataset.bins)
+        owned = [(dev, idx[1].start // Ly.num_lanes) for dev, idx in
+                 sharding.addressable_devices_indices_map(shape).items()]
+        # every device's pack is enqueued before the one block, so the
+        # chips pack side by side and the stage reads their longest
+        with span("fused/pack_codes", stage="state/pack_codes"):
+            packed = []
+            for dev, d in owned:
+                with jax.default_device(dev):
+                    packed.append(plane.build_codes_planes(
+                        jnp.asarray(bins[d * sr:(d + 1) * sr]), Ly))
+            # tpulint: sync-ok(set-up, once per state build, after every device's pack is enqueued)
+            jax.block_until_ready(packed)
+        return owned, packed
+
     def init_persistent_state(self, score_vec) -> jax.Array:
         """[P, D * num_lanes] planar state, lanes sharded over "data".
         Every shard is packed ON the device that owns it from host
@@ -712,7 +734,6 @@ class FusedDataParallelGrower(FusedSerialGrower):
         D, sr, Ly = self.num_shards, self.shard_rows, self.layout
         aux_label, aux_weight = self.objective.persistent_aux()
         n = self.global_rows
-        bins = np.asarray(self.dataset.bins)
         # one device->host copy each, sliced per shard below
         label, score, weight = (
             None if v is None else np.asarray(v, np.float32)
@@ -722,10 +743,6 @@ class FusedDataParallelGrower(FusedSerialGrower):
             """Shard d's slice of a global [n] host vector; build_data
             zero-pads it to the lane count."""
             return jnp.asarray(v[d * sr:(d + 1) * sr])
-
-        def pack_shard(d):
-            return plane.build_codes_planes(
-                jnp.asarray(bins[d * sr:(d + 1) * sr]), Ly)
 
         def build_shard(d, cp):
             # pad rows alias row id n -> dropped by the sync scatter
@@ -740,17 +757,7 @@ class FusedDataParallelGrower(FusedSerialGrower):
 
         shape = (Ly.num_planes, D * Ly.num_lanes)
         sharding = NamedSharding(self.mesh, P(None, "data"))
-        owned = [(dev, idx[1].start // Ly.num_lanes) for dev, idx in
-                 sharding.addressable_devices_indices_map(shape).items()]
-        # every device's pack is enqueued before the one block, so the
-        # chips pack side by side and the stage reads their longest
-        with span("fused/pack_codes", stage="state/pack_codes"):
-            packed = []
-            for dev, d in owned:
-                with jax.default_device(dev):
-                    packed.append(pack_shard(d))
-            # tpulint: sync-ok(set-up, once per state build, after every device's pack is enqueued)
-            jax.block_until_ready(packed)
+        owned, packed = self._pack_codes_per_device(sharding, shape)
         shards = []
         with span("fused/build_data", stage="state/build_data"):
             for (dev, d), cp in zip(owned, packed):
@@ -863,25 +870,26 @@ class FusedDataParallelGrower(FusedSerialGrower):
                 in_specs=(P(None, "data"),), out_specs=P())(body)(data)
 
     # -- sharded per-tree path (bagging / multiclass / custom fobj) -----
-    def _bins_row_sharded(self):
-        """[D, sr, F] row-contiguous bin shards (same ownership as the
-        persistent state: shard d owns rows [d*sr, (d+1)*sr))."""
-        if getattr(self, "_bins_sh", None) is None:
-            D, sr = self.num_shards, self.shard_rows
-            bins_np = np.asarray(self.dataset.bins)
-            pad = D * sr - bins_np.shape[0]
-            if pad:
-                bins_np = np.pad(bins_np, ((0, pad), (0, 0)), mode="edge")
-            self._bins_sh = jax.device_put(
-                bins_np.reshape(D, sr, -1),
-                NamedSharding(self.mesh, P("data", None, None)))
-        return self._bins_sh
+    def _codes_planes_sharded(self):
+        """[code_planes, D * num_lanes] resident planar codes, lanes
+        sharded over "data" (same ownership as the persistent state:
+        shard d owns rows [d*sr, (d+1)*sr))."""
+        if getattr(self, "_cp_sh", None) is None:
+            Ly = self.layout
+            shape = (Ly.code_planes, self.num_shards * Ly.num_lanes)
+            sharding = NamedSharding(self.mesh, P(None, "data"))
+            _, packed = self._pack_codes_per_device(sharding, shape)
+            self._cp_sh = jax.make_array_from_single_device_arrays(
+                shape, sharding, packed)
+        return self._cp_sh
 
     def _sharded_bag_views(self, perm, bag_cnt):
-        """Device-resident (per-shard local perms, per-shard counts) for
-        a bag. Cached on the perm object so the k class trees of one
-        iteration (and consecutive no-bagging iterations) skip the O(n)
-        host pass and the [n]-sized upload entirely."""
+        """Device-resident (per-shard local perms, per-shard counts) and
+        the static gather capacity that holds the fullest shard's bag
+        (None when no row is left out), for a bag. Cached on the perm
+        object so the k class trees of one iteration (and consecutive
+        no-bagging iterations) skip the O(n) host pass and the
+        [n]-sized upload entirely."""
         key = (id(perm), int(bag_cnt))
         if getattr(self, "_bag_cache_key", None) == key:
             return self._bag_cache_val
@@ -893,44 +901,41 @@ class FusedDataParallelGrower(FusedSerialGrower):
                 np.arange(sr, dtype=np.int32)[None], (D, sr))
             counts = np.asarray(
                 [max(0, min(n - d * sr, sr)) for d in range(D)], np.int32)
+            bag_cap = None
         else:
             perm_np, counts = shard_bag_permutation(perm, bag_cnt, D, sr)
+            bag_cap = self._bag_capacity(int(counts.max()))
         val = (jax.device_put(jnp.asarray(perm_np), spec_rows),
                jax.device_put(jnp.asarray(counts),
-                              NamedSharding(self.mesh, P("data"))))
+                              NamedSharding(self.mesh, P("data"))),
+               bag_cap)
         self._bag_cache_key = key
         self._bag_cache_ref = perm      # keep id() stable
         self._bag_cache_val = val
         return val
 
     def _grow_mc_jit_build(self):
-        from ..ops import plane
-        Ly = self.layout
+        def grow(cp, perm, cnt, g, h, mask, bag_cap):
+            def body(cp_l, perm_l, cnt_l, g_l, h_l, mask_):
+                # the serial per-tree program on the shard's own rows:
+                # local bag, local planes, psum'd histograms
+                ta, leaf = self._grow_tree(
+                    cp_l, g_l[0], h_l[0], perm_l[0], cnt_l[0], mask_,
+                    bag_cap=bag_cap)
+                return ta, leaf[:self.shard_rows][None]
 
-        def body(bins_l, perm_l, cnt_l, g_l, h_l, mask):
-            bins_l, perm_l, cnt_l = bins_l[0], perm_l[0], cnt_l[0]
-            g_l, h_l = g_l[0], h_l[0]
-            # one row gather per TREE (not per split) builds the
-            # bag-ordered planar pack, as on the single-chip path
-            cp = plane.build_codes_planes(bins_l[perm_l], Ly)
-            data = plane.build_data(Ly, cp, g_l[perm_l], h_l[perm_l],
-                                    rowid=perm_l)
-            ta, _st = self._grow_tree_core(data, cnt_l, mask)
-            # leaf of EVERY local row (incl. out-of-bag) for the score
-            # update, via bin-space traversal of the fresh tree
-            leaf = self.traverse_bins(ta, bins_l)
-            return ta, leaf[None]
+            return functools.partial(
+                shard_map, mesh=self.mesh, check_vma=False,
+                in_specs=(P(None, "data"), P("data", None), P("data"),
+                          P("data", None), P("data", None), P()),
+                out_specs=(P(), P("data", None)))(body)(
+                    cp, perm, cnt, g, h, mask)
 
-        f = functools.partial(
-            shard_map, mesh=self.mesh, check_vma=False,
-            in_specs=(P("data", None, None), P("data", None), P("data"),
-                      P("data", None), P("data", None), P()),
-            out_specs=(P(), P("data", None)))(body)
         from ..compile import get_manager
         sig, ok = self._mc_signature()
         return get_manager().shared_entry(
             "mc/grow_tree", sig,
-            lambda: jax.jit(f),  # tpulint: jit-ok(inside a shared_entry builder; the manager dispatches this jit)
+            lambda: jax.jit(grow, static_argnames=("bag_cap",)),  # tpulint: jit-ok(inside a shared_entry builder; the manager dispatches this jit)
             store=ok)
 
     def grow_device(self, grad, hess, perm, bag_cnt,
@@ -940,7 +945,8 @@ class FusedDataParallelGrower(FusedSerialGrower):
         network layer; here every config runs the same while_loop
         program per shard with psum'd histograms)."""
         D, sr, n = self.num_shards, self.shard_rows, self.global_rows
-        perm_dev, counts_dev = self._sharded_bag_views(perm, bag_cnt)
+        perm_dev, counts_dev, bag_cap = self._sharded_bag_views(perm,
+                                                                bag_cnt)
         spec_rows = NamedSharding(self.mesh, P("data", None))
 
         def pad_rows(v):
@@ -953,9 +959,9 @@ class FusedDataParallelGrower(FusedSerialGrower):
         with collective_span("fused_tree_psum", self._tree_psum_bytes,
                              axis="data"):
             ta, leaf = self._grow_mc_tree_jit(
-                self._bins_row_sharded(), perm_dev, counts_dev,
+                self._codes_planes_sharded(), perm_dev, counts_dev,
                 pad_rows(grad), pad_rows(hess),
-                self.feature_masks_for_tree())
+                self.feature_masks_for_tree(), bag_cap=bag_cap)
         leaf_of_row = leaf.reshape(-1)[:n] if compute_score_update else None
         return ta, leaf_of_row
 

@@ -89,6 +89,28 @@ def test_fused_dp_uneven_shards():
     assert float(np.mean(np.abs(p1 - p2))) < 0.01
 
 
+def test_fused_dp_sampled_rows_match_serial():
+    """GOSS on shards of uneven size: each shard runs the serial grower's
+    own per-tree program (FusedSerialGrower._grow_tree) on its resident
+    code planes — local bag gathered from them, every local row's leaf by
+    replaying the splits over them — so no shard holds a row-major table.
+    The third tree is the first grown on a sample."""
+    X, y = _make(n=2001)
+    base = {"objective": "binary", "boosting": "goss", "num_leaves": 7,
+            "learning_rate": 0.5, "verbose": -1}
+    b_serial = _train(dict(base, tree_learner="serial"), X, y, rounds=4)
+    b_dp = _train(dict(base, tree_learner="data"), X, y, rounds=4)
+    g = b_dp._gbdt._fused
+    from lightgbm_tpu.treelearner.parallel import FusedDataParallelGrower
+    assert isinstance(g, FusedDataParallelGrower)
+    assert b_dp._gbdt.bag_data_cnt == 400 + 200
+    assert g._cp_sh.shape == (g.layout.code_planes,
+                              g.num_shards * g.layout.num_lanes)
+    assert g._bins_dev is None and not hasattr(g, "_bins_sh")
+    p1, p2 = b_serial.predict(X), b_dp.predict(X)
+    assert float(np.mean(np.abs(p1 - p2))) < 1e-4
+
+
 @pytest.mark.slow
 def test_fused_dp_bagging_matches_serial():
     """Round-4: the sharded fused grower covers bagging via per-shard

@@ -1,0 +1,324 @@
+"""Sampled training (GOSS) on bundled one-hot columns against a numpy
+oracle: the sampler against the rule of goss.hpp:111-147, the first
+sampled tree of `lgb.train` against float64 sums over its own bag, EFB
+against `enable_bundle=false` and the dense copy, what the per-tree
+fused tier says about itself (plan, scopes), and the one grow program a
+bag of drifting size keeps. CPU, 20,000 x 700 at most.
+
+The oracle is inline, as the other tests' are; benchmarks/reference/
+keeps its own copy for the chip (`goss_numpy.py`).
+"""
+import math
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+import scipy.sparse as sp
+
+import lightgbm_tpu as lgb
+from lightgbm_tpu.boosting import gbdt as G
+
+FIELDS = (12, 31, 7, 22, 313, 313)      # one-hot fields: 698 columns + 2
+P = dict(objective="binary", boosting="goss", top_rate=0.2, other_rate=0.1,
+         num_leaves=31, max_bin=255, learning_rate=0.5,
+         min_sum_hessian_in_leaf=20, min_data_in_leaf=0, verbose=-1)
+
+
+# ------------------------------------------------------------- the oracle
+
+def goss_counts(n, top_rate, other_rate):
+    top_k = max(1, int(n * top_rate))
+    return top_k, max(1, min(int(n * other_rate), n - top_k))
+
+
+def goss_top_set(g, h, top_k):
+    """Rows of the top_k largest sum_c |g*h|, ties to the lower row id."""
+    w = np.abs(g.astype(np.float64) * h.astype(np.float64)).sum(axis=0)
+    return np.sort(np.lexsort((np.arange(len(w)), -w))[:top_k])
+
+
+def one_hot_rows(n, seed):
+    """(CSR float32 [n, 700] with 8 stored values a row, y): two numeric
+    columns and six one-hot fields with uneven levels."""
+    rng = np.random.default_rng(seed)
+    cols = [np.zeros(n, np.int64), np.ones(n, np.int64)]
+    vals = [rng.uniform(0.1, 24.0, n), np.exp(rng.normal(1.6, 0.7, n))]
+    score = 0.5 * np.sin(vals[0] / 4.0)
+    off = 2
+    for card in FIELDS:
+        p = 1.0 / np.arange(1, card + 1) ** 0.6
+        lev = rng.choice(card, n, p=p / p.sum())
+        score += np.random.default_rng(card).normal(0, 0.6, card)[lev]
+        cols.append(off + lev)
+        vals.append(np.ones(n))
+        off += card
+    X = sp.csr_matrix(
+        (np.stack(vals, 1).astype(np.float32).ravel(),
+         np.stack(cols, 1).astype(np.int32).ravel(),
+         np.arange(0, 8 * n + 1, 8, dtype=np.int32)), shape=(n, off))
+    y = (rng.random(n) < 1 / (1 + np.exp(-1.5 * score))).astype(np.float32)
+    return X, y
+
+
+def parse_tree(text, index):
+    block = dict(ln.split("=", 1) for ln in
+                 text.split(f"\nTree={index}\n")[1].split("\n\n")[0]
+                 .splitlines() if "=" in ln)
+    num = lambda key, dt: np.asarray(block[key].split(), dt)  # noqa: E731
+    return dict(feature=num("split_feature", np.int64),
+                threshold=num("threshold", np.float64),
+                left=num("left_child", np.int64),
+                right=num("right_child", np.int64),
+                value=num("leaf_value", np.float64),
+                count=num("leaf_count", np.int64))
+
+
+def leaf_of(tree, dense):
+    node = np.zeros(len(dense), np.int64)
+    rows = np.arange(len(dense))
+    while rows.size:
+        at = node[rows]
+        go_left = dense[rows, tree["feature"][at]] <= tree["threshold"][at]
+        node[rows] = np.where(go_left, tree["left"][at], tree["right"][at])
+        rows = rows[node[rows] >= 0]
+    return ~node
+
+
+# ------------------------------------------------------------ the sampler
+
+@pytest.mark.parametrize("classes", [1, 3])
+@pytest.mark.parametrize("rates", [(0.2, 0.1), (0.1, 0.1)])
+@pytest.mark.parametrize("n", [1000, 4097])
+def test_sampler_against_the_numpy_rule(n, rates, classes):
+    rng = np.random.default_rng(n + classes)
+    g = rng.normal(size=(classes, n)).astype(np.float32)
+    h = rng.uniform(0.05, 0.25, size=(classes, n)).astype(np.float32)
+    top_k, other_k = goss_counts(n, *rates)
+    g2, h2, perm = jax.jit(
+        G._goss_sample_device, static_argnames=("top_k", "other_k"))(
+        jnp.asarray(g), jnp.asarray(h), jnp.int32(7),
+        top_k=top_k, other_k=other_k)
+    g2, h2, perm = np.asarray(g2), np.asarray(h2), np.asarray(perm)
+
+    bag, oob = perm[:top_k + other_k], perm[top_k + other_k:]
+    assert np.array_equal(np.sort(perm), np.arange(n))
+    assert np.array_equal(bag, np.sort(bag)), "the bag keeps row order"
+    assert np.array_equal(oob, np.sort(oob)), "and so do the rest"
+    mult = np.float32((n - top_k) / other_k)
+    weighted = np.flatnonzero(~np.isclose(g2[0], g[0], rtol=1e-6, atol=0))
+    top = goss_top_set(g, h, top_k)
+    assert np.array_equal(np.setdiff1d(bag, weighted), top), "the top set"
+    assert len(weighted) == other_k and not np.intersect1d(weighted, top).size
+    assert np.isin(weighted, bag).all()
+    np.testing.assert_allclose(g2[:, weighted], g[:, weighted] * mult, 1e-6)
+    np.testing.assert_allclose(h2[:, weighted], h[:, weighted] * mult, 1e-6)
+    rest = np.setdiff1d(np.arange(n), weighted)
+    assert np.array_equal(g2[:, rest], g[:, rest])
+    assert np.array_equal(h2[:, rest], h[:, rest])
+
+
+# ------------------------------------------------- through lgb.train
+
+@pytest.fixture(scope="module")
+def flights():
+    return one_hot_rows(20_000, seed=5)
+
+
+def test_first_sampled_tree_is_the_oracles_on_the_same_bag(flights):
+    """learning_rate 0.5: sampling starts at iteration 2, the third tree."""
+    X, y = flights
+    n = X.shape[0]
+    bst = lgb.train(P, lgb.Dataset(X, label=y), num_boost_round=2,
+                    keep_training_booster=True)
+    gb = bst._gbdt
+    score = np.asarray(gb.get_training_score(), np.float64)[0]
+    bst.update()
+    top_k, other_k = goss_counts(n, 0.2, 0.1)
+    assert gb.bag_data_cnt == top_k + other_k
+    bag = np.asarray(gb._perm)[:gb.bag_data_cnt]
+
+    p = 1.0 / (1.0 + np.exp(-score))
+    g, h = p - y, p * (1.0 - p)
+    mult = (n - top_k) / other_k
+    is_other = np.abs(np.asarray(gb._grad[0])[bag]) > 4.0 * np.abs(g[bag])
+    assert is_other.sum() == other_k
+    top = goss_top_set(g[None], h[None], top_k)
+    # float32 weights against float64 ones: all but a few rows at the
+    # threshold agree
+    assert len(np.setdiff1d(top, bag[~is_other])) <= 3
+
+    tree = parse_tree(bst.model_to_string(), 2)
+    leaf = leaf_of(tree, X[bag].toarray())
+    scale = np.where(is_other, mult, 1.0)
+    L = len(tree["value"])
+    assert np.array_equal(np.bincount(leaf, None, L), tree["count"])
+    value = -0.5 * (np.bincount(leaf, g[bag] * scale, L)
+                    / np.bincount(leaf, h[bag] * scale, L))
+    np.testing.assert_allclose(tree["value"], value, rtol=2e-4, atol=2e-6)
+    flat = -0.5 * (np.bincount(leaf, g[bag], L) / np.bincount(leaf, h[bag], L))
+    assert np.max(np.abs(tree["value"] - flat)) > 1e-2, \
+        "weights left at 1 would pass too: the oracle cannot see them"
+
+
+@pytest.mark.parametrize("other", ["enable_bundle=false", "dense"])
+def test_bundling_loses_nothing(flights, other):
+    """efb_max_conflict_rate=0: only columns that no sampled row sets
+    together are bundled, so the trees are those of the unbundled columns:
+    the same splits and counts, and values that differ by the order of a
+    float32 sum."""
+    X, y = flights
+    X, y = X[:4000], y[:4000]
+    params = dict(P, num_leaves=15, efb_max_conflict_rate=0.0)
+    bundled = lgb.Dataset(X, label=y, params=params).construct()
+    assert bundled._handle.bins.shape[1] <= 16
+    want = lgb.train(params, bundled, num_boost_round=3).model_to_string()
+    if other == "dense":
+        ds = lgb.Dataset(X.toarray(), label=y, params=params)
+    else:
+        params = dict(params, enable_bundle=False)
+        ds = lgb.Dataset(X, label=y, params=params)
+        assert ds.construct()._handle.bins.shape[1] > 600
+    got = lgb.train(params, ds, num_boost_round=3).model_to_string()
+    for index in range(3):          # the third tree is grown on a sample
+        a, b = parse_tree(want, index), parse_tree(got, index)
+        for key in ("feature", "threshold", "left", "right", "count"):
+            assert np.array_equal(a[key], b[key]), (index, key)
+        np.testing.assert_allclose(a["value"], b["value"], atol=2e-5)
+
+
+@pytest.mark.parametrize("extra, sampling", [
+    (dict(boosting="goss"), "goss(top_rate=0.2, other_rate=0.1)"),
+    (dict(boosting="gbdt", bagging_fraction=0.8, bagging_freq=1),
+     "bagging(0.8/1)"),
+    (dict(boosting="gbdt"), None)])
+def test_execution_plan_names_the_sampling(extra, sampling):
+    X, y = one_hot_rows(2000, seed=1)
+    bst = lgb.train(dict(P, **extra), lgb.Dataset(X, label=y),
+                    num_boost_round=1, keep_training_booster=True)
+    plan = bst._gbdt.execution_plan()
+    assert plan["sampling"] == sampling
+    assert plan["tier"] == ("per-tree-fused" if sampling
+                            else "persistent-fused")
+
+
+# ------------------------------------------------- the bag's capacity
+
+def test_a_bag_of_drifting_size_keeps_one_grow_program():
+    """pos/neg bagging draws the bag's size anew each round. Planted:
+    16,384 rows at 0.5 / 0.5, so the sizes straddle 8,192, a multiple of
+    the lane tile; a capacity rounded up from each bag would compile the
+    grow program again at every crossing."""
+    rng = np.random.default_rng(3)
+    X = rng.normal(size=(16384, 6)).astype(np.float32)
+    y = (X[:, 0] + 0.3 * rng.normal(size=len(X)) > 0).astype(np.float32)
+    params = dict(objective="binary", num_leaves=7, verbose=-1,
+                  pos_bagging_fraction=0.5, neg_bagging_fraction=0.5,
+                  bagging_freq=1)
+    bst = lgb.train(params, lgb.Dataset(X, label=y), num_boost_round=1,
+                    keep_training_booster=True)
+    g = bst._gbdt._fused
+    calls, grow = [], g._grow_jit
+
+    def spy(*args, **statics):
+        calls.append((int(args[5]), statics["bag_cap"]))
+        return grow(*args, **statics)
+
+    g._grow_jit = spy
+    for _ in range(10):
+        bst.update()
+    counts, caps = zip(*calls)
+    tile = g.layout.max_tile
+    assert len({-(-c // tile) for c in counts}) > 1, (counts, tile)
+    assert len(set(caps)) == 1 and max(counts) <= caps[0] <= len(X), calls
+    assert bst._gbdt.execution_plan()["tier"] == "per-tree-fused"
+
+
+@pytest.mark.parametrize("counts, programs", [
+    # 22M rows, half kept by label: five standard deviations either way
+    ([11_000_000 + d for d in (0, 11_700, -11_700, 5_000, -9_000)], 1),
+    # GOSS: the same bag every round
+    ([6_600_000] * 4, 1),
+    # the fraction itself changes (reset_parameter): a new capacity
+    ([6_600_000, 6_601_000, 3_300_000, 3_299_000], 2)])
+def test_bag_capacity(counts, programs):
+    from types import SimpleNamespace
+    from lightgbm_tpu.treelearner.fused import FusedSerialGrower
+    n, tile = 22_000_000, 8192
+    g = SimpleNamespace(actual_rows=n, _bag_cap=None,
+                        layout=SimpleNamespace(max_tile=tile))
+    caps = [FusedSerialGrower._bag_capacity(g, c) for c in counts]
+    assert len(set(caps)) == programs, caps
+    for c, cap in zip(counts, caps):
+        # it holds the bag, on whole tiles, and wastes eight standard
+        # deviations of a by-label draw (0.2 % of these bags) and a tile
+        assert c <= cap <= n and cap % tile == 0
+        assert cap - c < 2 * (4 * math.isqrt(n) + tile)
+
+
+def test_a_round_that_keeps_every_row_gathers_nothing():
+    """GOSS before sampling starts: the grow program is the one a run
+    without sampling uses (no gather by the identity, no traverse)."""
+    X, y = one_hot_rows(2000, seed=4)
+    bst = lgb.train(dict(P, learning_rate=0.25), lgb.Dataset(X, label=y),
+                    num_boost_round=1, keep_training_booster=True)
+    g = bst._gbdt._fused
+    caps, grow = [], g._grow_jit
+
+    def spy(*args, **statics):
+        caps.append(statics["bag_cap"])
+        return grow(*args, **statics)
+
+    g._grow_jit = spy
+    for _ in range(5):
+        bst.update()                       # iterations 1..5; sampling from 4
+    assert caps[:3] == [None] * 3 and caps[3] is not None
+    assert caps[3] == caps[4] >= bst._gbdt.bag_data_cnt
+
+
+# ------------------------------------------------------------ the scopes
+
+@pytest.fixture(scope="module")
+def per_tree_programs():
+    """{program: lowered text with locations} of a sampled iteration."""
+    X, y = one_hot_rows(3000, seed=2)
+    bst = lgb.train(dict(P, num_leaves=7), lgb.Dataset(X, label=y),
+                    num_boost_round=3, keep_training_booster=True)
+    gb = bst._gbdt
+    g = gb._fused
+    n = gb.num_data
+    top_k, other_k = goss_counts(n, 0.2, 0.1)
+    cap = g._bag_capacity(gb.bag_data_cnt)
+    vec = jnp.zeros(n, jnp.float32)
+    lowered = {
+        "sampler": jax.jit(G._goss_sample_device,
+                           static_argnames=("top_k", "other_k")).lower(
+            gb._grad, gb._hess, jnp.int32(1), top_k=top_k, other_k=other_k),
+        "grow": jax.jit(g._entry_grow_tree,
+                        static_argnames=("compute_score_update",
+                                         "bag_cap")).lower(
+            g._tables(), g.codes_planes(), vec, vec, gb._perm,
+            jnp.int32(gb.bag_data_cnt), g.feature_masks_for_tree(), None,
+            compute_score_update=True, bag_cap=cap),
+        "score_add": jax.jit(G._score_add_device,
+                             static_argnames=("class_id",)).lower(
+            gb.train_score.score, jnp.zeros(7, jnp.float32),
+            jnp.zeros(n, jnp.int32), class_id=0),
+        "gradients": type(gb.objective).get_gradients.lower(
+            gb.objective, gb.train_score.score[0]),
+    }
+    return {k: v.as_text(debug_info=True) for k, v in lowered.items()}
+
+
+@pytest.mark.parametrize("scope, program", [
+    ("lgbm.goss_sample", "sampler"), ("lgbm.bag_gather", "grow"),
+    ("lgbm.row_traverse", "grow"), ("lgbm.score_update", "score_add"),
+    ("lgbm.grad", "gradients")])
+def test_per_tree_program_carries_scope(per_tree_programs, scope, program):
+    names = re.findall(r'loc\("([^"]*)"', per_tree_programs[program])
+    under = [nm for nm in names if scope in nm.split("/")]
+    assert under, f"no op of the {program} program is under {scope}"
+    if program == "grow":
+        # the shared grower's own stages are there beside it
+        assert any("lgbm.partition" in nm.split("/") for nm in names)

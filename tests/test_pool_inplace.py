@@ -33,6 +33,12 @@ CASES = {
     "persistent_quantized_i32": ({"use_quantized_grad": True}, "s32"),
     "per_tree": ({"objective": "multiclass", "num_class": 3}, "f32"),
     "data_parallel": ({"tree_learner": "data", "tpu_mesh_shape": [4]}, "f32"),
+    # row sampling: the bag gathered from the resident planes, every row's
+    # leaf by replaying the splits over them (FusedSerialGrower._grow_tree)
+    "per_tree_sampled": ({"bagging_fraction": 0.5, "bagging_freq": 1}, "f32"),
+    "data_parallel_sampled": ({"tree_learner": "data", "tpu_mesh_shape": [4],
+                               "bagging_fraction": 0.5, "bagging_freq": 1},
+                              "f32"),
 }
 
 
@@ -98,6 +104,23 @@ def _on(avals, sharding):
 
 def _lowered(case, g, request):
     """The program of `case`, lowered on the avals its warm-up would use."""
+    aval = jax.ShapeDtypeStruct
+    if case == "data_parallel_sampled":
+        g.mesh = mesh = Mesh(np.asarray(request.getfixturevalue("four_chips")),
+                             ("data",))
+        Ly, D, sr = g.layout, g.num_shards, g.shard_rows
+
+        def on(*spec):
+            return NamedSharding(mesh, P(*spec))
+
+        rows = aval((D, sr), jnp.float32, sharding=on("data", None))
+        return g._grow_mc_jit_build().jit_fn().lower(
+            aval((Ly.code_planes, D * Ly.num_lanes), jnp.int32,
+                 sharding=on(None, "data")),
+            aval((D, sr), jnp.int32, sharding=on("data", None)),
+            aval((D,), jnp.int32, sharding=on("data")), rows, rows,
+            aval((g.num_features,), jnp.bool_, sharding=on()),
+            bag_cap=g._bag_capacity(sr // 2))
     if case == "data_parallel":
         mesh = Mesh(np.asarray(request.getfixturevalue("four_chips")),
                     ("data",))
@@ -122,6 +145,19 @@ def _lowered(case, g, request):
             aval((), jnp.float32, sharding=on()),
             aval((), jnp.float32, sharding=on()))
     chip = SingleDeviceSharding(request.getfixturevalue("one_chip")[0])
+    if case == "per_tree_sampled":
+        Ly, n = g.layout, g.actual_rows
+        tables = jax.tree_util.tree_map(lambda a: aval(a.shape, a.dtype),
+                                        g._tables())
+        args = (tables, aval((Ly.code_planes, Ly.num_lanes), jnp.int32),
+                aval((n,), jnp.float32), aval((n,), jnp.float32),
+                aval((n,), jnp.int32), aval((), jnp.int32),
+                aval((g.num_features,), jnp.bool_), None)
+        return jax.jit(g._entry_grow_tree,
+                       static_argnames=("compute_score_update",
+                                        "bag_cap")).lower(
+            *_on(args, chip), compute_score_update=True,
+            bag_cap=g._bag_capacity(n // 2))
     if case == "per_tree":
         (args, statics), = g._grow_entry.specs
         return jax.jit(g._entry_grow_tree,
@@ -135,7 +171,7 @@ def _lowered(case, g, request):
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_no_pool_shaped_copy_in_the_v5e_program(case, as_on_tpu, request):
     extra, dtype = CASES[case]
-    if case == "data_parallel" and len(jax.devices()) < 4:
+    if case.startswith("data_parallel") and len(jax.devices()) < 4:
         pytest.skip("needs 4 (virtual) devices to build the grower")
     g = _grower(extra)
     text = _lowered(case, g, request).compile().as_text()
