@@ -20,10 +20,15 @@ registers, so no primitive ever pays a per-row toll:
 - **Stable partition as a carry stream**: grid pass 0 emits
   [pre-window rows | left rows], pass 1 continues with
   [right rows | tail rows] — one contiguous output stream. Each tile
-  compacts its kept lanes in-register via LSB-first binary shifts
-  (log2(S) rounds of `pltpu.roll` + select; stability proven by
-  exhaustive test), prepends the <128-lane carry from the previous
-  step, and DMAs a fixed `[P, S+128]` chunk to a 128-aligned offset.
+  compacts its kept lanes in-register by ONE plan for all the streams
+  it feeds (`_compact_streams`): the streams' keep rows stacked in the
+  sublanes of one array, one prefix sum for their ranks, then an
+  LSB-first binary shift network — log2(S) rounds of `pltpu.roll` +
+  select in which the stacked shifts are rolled, masked and selected
+  once for all streams and never decremented (tests/test_kernels.py
+  holds it to numpy's stable partition, exhaustively at 16 lanes). The
+  tile then prepends the <128-lane carry from the previous step and
+  DMAs a fixed `[P, S+128]` chunk to a 128-aligned offset.
   Consecutive chunks overlap by design (the garbage tail of chunk k
   is rewritten as the carry head of chunk k+1), so writes are
   serialized DMA k.wait -> DMA k+1.start while compute overlaps.
@@ -66,8 +71,9 @@ def partition_vmem_bytes_at(P: int, S: int, method: str = "pallas2") -> int:
     planes) can exceed the 16 MB scoped limit. Widths are CALIBRATED to
     compiler-reported scoped allocations (Mosaic multi-buffers the
     pipeline block on top of the declared scratch): at P=152, S=4096
-    the compiler reports 21.97 MB for v2 and 18.12 MB for v1 — ~8.8*S
-    and ~7.3*S lane-widths; a margin is added on both."""
+    the compiler reports 21.97 MB for v2 and 18.25 MB for v1 (re-read
+    in PR 28, compiled for a described v5e) — ~8.8*S and ~7.3*S
+    lane-widths; a margin is added on both."""
     width = 16 * S if method == "pallas2" else 8 * S
     return P * width * 4
 
@@ -407,14 +413,58 @@ def _lane_iota(s):
     return jax.lax.broadcasted_iota(jnp.int32, (1, s), 1)
 
 
-def _lane_prefix(x, s):
-    """Hillis-Steele inclusive prefix sum along lanes of [1, s] i32."""
-    from jax.experimental.pallas import tpu as pltpu
+def _lane_prefix(x, roll):
+    """Hillis-Steele inclusive prefix sum along the lanes of every row
+    of [K, s] i32."""
+    s = x.shape[1]
     b = 1
     while b < s:
-        x = x + jnp.where(_lane_iota(s) >= b, pltpu.roll(x, b, 1), 0)
+        x = x + jnp.where(_lane_iota(s) >= b, roll(x, b, 1), 0)
         b *= 2
     return x
+
+
+def _compact_streams(x, keeps, roll=None):
+    """In-tile stable compaction of the [P, S] tile ``x``, ONE plan for
+    all of ``keeps`` (K <= 8 rows of [1, S] i32 0/1, one per output
+    stream). Returns (K compacted [P, S] copies, K kept counts): copy j
+    holds the lanes with ``keeps[j] == 1`` in their order, in its lanes
+    [0, count j); the lanes past that are garbage.
+
+    The K keep rows are stacked in the sublanes of one [8, S] array (row
+    j by sublane iota + select; a [1, S] i32 row costs the vregs of an
+    [8, S] one, so the stack is free), which gets ONE prefix sum. Lane
+    i of stream j has to move down by shift = i - (rank - 1) lanes; the
+    LSB-first binary network does that in log2(S) rounds, round b
+    moving the lanes whose shift has bit b down by b. Every stream
+    rolls by the same S - b in the same round, so the stacked shifts
+    take one roll, one ``& b``, one compare and one select per round
+    for all K, and each stream's data takes its own row of the mask,
+    broadcast over the planes. A moved shift keeps its bit b: no later
+    round tests a bit at or below b, so clearing it would be dead work.
+    ``roll`` is `pltpu.roll` inside a kernel (tests pass `jnp.roll`)."""
+    if roll is None:
+        from jax.experimental.pallas import tpu as pltpu
+        roll = pltpu.roll
+    S = x.shape[1]
+    sub = jax.lax.broadcasted_iota(jnp.int32, (8, S), 0)
+    keep8 = jnp.broadcast_to(keeps[-1], (8, S))
+    for j in range(len(keeps) - 2, -1, -1):
+        keep8 = jnp.where(sub == j, keeps[j], keep8)
+    ranks = _lane_prefix(keep8, roll)
+    sh = jnp.where(keep8 == 1, _lane_iota(S) - (ranks - 1), 0)
+    comps = [x] * len(keeps)
+    b = 1
+    while b < S:
+        moved = roll(sh, S - b, 1)
+        take = moved & b
+        for j in range(len(keeps)):
+            comps[j] = jnp.where(
+                jnp.broadcast_to(take[j:j + 1], x.shape) != 0,
+                roll(comps[j], S - b, 1), comps[j])
+        sh = jnp.where(take != 0, moved, sh)
+        b *= 2
+    return comps, [jnp.sum(k) for k in keeps]
 
 
 def _partition_kernel(scal, data_ref, dout_ref, win_ref, nleft_ref,
@@ -469,19 +519,7 @@ def _partition_kernel(scal, data_ref, dout_ref, win_ref, nleft_ref,
         nl_here = jnp.sum(jnp.where(side == 0,
                                     (valid & go_left).astype(jnp.int32), 0))
 
-        # --- in-register stable compaction (LSB-first binary shifts) ---
-        ranks = _lane_prefix(keep, S)
-        k = jnp.sum(keep)
-        shift = jnp.where(keep == 1, _lane_iota(S) - (ranks - 1), 0)
-        comp = x
-        sh = shift
-        b = 1
-        while b < S:
-            moved_sh = pltpu.roll(sh, S - b, 1)
-            m1 = (moved_sh & b) != 0
-            comp = jnp.where(m1, pltpu.roll(comp, S - b, 1), comp)
-            sh = jnp.where(m1, moved_sh - b, sh)
-            b *= 2
+        (comp,), (k,) = _compact_streams(x, [keep])
 
         c = smem[2]
         written = pl.multiple_of(smem[1], 128)
@@ -663,7 +701,10 @@ def _partition_kernel2(scal, data_ref, dout_ref, win_ref, nleft_ref,
     coordinates (so it is already destination-aligned), and the
     R stream [rights|tail] carry-written into a second scratch region
     at fixed anchor `RB0 + S` (so its coordinates are independent of
-    the — still unknown — boundary). The two chunk-write chains are
+    the — still unknown — boundary). Both come from one compaction
+    plan per tile (`_compact_streams` with the rows keep_l, keep_r: one
+    prefix sum, one stacked shift row, the two data networks advancing
+    round by round together). The two chunk-write chains are
     independent and interleave, halving the per-step wait latency of
     the v1 design, and the window is read once instead of twice.
 
@@ -714,21 +755,6 @@ def _partition_kernel2(scal, data_ref, dout_ref, win_ref, nleft_ref,
         asteps = smem[5]
         slot = jax.lax.rem(asteps, 2)
 
-        def compact(keep):
-            ranks = _lane_prefix(keep, S)
-            k = jnp.sum(keep)
-            shift = jnp.where(keep == 1, _lane_iota(S) - (ranks - 1), 0)
-            comp = x
-            sh = shift
-            b = 1
-            while b < S:
-                moved_sh = pltpu.roll(sh, S - b, 1)
-                m1 = (moved_sh & b) != 0
-                comp = jnp.where(m1, pltpu.roll(comp, S - b, 1), comp)
-                sh = jnp.where(m1, moved_sh - b, sh)
-                b *= 2
-            return comp, k
-
         def emit(comp, k, cursor_slot, carry_slot, stg0, stg1, cbuf, sems):
             """One stream's carry-chunk write (the v1 mechanism)."""
             c = smem[carry_slot]
@@ -768,9 +794,8 @@ def _partition_kernel2(scal, data_ref, dout_ref, win_ref, nleft_ref,
             smem[cursor_slot] = written + adv
             smem[carry_slot] = total - adv
 
-        compL, kL = compact(keep_l)
+        (compL, compR), (kL, kR) = _compact_streams(x, [keep_l, keep_r])
         emit(compL, kL, 0, 1, stgL0, stgL1, cbufL, semL)
-        compR, kR = compact(keep_r)
         emit(compR, kR, 2, 3, stgR0, stgR1, cbufR, semR)
 
         smem[4] = smem[4] + nl_here

@@ -1,206 +1,257 @@
 """Partition-kernel cost decomposition on the real chip.
 
 Host wall timings of single dispatches are not device time, so every
-number here comes from the device-side profiler trace. Measures, at a
-HIGGS-scale window:
+number here comes from the device-side profiler trace (the kernel's own
+events on the chip's op line). Two tables per geometry (plane count P x
+processing tile S, `PART_GEOM`, default the cells' `16x8192,8x16384`):
 
-1. the production v1/v2 partition kernels (ns/lane),
-2. ablated kernel variants that isolate the cost components:
-   - copy-only (DMA floor: stream the window through VMEM untouched)
-   - +routing (the split-column decode + go_left compute)
-   - +compaction network (the log2(S) roll+select rounds)
-   - +carry rolls (the three full-width dynamic rolls per step)
+1. the PRODUCTION kernels with `plane._compact_streams`, the one
+   compaction primitive, swapped for a variant (`PLANS`):
+   - separate: one plan per stream — a prefix sum, a shift row with the
+     `- b` update and a network each; what shipped before PR 28
+   - ceiling:  `separate` without the second prefix sum and without
+     both shift updates. WRONG output, timing only: what removing that
+     bookkeeping outright would buy
+   - stacked:  the shipped helper — one prefix sum and one shift
+     bookkeeping for all streams, stacked in sublanes, no subtract
+2. stripped kernels that add one cost component at a time around the
+   SHIPPED helper (`COMPONENTS`): copy floor, routing, one network
+   (K=1), the stacked pair (K=2), the carry's three dynamic rolls, and
+   the production structure's scalar-prefetched index map and
+   double-buffered manual DMA.
 
-Run:  python scripts/part_micro.py
+Run:  python scripts/part_micro.py   (writes chiprun_out/part_micro.json)
 """
-import functools
 import glob
-import gzip
 import json
 import os
+import shutil
 import sys
-from collections import defaultdict
+import tempfile
 
 import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 ROWS = int(os.environ.get("PART_ROWS", 4 << 20))
-P = 16
-S = int(os.environ.get("PART_TILE", 4096))
+GEOMETRIES = [tuple(int(v) for v in g.split("x")) for g in
+              os.environ.get("PART_GEOM", "16x8192,8x16384").split(",")]
+REPEATS = 3
 
 
-def device_ms(fn, x):
-    """Total device-lane ms for one call of fn, from the profiler.
-    The traced call uses a different argument value than the warm-up."""
+def device_ms(fn, x, match=""):
+    """Device ms of the fastest of REPEATS traced calls of fn(x): per
+    call, the summed durations of the op-line events whose instruction
+    name contains `match` (all top-level ops when empty)."""
     import jax
-    jax.block_until_ready(fn(x))  # warm/compile + drain before tracing
-    tdir = "/tmp/part_micro_trace"
-    os.system(f"rm -rf {tdir}")
-    with jax.profiler.trace(tdir):
-        out = fn(x + 1)
-        jax.block_until_ready(out)
-    files = glob.glob(f"{tdir}/**/*.trace.json.gz", recursive=True)
-    with gzip.open(files[0], "rt") as fh:
-        trace = json.load(fh)
-    events = trace.get("traceEvents", [])
-    pid_names = {}
-    for e in events:
-        if e.get("ph") == "M" and e.get("name") == "process_name":
-            pid_names[e["pid"]] = e["args"].get("name", "")
-    device_pids = {p for p, n in pid_names.items()
-                   if "TPU" in n or "/device" in n.lower()}
-    agg = defaultdict(float)
-    for e in events:
-        if e.get("ph") == "X" and e.get("pid") in device_pids:
-            agg[e.get("name", "?")] += e.get("dur", 0) / 1e3
-    return agg
+    from jax.profiler import ProfileData
+    jax.block_until_ready(fn(x))        # compile + drain before tracing
+    tdir = tempfile.mkdtemp(prefix="part_micro_")
+    try:
+        with jax.profiler.trace(tdir):
+            for _ in range(REPEATS):
+                jax.block_until_ready(fn(x))
+        path = sorted(glob.glob(os.path.join(
+            tdir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+        events = []
+        for pl_ in ProfileData.from_file(path).planes:
+            if not pl_.name.startswith("/device:TPU:0"):
+                continue
+            for line in pl_.lines:
+                if line.name == "XLA Ops":
+                    events += [(e.name.split(" = ", 1)[0], e.duration_ns / 1e6)
+                               for e in line.events]
+    finally:
+        shutil.rmtree(tdir, ignore_errors=True)
+    durs = [d for n, d in events if match in n]
+    if not durs:
+        raise SystemExit(f"no device op named *{match}* among "
+                         f"{sorted({n for n, _ in events})}")
+    per_call, rest = divmod(len(durs), REPEATS)
+    if rest:        # an event went missing: the mean over what is there
+        return sum(durs) / REPEATS
+    return min(sum(durs[i * per_call:(i + 1) * per_call])
+               for i in range(REPEATS))
 
 
-def kernel_variant(mode: str):
-    """A stripped partition-like kernel: reads [P, S] blocks, applies
-    the chosen cost component, writes back. Grid = one pass over the
-    window."""
-    import jax
+# ---------------------------------------------------------------------------
+# compaction plans for the production kernels
+# ---------------------------------------------------------------------------
+
+def _plan_separate(x, keeps, roll=None):
+    """One plan per stream, as shipped before PR 28."""
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-
-    nt = ROWS // S
-
-    def body(x_ref, o_ref):
-        x = x_ref[...]
-        if mode == "copy":
-            o_ref[...] = x
-            return
-        # routing: split-column decode + threshold compare
-        col = jnp.sum(jnp.where(
-            jax.lax.broadcasted_iota(jnp.int32, (P, S), 0) == 3, x, 0),
-            axis=0, keepdims=True)
-        keep = ((col >> 8) & 0xFF) <= 120
-        if mode == "routing":
-            o_ref[...] = jnp.where(keep, x, x + 1)
-            return
-        # compaction network: log2(S) roll+select rounds (the v1/v2
-        # inner loop shape, static shifts, data-dependent selects)
-        ranks = keep.astype(jnp.int32)
-        b = 1
-        while b < S:
-            ranks = ranks + jnp.where(
-                jax.lax.broadcasted_iota(jnp.int32, (1, S), 1) >= b,
-                pltpu.roll(ranks, b, 1), 0)
-            b *= 2
-        sh = jnp.where(keep, jax.lax.broadcasted_iota(
-            jnp.int32, (1, S), 1) - (ranks - 1), 0)
+    from lightgbm_tpu.ops import plane
+    S = x.shape[1]
+    comps = []
+    for keep in keeps:
+        ranks = plane._lane_prefix(keep, pltpu.roll)
+        sh = jnp.where(keep == 1, plane._lane_iota(S) - (ranks - 1), 0)
         comp = x
-        shv = sh
         b = 1
         while b < S:
-            moved = pltpu.roll(shv, S - b, 1)
+            moved = pltpu.roll(sh, S - b, 1)
             m1 = (moved & b) != 0
             comp = jnp.where(m1, pltpu.roll(comp, S - b, 1), comp)
-            shv = jnp.where(m1, moved - b, shv)
+            sh = jnp.where(m1, moved - b, sh)
             b *= 2
-        if mode == "network":
-            o_ref[...] = comp
-            return
-        # + the three full-width dynamic rolls of the carry machinery
-        c = jnp.sum(keep.astype(jnp.int32)) % 128
-        comp = pltpu.roll(comp, jax.lax.rem(128 - c, 128), 1)
-        comp = pltpu.roll(comp, c, 1)
-        comp = pltpu.roll(comp, jax.lax.rem(S - c, S), 1)
-        o_ref[...] = comp
-
-    f = pl.pallas_call(
-        body,
-        grid=(nt,),
-        in_specs=[pl.BlockSpec((P, S), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((P, S), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((P, ROWS), jnp.int32),
-    )
-    return jax.jit(f)
+        comps.append(comp)
+    return comps, [jnp.sum(k) for k in keeps]
 
 
-def kernel_structural(mode: str):
-    """Variants that mimic the PRODUCTION kernel's structure one
-    element at a time: dynamic (scalar-prefetched) input index maps,
-    manual-DMA output with double buffering, and the 2-stream v2 shape.
-    """
+def _plan_ceiling(x, keeps, roll=None):
+    """`separate` less the second prefix sum and both shift updates:
+    every stream ranks by the first keep row and tests the bits of its
+    INITIAL shifts. Wrong lanes, right amount of data movement."""
+    import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
+    from lightgbm_tpu.ops import plane
+    S = x.shape[1]
+    ranks = plane._lane_prefix(keeps[0], pltpu.roll)
+    comps = []
+    for keep in keeps:
+        sh = jnp.where(keep == 1, plane._lane_iota(S) - (ranks - 1), 0)
+        comp = x
+        b = 1
+        while b < S:
+            m1 = (pltpu.roll(sh, S - b, 1) & b) != 0
+            comp = jnp.where(m1, pltpu.roll(comp, S - b, 1), comp)
+            b *= 2
+        comps.append(comp)
+    return comps, [jnp.sum(k) for k in keeps]
+
+
+def _plan_none(x, keeps, roll=None):
+    """No compaction at all (part_sides.py's `nonet`)."""
+    import jax.numpy as jnp
+    return [x] * len(keeps), [jnp.sum(k) for k in keeps]
+
+
+PLANS = {"separate": _plan_separate, "ceiling": _plan_ceiling,
+         "stacked": None}
+
+
+def production_ms(kernel, data, layout, count, rscal, S, plan):
+    """Device ms of one production partition of [0, count) in the grow
+    loop's mode (cap=None, per-branch tile S) under compaction `plan`
+    (a PLANS value; None = the shipped helper)."""
+    from lightgbm_tpu.ops import plane
+    shipped = plane._compact_streams
+    fn = getattr(plane, kernel)
+    if plan is not None:
+        plane._compact_streams = plan
+    fn.clear_cache()
+    try:
+        return device_ms(
+            lambda d: fn(d, layout, 0, count, rscal, cap=None, tile=S)[0],
+            data, match="partition")
+    finally:
+        plane._compact_streams = shipped
+        fn.clear_cache()
+
+
+def random_state(P, S, rows, seed=0):
+    """(layout, data, rscal): a [P, R] planar state of random words, a
+    split of byte 1 of code plane 0 at 120 of 256 (~47 % left)."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops import plane
+    full = P > 8                  # label and score planes, as HIGGS has
+    layout = plane.make_layout(4 * (P - (5 if full else 3)), 8, rows,
+                               with_label=full, with_score=full, tile=S)
+    assert layout.num_planes == P and layout.max_tile >= S, layout
+    rng = np.random.RandomState(seed)
+    data = jnp.asarray(rng.randint(0, 1 << 31, size=(P, layout.num_lanes),
+                                   dtype=np.int64).astype(np.int32))
+    return layout, data, plane.route_scalars(layout, 1, 120, 1, 255)
+
+
+# ---------------------------------------------------------------------------
+# stripped kernels: one cost component at a time
+# ---------------------------------------------------------------------------
+
+COMPONENTS = ("copy", "routing", "network", "stacked2", "carry",
+              "dynidx", "dma")
+
+
+def component_kernel(mode, P, S):
+    """A stripped partition-like kernel over a [P, ROWS] window: reads
+    [P, S] blocks, applies the cost components up to `mode`, writes
+    back. `dynidx` / `dma` put one network under the production
+    structure: a scalar-prefetched input index map, then a manual,
+    double-buffered output DMA."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+    from lightgbm_tpu.ops.plane import _compact_streams
 
     nt = ROWS // S
+
+    def compute(x):
+        if mode == "copy":
+            return x
+        col = jnp.sum(jnp.where(
+            jax.lax.broadcasted_iota(jnp.int32, (P, S), 0) == 0, x, 0),
+            axis=0, keepdims=True)
+        keep = (((col >> 8) & 0xFF) <= 120).astype(jnp.int32)
+        if mode == "routing":
+            return jnp.where(keep == 1, x, x + 1)
+        if mode == "stacked2":
+            (cl, cr), _ = _compact_streams(x, [keep, 1 - keep])
+            return cl + cr
+        (comp,), (k,) = _compact_streams(x, [keep])
+        if mode == "carry":
+            # the three full-width dynamic rolls of the carry machinery
+            c = k % 128
+            comp = pltpu.roll(comp, jax.lax.rem(128 - c, 128), 1)
+            comp = pltpu.roll(comp, c, 1)
+            comp = pltpu.roll(comp, jax.lax.rem(S - c, S), 1)
+        return comp
 
     def body(scal, x_ref, o_ref, stg0, stg1, sems):
-        t = pl.program_id(0)
-        x = x_ref[...]
-        col = jnp.sum(jnp.where(
-            jax.lax.broadcasted_iota(jnp.int32, (P, S), 0) == 3, x, 0),
-            axis=0, keepdims=True)
-        keep = ((col >> 8) & 0xFF) <= 120
-        ranks = keep.astype(jnp.int32)
-        b = 1
-        while b < S:
-            ranks = ranks + jnp.where(
-                jax.lax.broadcasted_iota(jnp.int32, (1, S), 1) >= b,
-                pltpu.roll(ranks, b, 1), 0)
-            b *= 2
-        sh = jnp.where(keep, jax.lax.broadcasted_iota(
-            jnp.int32, (1, S), 1) - (ranks - 1), 0)
-        comp = x
-        shv = sh
-        b = 1
-        while b < S:
-            moved = pltpu.roll(shv, S - b, 1)
-            m1 = (moved & b) != 0
-            comp = jnp.where(m1, pltpu.roll(comp, S - b, 1), comp)
-            shv = jnp.where(m1, moved - b, shv)
-            b *= 2
-        if mode == "dynidx":
+        comp = compute(x_ref[...])
+        if mode != "dma":
             o_ref[...] = comp
             return
-        # manual-DMA double-buffered output, production-style
+        t = pl.program_id(0)
         slot = jax.lax.rem(t, 2)
+
+        def out(stg, s, tt):
+            return pltpu.make_async_copy(
+                stg, o_ref.at[:, pl.ds(tt * S, S)], sems.at[s])
 
         @pl.when(slot == 0)
         def _():
             stg0[...] = comp
             @pl.when(t > 0)
             def _():
-                pltpu.make_async_copy(
-                    stg1, o_ref.at[:, pl.ds((t - 1) * S, S)],
-                    sems.at[1]).wait()
-            pltpu.make_async_copy(
-                stg0, o_ref.at[:, pl.ds(t * S, S)], sems.at[0]).start()
+                out(stg1, 1, t - 1).wait()
+            out(stg0, 0, t).start()
 
         @pl.when(slot == 1)
         def _():
             stg1[...] = comp
-            pltpu.make_async_copy(
-                stg0, o_ref.at[:, pl.ds((t - 1) * S, S)], sems.at[0]).wait()
-            pltpu.make_async_copy(
-                stg1, o_ref.at[:, pl.ds(t * S, S)], sems.at[1]).start()
+            out(stg0, 0, t - 1).wait()
+            out(stg1, 1, t).start()
 
         @pl.when((t == nt - 1) & (slot == 0))
         def _():
-            pltpu.make_async_copy(
-                stg0, o_ref.at[:, pl.ds(t * S, S)], sems.at[0]).wait()
+            out(stg0, 0, t).wait()
 
         @pl.when((t == nt - 1) & (slot == 1))
         def _():
-            pltpu.make_async_copy(
-                stg1, o_ref.at[:, pl.ds(t * S, S)], sems.at[1]).wait()
+            out(stg1, 1, t).wait()
 
+    dyn = mode in ("dynidx", "dma")
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nt,),
         in_specs=[pl.BlockSpec(
-            (P, S), lambda t, scal: (0, scal[0] + jnp.minimum(t, scal[1])))],
-        out_specs=(pl.BlockSpec(memory_space=pltpu.ANY)
-                   if mode == "dma" else
-                   pl.BlockSpec((P, S), lambda t, scal: (0, t))),
+            (P, S), (lambda t, scal: (0, scal[0] + jnp.minimum(t, scal[1])))
+            if dyn else (lambda t, scal: (0, t)))],
+        out_specs=(pl.BlockSpec(memory_space=pltpu.HBM) if mode == "dma"
+                   else pl.BlockSpec((P, S), lambda t, scal: (0, t))),
         scratch_shapes=[
             pltpu.VMEM((P, S), jnp.int32),
             pltpu.VMEM((P, S), jnp.int32),
@@ -208,61 +259,39 @@ def kernel_structural(mode: str):
         ],
     )
     f = pl.pallas_call(
-        body,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((P, ROWS), jnp.int32),
-    )
+        body, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((P, ROWS), jnp.int32))
     scal = jnp.asarray([0, nt - 1], jnp.int32)
     return jax.jit(lambda x: f(scal, x))
 
 
 def main():
     import jax
-    import jax.numpy as jnp
-    from lightgbm_tpu.ops import plane
-
-    rng = np.random.RandomState(0)
-    x = jnp.asarray(rng.randint(0, 1 << 30, size=(P, ROWS)), jnp.int32)
-
-    print(f"window: {ROWS} lanes x {P} planes, tile {S}")
-    for mode in ("copy", "routing", "network", "carry"):
-        fn = kernel_variant(mode)
-        agg = device_ms(fn, x)
-        total = sum(v for k, v in agg.items() if "pallas" in k.lower()
-                    or "custom" in k.lower() or "fusion" in k.lower())
-        # fall back to the total if names don't match
-        total = total or sum(agg.values())
-        print(f"  {mode:8s}: {total:8.2f} ms = "
-              f"{total * 1e6 / ROWS:.3f} ns/lane")
-    for mode in ("dynidx", "dma"):
-        fn = kernel_structural(mode)
-        agg = device_ms(fn, x)
-        total = sum(v for k, v in agg.items() if "pallas" in k.lower()
-                    or "custom" in k.lower() or "fusion" in k.lower())
-        total = total or sum(agg.values())
-        print(f"  {mode:8s}: {total:8.2f} ms = "
-              f"{total * 1e6 / ROWS:.3f} ns/lane")
-
-    # the production kernels at the same shape
-    codes = rng.randint(0, 250, size=(ROWS, 8)).astype(np.uint8)
-    layout = plane.make_layout(8, 8, ROWS, with_label=True, with_score=True,
-                               tile=S)
-    cp = plane.build_codes_planes(jnp.asarray(codes), layout)
-    grad = jnp.asarray(rng.randn(ROWS), jnp.float32)
-    data = plane.build_data(layout, cp, grad, grad, label=grad, score=grad)
-    rscal = plane.route_scalars(layout, 3, 120, 1, 249)
-    cap = (ROWS // S - 1) * S
-    for name, meth in (("v1", "pallas"), ("v2", "pallas2")):
-        fn = functools.partial(plane.partition_window, layout=layout,
-                               start=0, count=cap, rscal=rscal, cap=cap,
-                               method=meth)
-        agg = device_ms(lambda d: fn(d)[0], data)
-        total = sum(v for k, v in agg.items()
-                    if "partition" in k.lower() or "custom" in k.lower())
-        total = total or sum(agg.values())
-        print(f"  prod {name}: {total:8.2f} ms = "
-              f"{total * 1e6 / cap:.3f} ns/lane "
-              f"(P={layout.num_planes})")
+    if jax.default_backend() != "tpu":
+        raise SystemExit("part_micro times kernels on the chip; JAX "
+                         f"initialised the {jax.default_backend()} backend")
+    out = {"rows": ROWS, "device": jax.devices()[0].device_kind,
+           "geometries": {}}
+    for P, S in GEOMETRIES:
+        layout, data, rscal = random_state(P, S, ROWS)
+        res = {"production": {}, "components": {}}
+        print(f"window {ROWS} lanes x {P} planes, tile {S}", flush=True)
+        for kernel in ("partition_pallas2", "partition_pallas"):
+            for name, plan in PLANS.items():
+                ms = production_ms(kernel, data, layout, ROWS, rscal, S, plan)
+                res["production"][f"{kernel}.{name}"] = ms * 1e6 / ROWS
+                print(f"  {kernel:18s} {name:9s}: {ms:8.3f} ms = "
+                      f"{ms * 1e6 / ROWS:.4f} ns/lane", flush=True)
+        x = data[:, :ROWS]
+        for mode in COMPONENTS:
+            ms = device_ms(component_kernel(mode, P, S), x)
+            res["components"][mode] = ms * 1e6 / ROWS
+            print(f"  {mode:9s}: {ms:8.3f} ms = {ms * 1e6 / ROWS:.4f} ns/lane",
+                  flush=True)
+        out["geometries"][f"{P}x{S}"] = res
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/part_micro.json", "w") as fh:
+        json.dump(out, fh, indent=1)
 
 
 if __name__ == "__main__":

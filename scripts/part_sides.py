@@ -1,106 +1,58 @@
 """Production-v2 partition ablation IN CONTEXT: times the real kernel
-against modified copies with individual stages stubbed out, at a real
-window on the chip. Pinpoints where the ~2.7 ns/lane goes (the
-component-sum ablations in part_micro.py reach ~0.9).
+against itself with individual stages stubbed out, at a real window on
+the chip (the component sums of part_micro.py add up to less).
 
-Stages stubbed (cumulatively, by monkeypatching the kernel body):
+Stages stubbed, cumulatively:
   full      — production _partition_kernel2
   noalign   — side 1 (realign/writeback) body skipped
-  nonet     — + both compaction networks replaced by pass-through
+  nonet     — + the compaction (`plane._compact_streams`) replaced by
+              pass-through: what is left is routing, the carry rolls,
+              the staging copies and the DMA chains
 
 Run: python scripts/part_sides.py
 """
-import glob
-import gzip
-import json
 import os
 import sys
-from collections import defaultdict
 
-import numpy as np
-
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-ROWS = int(os.environ.get("PART_ROWS", 8 << 20))
+import part_micro
 
-
-def device_total_ms(fn, x, match):
-    import jax
-    jax.block_until_ready(fn(x))
-    tdir = "/tmp/part_sides_trace"
-    os.system(f"rm -rf {tdir}")
-    with jax.profiler.trace(tdir):
-        jax.block_until_ready(fn(x + 1))
-    files = glob.glob(f"{tdir}/**/*.trace.json.gz", recursive=True)
-    with gzip.open(files[0], "rt") as fh:
-        trace = json.load(fh)
-    events = trace.get("traceEvents", [])
-    pid_names = {}
-    for e in events:
-        if e.get("ph") == "M" and e.get("name") == "process_name":
-            pid_names[e["pid"]] = e["args"].get("name", "")
-    device_pids = {p for p, n in pid_names.items()
-                   if "TPU" in n or "/device" in n.lower()}
-    agg = defaultdict(float)
-    for e in events:
-        if e.get("ph") == "X" and e.get("pid") in device_pids:
-            agg[e.get("name", "?")] += e.get("dur", 0) / 1e3
-    tot = sum(v for k, v in agg.items()
-              if match in k and not k.startswith("jit"))
-    return tot or sum(v for k, v in agg.items()
-                      if not k.startswith("jit"))
+P = int(os.environ.get("PART_PLANES", 16))
+S = int(os.environ.get("PART_TILE", 8192))
 
 
 def main():
     import jax
-    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
     from lightgbm_tpu.ops import plane
 
-    rng = np.random.RandomState(0)
-    n, g = ROWS, 8
-    codes = rng.randint(0, 250, size=(n, g)).astype(np.uint8)
-    layout = plane.make_layout(g, 8, n, with_label=True, with_score=True)
-    cp = plane.build_codes_planes(jnp.asarray(codes), layout)
-    grad = jnp.asarray(rng.randn(n), jnp.float32)
-    data = plane.build_data(layout, cp, grad, grad, label=grad, score=grad)
-    rscal = plane.route_scalars(layout, 3, 120, 1, 249)
-    S = layout.tile
-    cap = (min(layout.num_lanes - layout.max_tile, n) // S) * S
-    print(f"window {cap} lanes, P={layout.num_planes}, tile {S}")
+    if jax.default_backend() != "tpu":
+        raise SystemExit("part_sides times kernels on the chip; JAX "
+                         f"initialised the {jax.default_backend()} backend")
+    rows = part_micro.ROWS
+    layout, data, rscal = part_micro.random_state(P, S, rows)
+    print(f"window {rows} lanes, P={P}, tile {S}")
+    whole = plane._partition_kernel2
 
-    import lightgbm_tpu.ops.plane as pl_mod
-    orig_kernel = pl_mod._partition_kernel2
+    def stream_side_only(*refs, **kw):
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            whole(*refs, **kw)
 
-    def run(label):
-        pl_mod.partition_pallas2.clear_cache()
-        fn = lambda d: pl_mod.partition_pallas2(
-            d, layout, 0, cap, rscal, cap=cap)[0]
-        ms = device_total_ms(fn, data, "partition")
-        print(f"  {label:8s}: {ms:8.2f} ms = {ms * 1e6 / cap:.3f} ns/lane",
+    for label, kernel, plan in (
+            ("full", whole, None),
+            ("noalign", stream_side_only, None),
+            ("nonet", stream_side_only, part_micro._plan_none)):
+        plane._partition_kernel2 = kernel
+        try:
+            ms = part_micro.production_ms("partition_pallas2", data, layout,
+                                          rows, rscal, S, plan)
+        finally:
+            plane._partition_kernel2 = whole
+        print(f"  {label:8s}: {ms:8.3f} ms = {ms * 1e6 / rows:.4f} ns/lane",
               flush=True)
-
-    run("full")
-
-    import functools
-
-    def make_stub(skip_align, skip_net):
-        def kern(scal, data_ref, dout_ref, win_ref, nleft_ref, *scratch,
-                 S, P, RB0):
-            from jax.experimental import pallas as pl
-            side = pl.program_id(0)
-            if skip_align:
-                @pl.when(side == 0)
-                def _():
-                    orig_kernel(scal, data_ref, dout_ref, win_ref,
-                                nleft_ref, *scratch, S=S, P=P, RB0=RB0)
-                return
-            orig_kernel(scal, data_ref, dout_ref, win_ref, nleft_ref,
-                        *scratch, S=S, P=P, RB0=RB0)
-        return kern
-
-    pl_mod._partition_kernel2 = make_stub(True, False)
-    run("noalign")
-    pl_mod._partition_kernel2 = orig_kernel
 
 
 if __name__ == "__main__":

@@ -20,21 +20,36 @@ from lightgbm_tpu.ops.histogram import (histogram_planar_pallas,
                                         histogram_scatter)
 
 
+@pytest.fixture
+def drop_executables():
+    """An interpreted kernel is a large XLA:CPU executable, one per
+    (kernel, layout, cap); each holds hundreds of memory mappings for
+    the life of the process, and a test worker that keeps them all runs
+    into the kernel's per-process limit (vm.max_map_count) files later."""
+    yield
+    plane.partition_pallas.clear_cache()
+    plane.partition_pallas2.clear_cache()
+
+
 # ---------------------------------------------------------------------------
 # partition_pallas vs partition_ref
 # ---------------------------------------------------------------------------
 
-def _make_state(n, g, seed, code_bits=8, tile=512, max_code=250):
+def _make_state(n, g, seed, code_bits=8, tile=512, max_code=250,
+                persistent=True):
+    """`persistent`: label and score planes ride along, as in the
+    persistent tier's state; the per-tree tier's has neither."""
     rng = np.random.RandomState(seed)
     codes = rng.randint(0, max_code, size=(n, g)).astype(np.uint8)
     grad = rng.randn(n).astype(np.float32)
     hess = rng.rand(n).astype(np.float32)
-    layout = plane.make_layout(g, code_bits, n, with_label=True,
-                               with_score=True, tile=tile)
+    layout = plane.make_layout(g, code_bits, n, with_label=persistent,
+                               with_score=persistent, tile=tile)
     cp = plane.build_codes_planes(jnp.asarray(codes), layout)
+    extra = dict(label=jnp.asarray(grad),
+                 score=jnp.asarray(hess)) if persistent else {}
     data = plane.build_data(layout, cp, jnp.asarray(grad), jnp.asarray(hess),
-                            label=jnp.asarray(grad),
-                            score=jnp.asarray(hess))
+                            **extra)
     return layout, data, codes
 
 
@@ -44,25 +59,51 @@ def _cap_for(layout, count):
     return min(cap, layout.num_lanes - tile)
 
 
-@pytest.mark.parametrize("kernel", [plane.partition_pallas,
-                                    plane.partition_pallas2])
-@pytest.mark.parametrize("start,count,feat,thr,dl", [
+WINDOWS = [
     (0, 4096, 3, 120, 0),        # full window
     (1234, 2000, 7, 60, 1),      # interior window, default-left
     (4000, 96, 0, 200, 0),       # tail window
     (17, 3, 5, 10, 1),           # tiny leaf
     (100, 3900, 3, 5, 0),        # nearly all right (boundary near off)
     (100, 3900, 3, 245, 0),      # nearly all left (boundary near end)
-])
-def test_partition_pallas_interpret_matches_ref(kernel, start, count, feat,
-                                                thr, dl):
-    layout, data, codes = _make_state(4096, 12, seed=start + count)
+]
+
+# (bundle columns, persistent, P). The cells' states by planes used:
+# HIGGS 28 columns + label + score = 12 of 16; the per-tree tier's
+# 10 bundles, no label or score = 6 of 8. And two with NO spare plane
+# (the count is a multiple of 8 before padding): 8 of 8 and 16 of 16.
+GEOMETRIES = {"full8": (12, True, 8), "higgs16": (28, True, 16),
+              "pertree8": (10, False, 8), "full16": (44, True, 16)}
+
+# every window on the first geometry with a static cap, as before; two
+# windows on every other (geometry, cap mode)
+CASES = [("full8", False, w) for w in WINDOWS] + [
+    (geom, dynamic, w)
+    for geom in GEOMETRIES for dynamic in (False, True)
+    if (geom, dynamic) != ("full8", False)
+    for w in (WINDOWS[1], WINDOWS[4])]
+
+
+@pytest.mark.parametrize("kernel", [plane.partition_pallas,
+                                    plane.partition_pallas2])
+@pytest.mark.parametrize(
+    "geom,dynamic,window", CASES,
+    ids=[f"{g}-{'dynamic' if d else 'static'}-{w[0]}+{w[1]}f{w[2]}t{w[3]}"
+         for g, d, w in CASES])
+def test_partition_pallas_interpret_matches_ref(kernel, geom, dynamic,
+                                                window, drop_executables):
+    start, count, feat, thr, dl = window
+    g, persistent, planes = GEOMETRIES[geom]
+    layout, data, codes = _make_state(4096, g, seed=start + count,
+                                      persistent=persistent)
+    assert layout.num_planes == planes
     rscal = plane.route_scalars(layout, feat, thr, dl, miss_bin=249)
     cap = _cap_for(layout, count)
     ref, nl_ref = plane.partition_ref(data, layout, start, count, rscal,
                                       cap=cap)
+    # cap=None: the grow loop's dynamic grid over the whole lane extent
     got, nl_got = kernel(data, layout, start, count, rscal,
-                         cap=cap, interpret=True)
+                         cap=None if dynamic else cap, interpret=True)
     assert int(nl_ref) == int(nl_got)
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
     # independent semantic check against the raw codes: rows in
@@ -125,6 +166,114 @@ def test_partition_pallas_interpret_stability(kernel):
     # stable: each side's rowids strictly increasing (input was iota)
     assert (np.diff(rowids[:nl]) > 0).all()
     assert (np.diff(rowids[nl:]) > 0).all()
+
+
+# ---------------------------------------------------------------------------
+# _compact_streams (the kernels' one compaction primitive) vs numpy
+# ---------------------------------------------------------------------------
+
+def _compact(x, keeps):
+    """The helper as a plain function: `jnp.roll` for `pltpu.roll`,
+    batched over the leading axis of every keep row."""
+    fn = jax.jit(jax.vmap(
+        lambda *ks: plane._compact_streams(x, [k[None] for k in ks],
+                                           roll=jnp.roll)))
+    comps, counts = fn(*(jnp.asarray(k) for k in keeps))
+    return [np.asarray(c) for c in comps], [np.asarray(c) for c in counts]
+
+
+def _masks(s, n, seed):
+    """n seeded keep masks of every density, then the edge set: all,
+    none, one lane, alternating (both phases), first / last lane only,
+    all but the first / last."""
+    rng = np.random.RandomState(seed)
+    m = (rng.rand(n, s) < rng.rand(n, 1)).astype(np.int32)
+    lane = np.arange(s)
+    edges = [np.ones(s), np.zeros(s), lane == s // 3, lane % 2 == 0,
+             lane % 2 == 1, lane == 0, lane == s - 1, lane != 0,
+             lane != s - 1]
+    return np.concatenate([m, np.asarray(edges, np.int32)])
+
+
+def _assert_stable_partition(x, keep, comp, count):
+    """Every mask's kept lanes of x, in order, lead its compacted copy
+    (the lanes past the count are garbage)."""
+    np.testing.assert_array_equal(count, keep.sum(axis=1))
+    # the kept lanes first, in order: a stable argsort of "dropped"
+    want = x[:, np.argsort(1 - keep, axis=1, kind="stable")]
+    live = (np.arange(x.shape[1]) < count[:, None])[:, None, :]
+    np.testing.assert_array_equal(
+        np.where(live, comp, 0), np.where(live, want.transpose(1, 0, 2), 0))
+
+
+_X128 = np.random.RandomState(7).randint(
+    -2 ** 31, 2 ** 31, size=(16, 128), dtype=np.int64).astype(np.int32)
+
+
+@pytest.mark.parametrize("lanes,streams", [
+    (128, "one"), (128, "complement"), (128, "independent"),
+    (512, "complement")])
+def test_compact_streams_matches_numpy_stable_partition(lanes, streams):
+    """K = 1; K = 2 as the v2 kernel stacks it (a row and its
+    complement); K = 2 with unrelated rows. 512 lanes reach the rounds
+    that shift by whole 128-lane columns."""
+    x = np.tile(_X128, (1, lanes // 128)) + np.arange(lanes, dtype=np.int32)
+    keep = _masks(lanes, 2000, seed=1)
+    keeps = {"one": [keep], "complement": [keep, 1 - keep],
+             "independent": [keep, _masks(lanes, 2000, seed=2)]}[streams]
+    comps, counts = _compact(x, keeps)
+    assert len(comps) == len(counts) == len(keeps)
+    for k, comp, count in zip(keeps, comps, counts):
+        _assert_stable_partition(x, k, comp, count)
+
+
+def test_compact_streams_two_rows_equal_two_calls():
+    """Stacking changes no lane of either stream, garbage included."""
+    keep_l = _masks(128, 2000, seed=3)
+    keep_r = 1 - keep_l
+    (both_l, both_r), (kl, kr) = _compact(_X128, [keep_l, keep_r])
+    (one_l,), (k1,) = _compact(_X128, [keep_l])
+    (one_r,), (k2,) = _compact(_X128, [keep_r])
+    np.testing.assert_array_equal(both_l, one_l)
+    np.testing.assert_array_equal(both_r, one_r)
+    np.testing.assert_array_equal(kl, k1)
+    np.testing.assert_array_equal(kr, k2)
+
+
+def test_compact_streams_exhaustive_on_16_lanes():
+    """The LSB-first network is a stable compaction for EVERY keep mask
+    of a 16-lane tile (all 65,536), on both stacked streams."""
+    x = _X128[:8, :16]
+    keep = (np.arange(1 << 16)[:, None] >> np.arange(16) & 1).astype(np.int32)
+    (comp_l, comp_r), (kl, kr) = _compact(x, [keep, 1 - keep])
+    _assert_stable_partition(x, keep, comp_l, kl)
+    _assert_stable_partition(x, 1 - keep, comp_r, kr)
+
+
+def test_compact_streams_dropped_subtract_is_exact():
+    """Before PR 28 a moved shift had its bit b cleared (`moved - b`).
+    No later round tests a bit at or below b, so the network routes
+    every lane the same with and without it, garbage included."""
+    def with_subtract(keep):
+        s = keep.shape[0]
+        keep = keep[None]
+        lane = jnp.arange(s, dtype=jnp.int32)[None]
+        ranks = jnp.cumsum(keep, axis=1)
+        sh = jnp.where(keep == 1, lane - (ranks - 1), 0)
+        comp = jnp.asarray(_X128)
+        b = 1
+        while b < s:
+            moved = jnp.roll(sh, s - b, 1)
+            m1 = (moved & b) != 0
+            comp = jnp.where(m1, jnp.roll(comp, s - b, 1), comp)
+            sh = jnp.where(m1, moved - b, sh)
+            b *= 2
+        return comp
+
+    keep = _masks(128, 2000, seed=4)
+    want = np.asarray(jax.jit(jax.vmap(with_subtract))(jnp.asarray(keep)))
+    (got,), _ = _compact(_X128, [keep])
+    np.testing.assert_array_equal(got, want)
 
 
 # ---------------------------------------------------------------------------
