@@ -162,7 +162,7 @@ class _ModuleDonations:
             return self._expr_positions(node.args[0], fi, local,
                                         record_site)
         # self-method call returning a donating callable
-        # (`self._iters_scan_jit_build(k)`)
+        # (a `self._build_entry(k)` that returns its registered entry)
         callees = self.pkg.resolve_call(self.rel, fi, node.func,
                                         fallback=False)
         for q in callees:

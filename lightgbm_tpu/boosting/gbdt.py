@@ -180,14 +180,6 @@ class GBDT:
         # path (bagging via per-shard local permutations, multiclass);
         # no more persistent-only restriction
         self._fused_check_every = 50
-        # persistent-path iteration batching: queue up to K iterations
-        # and dispatch them as ONE lax.scan program. Default 1 (the
-        # streamed single-dispatch program); ROADMAP C5 lists the knob
-        # for deletion.
-        self._iter_batch = max(1, int(os.environ.get(
-            "LGBM_TPU_ITER_BATCH", "1")))
-        self._pq_trees: list = []
-        self._pq_masks: list = []
         # dispatch-ahead / fetch-behind pipelining (LGBM_TPU_PIPELINE=0
         # restores the fully synchronous loop — the parity reference):
         # the periodic stop-check readback trails one check period
@@ -203,7 +195,6 @@ class GBDT:
         # finiteness/overflow checks whose verdicts ride the existing
         # trailing fetches
         self._sentinel = None
-        self._sentinel_deferred: list = []  # (iteration, queued tree)
         if config.numeric_sentinels:
             from ..robust.sentinel import NumericSentinel
             self._sentinel = NumericSentinel(
@@ -314,55 +305,19 @@ class GBDT:
 
     def device_score_state(self):
         """The device array that per-iteration work actually updates —
-        for block_until_ready in benchmarks/profilers. Dispatches any
-        queued iterations first so waiting on it covers ALL requested
-        work."""
-        if self._pq_trees:
-            self._flush_persistent_queue()
+        for block_until_ready in benchmarks/profilers."""
         if self._fused_state is not None:
             return self._fused_state
         return self.train_score.score
 
     def get_training_score(self) -> jax.Array:
         if self._score_dirty and self._fused_state is not None:
-            self._flush_persistent_queue()
             # one scatter back to row order, only when a host consumer
             # (metrics, refit, rollback, custom fobj) actually asks
             self.train_score.score = \
                 self._fused.sync_scores(self._fused_state)[None, :]
             self._score_dirty = False
         return self.train_score.score
-
-    def _flush_persistent_queue(self) -> None:
-        """Dispatch queued persistent iterations. The full batch size
-        runs as the compiled K-iteration scan; any other size runs as
-        single-iteration dispatches (no extra compiles for partials)."""
-        q = self._pq_trees
-        if not q:
-            return
-        from ..treelearner.fused import TreeArrayBatch
-        k = len(q)
-        if k == self._iter_batch:
-            self._fused_state, ta_stack = self._fused.train_iters_persistent(
-                self._fused_state, self.shrinkage_rate,
-                jnp.stack(self._pq_masks))
-            batch = TreeArrayBatch(ta_stack)
-            for i, t in enumerate(q):
-                t.batch = batch
-                t.index = i
-        else:
-            for t, mask in zip(q, self._pq_masks):
-                self._fused_state, ta = self._fused.train_iter_persistent(
-                    self._fused_state, self.shrinkage_rate, 0.0, mask=mask)
-                t.tree_arrays = ta
-        self._pq_trees = []
-        self._pq_masks = []
-        if self._sentinel_deferred:
-            # queued iterations now have device arrays: dispatch the
-            # health checks that were deferred to keep the batch intact
-            deferred, self._sentinel_deferred = self._sentinel_deferred, []
-            for it, t in deferred:
-                self._sentinel_check_trees([t], iteration=it)
 
     def _invalidate_fused_state(self) -> None:
         """Call after any direct train_score mutation (rollback, refit,
@@ -544,51 +499,34 @@ class GBDT:
             mine[0][1])
         return True
 
-    def _sentinel_check_trees(self, trees, iteration: Optional[int] = None
-                              ) -> None:
+    def _sentinel_check_trees(self, trees) -> None:
         """Numeric-health checks on this iteration's new trees
         (robust/sentinel.py). Device-resident leaf values get an async
         [nonfinite, overflow] reduction whose tiny verdict rides the
-        NEXT trailing fetch; host trees are judged immediately. Queued
-        persistent iterations are deferred to the queue flush — forcing
-        the resolver here would defeat the dispatch batch. Costs zero
-        extra blocking syncs either way."""
+        NEXT trailing fetch; host trees are judged immediately. Costs
+        zero extra blocking syncs either way."""
         sent = self._sentinel
         if sent is None:
             return
-        if iteration is None:
-            iteration = self.iter
         from ..treelearner.fused import PendingTree
         arrays: list = []
         with obs_span("sentinel health check (dispatch)", phase="sentinel"):
             for t in trees:
                 if isinstance(t, PendingTree) and t._tree is None:
-                    if t._ta is None and t.batch is None \
-                            and t.resolver is not None:
-                        self._sentinel_deferred.append((iteration, t))
-                        continue
-                    stacked = t._ta is None and t.batch is not None \
-                        and t.batch._host is None
-                    src = t.batch.stack if stacked else t.tree_arrays
-                    arrays.append(src["leaf_value"][t.index] if stacked
-                                  else src["leaf_value"])
+                    arrays.append(t.tree_arrays["leaf_value"])
                 else:
                     tree = t._tree if isinstance(t, PendingTree) else t
                     arrays.append(np.asarray(
                         tree.leaf_value[:max(tree.num_leaves, 1)],
                         dtype=np.float32))
             if arrays:
-                sent.dispatch(arrays, iteration)
+                sent.dispatch(arrays, self.iter)
 
     def _train_one_iter_persistent(self, init_scores) -> bool:
         """Persistent fused path: the ENTIRE boosting iteration
         (gradients, tree growth, score update) is one device program
         over the leaf-permuted planar state — no [N]-sized scatter, no
-        repacking, zero synchronous host transfers. Iterations are
-        QUEUED and dispatched K at a time as one lax.scan program
-        (dispatch latency amortization; see _flush_persistent_queue);
-        valid-set evaluation needs per-tree effects, so the presence of
-        valid sets keeps the batch at 1."""
+        repacking, zero synchronous host transfers."""
         from ..treelearner.fused import PendingTree
         if self._fused_state is None:
             # created AFTER _boost_from_average, so the state's score
@@ -596,25 +534,15 @@ class GBDT:
             # (the PendingTree still gets add_bias for the model)
             self._fused_state = self._fused.init_persistent_state(
                 self.get_training_score()[0])
-        batched = self._iter_batch > 1 and not self.valid_score
-        if batched:
-            pending = PendingTree(self._fused,
-                                  resolver=self._flush_persistent_queue)
-            self._pq_trees.append(pending)
-            self._pq_masks.append(self._fused.feature_masks_for_tree())
-            if len(self._pq_trees) >= self._iter_batch:
-                self._flush_persistent_queue()
-        else:
-            self._fused_state, ta = self._fused.train_iter_persistent(
-                self._fused_state, self.shrinkage_rate, 0.0)
-            pending = PendingTree(self._fused, ta)
-            if self.valid_score:
-                vals = (pending.leaf_values_device()
-                        * self.shrinkage_rate)
-                for vs in self.valid_score:
-                    vleaf = self._fused._valid_traverse_jit(
-                        ta, vs.dataset.device_bins())
-                    vs.score = vs.score.at[0].add(vals[vleaf])
+        self._fused_state, ta = self._fused.train_iter_persistent(
+            self._fused_state, self.shrinkage_rate, 0.0)
+        pending = PendingTree(self._fused, ta)
+        if self.valid_score:
+            vals = pending.leaf_values_device() * self.shrinkage_rate
+            for vs in self.valid_score:
+                vleaf = self._fused._valid_traverse_jit(
+                    ta, vs.dataset.device_bins())
+                vs.score = vs.score.at[0].add(vals[vleaf])
         self._score_dirty = True
         pending.apply_shrinkage(self.shrinkage_rate)
         if abs(init_scores[0]) > K_EPSILON:
@@ -698,17 +626,10 @@ class GBDT:
         counts: list = []
         for t in trees:
             if isinstance(t, PendingTree) and t._tree is None:
-                if t._ta is None and t.batch is None \
-                        and t.resolver is not None:
-                    t.resolver()   # dispatch queued iterations first
                 if t._n_leaves_host is not None:
                     counts.append(int(t._n_leaves_host))
                     continue
-                stacked = t._ta is None and t.batch is not None \
-                    and t.batch._host is None
-                src = t.batch.stack if stacked else t.tree_arrays
-                ref = src["n_leaves"][t.index] if stacked \
-                    else src["n_leaves"]
+                ref = t.tree_arrays["n_leaves"]
                 try:
                     ref.copy_to_host_async()
                 except Exception:
@@ -790,19 +711,10 @@ class GBDT:
         for i, t in enumerate(trees):
             if not (isinstance(t, PendingTree) and t._tree is None):
                 continue
-            if t._ta is None and t.batch is None and t.resolver is not None:
-                t.resolver()       # dispatch queued iterations first
-            stacked = t._ta is None and t.batch is not None \
-                and t.batch._host is None
-            src = t.batch.stack if stacked else t.tree_arrays
             if t._n_leaves_host is None:
-                refs[(i, "n_leaves")] = (
-                    src["n_leaves"][t.index] if stacked
-                    else src["n_leaves"])
+                refs[(i, "n_leaves")] = t.tree_arrays["n_leaves"]
             if with_gains:
-                refs[(i, "split_gain")] = (
-                    src["split_gain"][t.index] if stacked
-                    else src["split_gain"])
+                refs[(i, "split_gain")] = t.tree_arrays["split_gain"]
         with obs_span("batched tree stats (device fetch)",
                       phase="stop_check"):
             # tpulint: sync-ok(batched tree stats, ONE transfer per stop check)
@@ -948,7 +860,6 @@ class GBDT:
         if idx < 0 or (idx + 1) * k > len(self.models):
             return False
         self._pred_revision = getattr(self, "_pred_revision", 0) + 1
-        self._flush_persistent_queue()
         self._materialize_models()
         self._drain_stop_check()
         del self.models[idx * k:(idx + 1) * k]
@@ -992,8 +903,6 @@ class GBDT:
         sent = self._sentinel
         if sent is None:
             return
-        if self._sentinel_deferred:
-            self._flush_persistent_queue()
         pending = sent.take_pending()
         if pending:
             # tpulint: sync-ok(sentinel drain: end-of-training/rollback only, one batched fetch)
@@ -1443,7 +1352,6 @@ class GBDT:
         bagging permutation, and the f32 score accumulators (restored
         directly — recomputing scores from the trees would change the
         accumulation order and drift in the last ulp)."""
-        self._flush_persistent_queue()
         self._materialize_models()
         # the pipelined loop must not leak live device refs into the
         # checkpoint; a drained positive verdict is persisted instead
@@ -1494,11 +1402,8 @@ class GBDT:
         self._stop_fetch = None
         self._stop_pending = True if state.get("stop_pending") else None
         # a mid-run restore (watchdog auto-resume, sentinel rollback)
-        # lands on a LIVE booster: queued iterations and deferred
-        # sentinel work belong to the abandoned timeline
-        self._pq_trees = []
-        self._pq_masks = []
-        self._sentinel_deferred = []
+        # lands on a LIVE booster: in-flight sentinel verdicts belong
+        # to the abandoned timeline
         if self._sentinel is not None:
             self._sentinel.drop_pending()
         self.models = list(parse_tree_blocks(model_text))

@@ -9,16 +9,22 @@ atomics kernels src/treelearner/ocl/histogram{16,64,256}.cl).
 TPU re-design: there are no fast global atomics on TPU, so instead of
 scatter-adds we accumulate *privatized* histograms in VMEM, exactly the
 shape of the reference GPU kernel's local-memory strategy but mapped to
-the TPU memory hierarchy:
+the TPU memory hierarchy, as radix one-hot matmuls on the MXU. What a
+dispatcher (``hist_method``) can select, plus the oracle:
 
-- ``histogram_pallas``: a Pallas kernel; the grid walks row blocks, each
-  block loads ``[rows_per_block, F]`` bin codes into VMEM and runs a
-  bin-indexed masked multiply-accumulate on the VPU, accumulating into a
-  ``[2, B, F]`` VMEM-resident output that only flushes to HBM once.
-  HBM traffic is therefore one read of the bin codes + grad/hess.
 - ``histogram_scatter``: jnp scatter-add formulation — the portable
   reference oracle (mirrors the role of GPU_DEBUG_COMPARE in
-  reference gpu_tree_learner.cpp:992-1030) and the CPU-backend path.
+  reference gpu_tree_learner.cpp:992-1030) and the CPU-backend path
+  (method ``None``).
+- ``histogram_radix_pallas``: the Pallas radix kernel over row-major
+  ``[rows, F]`` bin codes — the host-loop learners' TPU kernel
+  (methods ``radix_pallas`` / ``radix_pallas_bf16`` of ``histogram``).
+- ``histogram_planar_pallas``: the same formulation reading the fused
+  growers' ``[P, R]`` planar state directly, feature chunks and row
+  blocks on the grid; a static ``cap`` or, with ``cap=None``, one
+  program for every leaf size.
+- ``ops/multival.py`` holds the row-wise multi-value layout
+  (``multival_pallas``) and its own oracle.
 
 Histograms hold (sum_gradient, sum_hessian) per (feature, bin); bin
 counts are NOT stored — like the reference (bin.h:41-42 GET_GRAD/GET_HESS,
@@ -41,15 +47,15 @@ so whole-dataset integer sums never round. Dispatch is by input dtype:
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-# planar-histogram block length (lanes per grid step); tunable for
-# per-step overhead experiments (see docs/PERF_NOTES.md)
-PLANAR_RB = int(os.environ.get("LGBM_TPU_HIST_RB", 1024))
+# planar-histogram block length (lanes per grid step): the kernel is
+# per-step-overhead bound below it (~1.7 us a step) and 2048 gains
+# nothing (docs/PERF_NOTES.md)
+PLANAR_RB = 1024
 
 
 def histogram_scatter(bins: jax.Array, grad: jax.Array, hess: jax.Array,
@@ -70,71 +76,6 @@ def histogram_scatter(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     vals = jnp.broadcast_to(vals[:, None, :], (c, f, 2))
     return hist.at[feat_idx.reshape(-1), b.reshape(-1)].add(
         vals.reshape(-1, 2), mode="drop")
-
-
-def _hist_pallas_kernel(bins_ref, grad_ref, hess_ref, out_ref, *, num_bins: int):
-    """Pallas TPU kernel body: one row block → accumulate [2, B, F].
-
-    Grid iterations run sequentially per TPU core, so ``out_ref`` can be
-    initialized on the first step and accumulated across steps (the same
-    sub-histogram reduction the reference GPU kernel does with
-    sync_counters_, here for free from the sequential grid).
-    """
-    from jax.experimental import pallas as pl
-
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    bins = bins_ref[...]            # [Rb, F] int32
-    g = grad_ref[...]               # [Rb, 1] f32 (or i32 levels)
-    h = hess_ref[...]               # [Rb, 1] f32 (or i32 levels)
-
-    def body(b, _):
-        mask = (bins == b).astype(g.dtype)              # [Rb, F]
-        gsum = jnp.sum(mask * g, axis=0)                # [F]
-        hsum = jnp.sum(mask * h, axis=0)                # [F]
-        idx = (slice(None), pl.dslice(b, 1), slice(None))
-        out_ref[idx] = out_ref[idx] + jnp.stack([gsum, hsum])[:, None, :]
-        return ()
-
-    jax.lax.fori_loop(0, num_bins, body, ())
-
-
-# tpulint: jit-ok(kernel entry; dispatched through manager-registered learner entries)
-@functools.partial(jax.jit,
-                   static_argnames=("num_bins", "rows_per_block", "interpret"))
-def histogram_pallas(bins: jax.Array, grad: jax.Array, hess: jax.Array,
-                     num_bins: int, rows_per_block: int = 1024,
-                     interpret: bool = False) -> jax.Array:
-    """Pallas TPU histogram. Same contract as histogram_scatter."""
-    from jax.experimental import pallas as pl
-
-    c, f = bins.shape
-    acc = (jnp.int32 if jnp.issubdtype(grad.dtype, jnp.integer)
-           else jnp.float32)
-    nblk = max(1, (c + rows_per_block - 1) // rows_per_block)
-    pad = nblk * rows_per_block - c
-    b32 = bins.astype(jnp.int32)
-    if pad:
-        # padding rows carry bin -1 (matches no bin) and zero grad/hess
-        b32 = jnp.pad(b32, ((0, pad), (0, 0)), constant_values=-1)
-        grad = jnp.pad(grad, (0, pad))
-        hess = jnp.pad(hess, (0, pad))
-
-    out = pl.pallas_call(
-        functools.partial(_hist_pallas_kernel, num_bins=num_bins),
-        grid=(nblk,),
-        in_specs=[
-            pl.BlockSpec((rows_per_block, f), lambda i: (i, 0)),
-            pl.BlockSpec((rows_per_block, 1), lambda i: (i, 0)),  # tpulint: tile-ok(grad is a per-row scalar column; [R,1] pads to one lane tile, cheaper than replicating to 128 lanes)
-            pl.BlockSpec((rows_per_block, 1), lambda i: (i, 0)),  # tpulint: tile-ok(hess per-row scalar column, same [R,1] single padded lane tile as grad)
-        ],
-        out_specs=pl.BlockSpec((2, num_bins, f), lambda i: (0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((2, num_bins, f), acc),
-        interpret=interpret,
-    )(b32, grad.astype(acc)[:, None], hess.astype(acc)[:, None])
-    return jnp.transpose(out, (2, 1, 0))  # → [F, B, 2]
 
 
 # ---------------------------------------------------------------------------
@@ -163,102 +104,11 @@ def _radix_dims(num_bins: int) -> tuple:
     return bh_bits, bl_bits
 
 
-# tpulint: jit-ok(kernel entry; dispatched through manager-registered learner entries)
-@functools.partial(jax.jit, static_argnames=("num_bins", "dtype", "row_chunk"))
-def histogram_radix(bins: jax.Array, grad: jax.Array, hess: jax.Array,
-                    num_bins: int, dtype=jnp.float32,
-                    row_chunk: int = 131072) -> jax.Array:
-    """Radix one-hot MXU histogram. Same contract as histogram_scatter.
-
-    ``dtype`` is the matmul input dtype (one-hots are exact in any
-    dtype; grad/hess are rounded to it). Accumulation is always f32 via
-    preferred_element_type — bf16 inputs mirror the reference GPU
-    learner's single-precision histograms (gpu_use_dp=false default).
-    Rows are processed in ``row_chunk`` chunks via lax.scan so the
-    materialized one-hots stay bounded.
-
-    Integer grad/hess (quantized levels): the per-chunk matmul still
-    runs in ``dtype`` with f32 accumulation — exact, since
-    row_chunk * qmax < 2^24 — and each chunk partial is converted to
-    int32 before entering the scan carry, so the whole-dataset sums are
-    exact int32.
-    """
-    r, f = bins.shape
-    int_out = jnp.issubdtype(grad.dtype, jnp.integer)
-    bh_bits, bl_bits = _radix_dims(num_bins)
-    Bh, Bl = 1 << bh_bits, 1 << bl_bits
-    Fc = max(1, 128 // Bl)          # N tile = Fc*Bl ≈ 128
-    C = -(-f // Fc)                 # feature chunks
-    Fp = C * Fc
-
-    b = bins.astype(jnp.int32)
-    if Fp > f:
-        # padding features carry bin -1: hi = -1 matches no one-hot slot,
-        # so the diagonal blocks read zero for them
-        b = jnp.pad(b, ((0, 0), (0, Fp - f)), constant_values=-1)
-
-    def chunk_hist(b_ck, g_ck, h_ck):
-        rows = b_ck.shape[0]
-        hi = b_ck >> bl_bits                       # [r, Fp]
-        lo = b_ck & (Bl - 1)
-        iota_h = jnp.arange(Bh, dtype=jnp.int32)
-        iota_l = jnp.arange(Bl, dtype=jnp.int32)
-        mhi = (hi[:, :, None] == iota_h).astype(dtype)    # [r, Fp, Bh]
-        mlo = (lo[:, :, None] == iota_l)
-        # bin -1 must not fire: lo = (-1 & mask) aliases Bl-1, but mhi is
-        # all-zero there so the diagonal product vanishes — no mask needed
-        mlo = mlo.reshape(rows, C, Fc * Bl).astype(dtype)
-        gw = g_ck.astype(dtype)[:, None, None, None]
-        hw = h_ck.astype(dtype)[:, None, None, None]
-        mhi = mhi.reshape(rows, C, Fc, Bh)
-        ag = (mhi * gw).reshape(rows, C, Fc * Bh)
-        ah = (mhi * hw).reshape(rows, C, Fc * Bh)
-        a = jnp.concatenate([ag, ah], axis=-1)            # [r, C, 2FcBh]
-        # TPU matmul default feeds bf16 into the MXU; for f32 inputs ask
-        # for full f32 precision, for bf16 inputs default is already it
-        prec = ("highest" if dtype == jnp.float32 else "default")
-        part = jnp.einsum("rcm,rcn->cmn", a, mlo, precision=prec,
-                          preferred_element_type=jnp.float32)
-        # quantized levels: the f32 partial holds exact integers
-        # (row_chunk * qmax < 2^24) — snap to int32 for the carry
-        return part.astype(jnp.int32) if int_out else part
-
-    nck = -(-r // row_chunk)
-    if nck <= 1:
-        h_all = chunk_hist(b, grad, hess)
-    else:
-        pad = nck * row_chunk - r
-        bp = jnp.pad(b, ((0, pad), (0, 0)), constant_values=-1)
-        gp = jnp.pad(grad, (0, pad))
-        hp = jnp.pad(hess, (0, pad))
-
-        def step(acc, ck):
-            bc, gc, hc = ck
-            return acc + chunk_hist(bc, gc, hc), None
-
-        init = jnp.zeros((C, 2 * Fc * Bh, Fc * Bl),
-                         jnp.int32 if int_out else jnp.float32)
-        h_all, _ = jax.lax.scan(
-            step, init,
-            (bp.reshape(nck, row_chunk, Fp),
-             gp.reshape(nck, row_chunk),
-             hp.reshape(nck, row_chunk)))
-
-    # extract diagonal f1 == f2 blocks → [C, 2, Fc, Bh, Fc, Bl]
-    h_all = h_all.reshape(C, 2, Fc, Bh, Fc, Bl)
-    idx = jnp.arange(Fc)
-    hd = h_all[:, :, idx, :, idx, :]        # [Fc, C, 2, Bh, Bl]
-    hd = jnp.transpose(hd, (1, 0, 3, 4, 2))  # [C, Fc, Bh, Bl, 2]
-    hd = hd.reshape(Fp, Bh * Bl, 2)[:f, :num_bins, :]
-    return hd
-
-
 # ---------------------------------------------------------------------------
 # Pallas radix histogram — the MXU formulation with VMEM-resident
-# one-hots. The XLA version of histogram_radix materializes the one-hot
-# tensors to HBM (~2 KB/row of traffic for 28 uint8 codes, measured as
-# THE dominant cost of the fused tree step at HIGGS shape); here each
-# row block's one-hots live only in VMEM and the [CS, CC, 2FcBh, FcBl]
+# one-hots. An XLA einsum of the same formulation materializes the
+# one-hot tensors to HBM (~2 KB/row of traffic for 28 uint8 codes); here
+# each row block's one-hots live only in VMEM and the [CS, CC, 2FcBh, FcBl]
 # accumulator is flushed once per super-chunk. This is the direct
 # analogue of the reference GPU kernel's local-memory accumulation
 # (src/treelearner/ocl/histogram256.cl:317), mapped to MXU matmuls
@@ -300,10 +150,9 @@ def _chunk_partials(lo_c, hi_c, g_t, h_t, *, Fc, Bh, Bl, dtype,
     [Fc*Bh, Fc*Bl], from the chunk's low/high code rows [Fc, Rb] (already
     in ``dtype``) and the masked grad/hess lane rows [1, Rb].
 
-    Shared verbatim by the unrolled body (`_accum_chunks`) and the
-    grid-parameterized body (`_radix_planar_kernel_grid`) so the two
-    paths stay bit-identical: same operands, same matmul shapes, same
-    f32 accumulators."""
+    Shared by the row-major kernel (`_accum_chunks`) and the planar
+    grid body (`_radix_planar_kernel_grid`): same operands, same matmul
+    shapes, same f32 accumulators."""
     prec = (jax.lax.Precision.HIGHEST if dtype == jnp.float32
             else jax.lax.Precision.DEFAULT)
     ex_lo, slot_lo, ex_hi, slot_hi = _chunk_onehot_consts(Fc, Bh, Bl, dtype)
@@ -417,7 +266,7 @@ def histogram_radix_pallas(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         interpret=interpret,
     )(b.T, gh_t)
 
-    # extract diagonal f1 == f2 blocks (same layout as histogram_radix)
+    # extract diagonal f1 == f2 blocks → [C, 2, Fc, Bh, Fc, Bl]
     h_all = out.reshape(CS * CC, 2, Fc, Bh, Fc, Bl)
     idx = jnp.arange(Fc)
     hd = h_all[:, :, idx, :, idx, :]          # [Fc, C, 2, Bh, Bl]
@@ -443,8 +292,8 @@ def planar_grid_dims(num_bins: int, code_bits: int, num_cols: int):
 
     Returns (Fc, SP, CC, CS): Fc features per matmul chunk, SP planes
     per super-chunk (the sublane extent of one grid step's code block, a
-    multiple of 8), CC chunks per super-chunk (the CONSTANT body unroll),
-    CS super-chunks (grid dimension 0). The planar path is viable iff
+    multiple of 8), CC chunks per super-chunk, CS super-chunks (the grid's
+    dimension 0 is the CS * CC flat chunks). The planar path is viable iff
     CS * SP <= layout.num_planes (callers guard on this)."""
     _, bl_bits = _radix_dims(num_bins)
     Bl = 1 << bl_bits
@@ -461,12 +310,31 @@ def planar_grid_dims(num_bins: int, code_bits: int, num_cols: int):
     return Fc, SP, CC, CS
 
 
-def _radix_planar_kernel(scal, codes_ref, gh_ref, out_ref, *, CC, Fc, Bh,
-                         Bl, bl_bits, dtype, code_bits, gh_off, Rb, SP,
-                         quant=False):
+def _radix_planar_kernel_grid(scal, codes_ref, gh_ref, out_ref, *, CC, Fc,
+                              Bh, Bl, bl_bits, dtype, code_bits, gh_off,
+                              Rb, SP, quant=False):
+    """Grid-parameterized planar body: ONE feature chunk per grid step.
+
+    Grid is (C, nblk) with C = CS*CC flat chunks — the chunk loop rides
+    the grid, not the body, so the lowered program holds exactly one
+    chunk's matmuls no matter how wide the dataset is (the round-4
+    70-minute Mosaic lowering cliff is structurally impossible: program
+    size is constant in the column count, which only appears in the
+    grid bounds).
+
+    The codes block is the chunk's parent SP-plane block (index c//CC),
+    so within a super-chunk the same block is fetched once per chunk per
+    row block — CC× the DMA of a body that unrolls the super-chunk, but
+    the kernel is one-hot-VPU-bound (~16 us compute vs ~80 ns DMA per
+    step at Rb=1024) and the pipeline overlaps the refetch. The chunk's
+    Fc code rows are selected from the unpacked [CC*Fc, Rb] block by a
+    masked sum over the CC static sub-slices (int32-exact; Mosaic has
+    no dynamic sublane slice), keyed on the traced chunk id."""
     from jax.experimental import pallas as pl
 
     i = pl.program_id(1)
+    # which of the super-chunk's CC chunks this step owns
+    cc = jax.lax.rem(pl.program_id(0), CC)
 
     @pl.when(i == 0)
     def _init():
@@ -505,66 +373,6 @@ def _radix_planar_kernel(scal, codes_ref, gh_ref, out_ref, *, CC, Fc, Bh,
         sh = (jax.lax.broadcasted_iota(jnp.int32, (Fsp, 1), 0) % k) \
             * code_bits
         ct = jax.lax.shift_right_logical(e, sh) & mask     # [Fsp, Rb]
-        _accum_chunks(ct, g_t, h_t, out_ref, CC=CC, Fc=Fc, Bh=Bh, Bl=Bl,
-                      bl_bits=bl_bits, dtype=dtype, int_out=quant)
-
-
-def _radix_planar_kernel_grid(scal, codes_ref, gh_ref, out_ref, *, CC, Fc,
-                              Bh, Bl, bl_bits, dtype, code_bits, gh_off,
-                              Rb, SP, quant=False):
-    """Grid-parameterized planar body: ONE feature chunk per grid step.
-
-    Grid is (C, nblk) with C = CS*CC flat chunks — the chunk loop that
-    `_radix_planar_kernel` unrolls CC× into its body rides the grid
-    instead, so the lowered program holds exactly one chunk's matmuls no
-    matter how wide the dataset is (the round-4 70-minute Mosaic
-    lowering cliff is structurally impossible: program size is constant
-    in the column count, which only appears in the grid bounds).
-
-    The codes block is the chunk's parent SP-plane block (index c//CC),
-    so within a super-chunk the same block is fetched once per chunk per
-    row block — CC× the DMA of the unrolled body, but the kernel is
-    one-hot-VPU-bound (~16 us compute vs ~80 ns DMA per step at
-    Rb=1024) and the pipeline overlaps the refetch. The chunk's Fc code
-    rows are selected from the unpacked [CC*Fc, Rb] block by a masked
-    sum over the CC static sub-slices (int32-exact; Mosaic has no
-    dynamic sublane slice), keyed on the traced chunk id — so the
-    accumulated values, and their per-element accumulation order across
-    row blocks, match the unrolled body bit for bit."""
-    from jax.experimental import pallas as pl
-
-    i = pl.program_id(1)
-    # which of the super-chunk's CC chunks this step owns
-    cc = jax.lax.rem(pl.program_id(0), CC)
-
-    @pl.when(i == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    @pl.when(i <= scal[3])
-    def _active():
-        x = codes_ref[...]                         # [SP, Rb] i32
-        off, count = scal[1], scal[2]
-        pos = jax.lax.broadcasted_iota(jnp.int32, (1, Rb), 1) + i * Rb
-        valid = ((pos >= off) & (pos < off + count)).astype(jnp.float32)
-
-        if quant:
-            w = gh_ref[gh_off:gh_off + 1, :]       # [1, Rb] i32
-            g_t = ((w >> 16).astype(jnp.float32) * valid).astype(dtype)
-            h_t = ((w & 0xFFFF).astype(jnp.float32) * valid).astype(dtype)
-        else:
-            gh = jax.lax.bitcast_convert_type(
-                gh_ref[gh_off:gh_off + 2, :], jnp.float32)
-            g_t = (gh[0:1, :] * valid).astype(dtype)
-            h_t = (gh[1:2, :] * valid).astype(dtype)
-
-        k = 32 // code_bits
-        mask = (1 << code_bits) - 1
-        Fsp = SP * k                               # = CC * Fc
-        e = jnp.broadcast_to(x[:, None, :], (SP, k, Rb)).reshape(Fsp, Rb)
-        sh = (jax.lax.broadcasted_iota(jnp.int32, (Fsp, 1), 0) % k) \
-            * code_bits
-        ct = jax.lax.shift_right_logical(e, sh) & mask     # [Fsp, Rb]
         if CC == 1:
             ck = ct
         else:
@@ -585,14 +393,13 @@ def _radix_planar_kernel_grid(scal, codes_ref, gh_ref, out_ref, *, CC, Fc,
                                              "code_bits", "grad_plane",
                                              "cap", "dtype",
                                              "rows_per_block", "interpret",
-                                             "quant", "unroll"))
+                                             "quant"))
 def histogram_planar_pallas(data: jax.Array, start, count, *, num_bins: int,
                             num_cols: int, code_bits: int, grad_plane: int,
                             cap: Optional[int] = None, dtype=jnp.float32,
                             rows_per_block: Optional[int] = None,
                             interpret: bool = False,
-                            quant: bool = False,
-                            unroll: bool = False) -> jax.Array:
+                            quant: bool = False) -> jax.Array:
     """Leaf-window histogram straight off the planar state.
 
     data: [P, R] int32 planar training rows; the window is the lane
@@ -606,11 +413,8 @@ def histogram_planar_pallas(data: jax.Array, start, count, *, num_bins: int,
     `cap//Rb + 1` block sweep (every block past the window skipped via
     the prefetched scalars) for callers that need a shape-stable grid.
 
-    ``unroll=True`` selects the legacy body that unrolls all CC chunks
-    of a super-chunk per grid step (grid=(CS, nblk)); the default body
-    puts feature chunks on the grid too (grid=(CS*CC, nblk)), so program
-    size is constant in the column count. Both bodies are bit-identical
-    per output element.
+    Feature chunks ride the grid too (grid=(CS*CC, nblk)), so program
+    size is constant in the column count.
 
     Returns [num_cols, num_bins, 2] f32 — or int32 when ``quant``, in
     which case the grad plane holds packed ``(qg << 16) | qh`` level
@@ -655,12 +459,10 @@ def histogram_planar_pallas(data: jax.Array, start, count, *, num_bins: int,
     in_specs = [
         pl.BlockSpec(
             (SP, Rb),
-            (lambda s, i, scal: (s, scal[0] + jnp.minimum(i, scal[3])))
-            if unroll else
-            (lambda c, i, scal: (c // CC,
-                                 scal[0] + jnp.minimum(i, scal[3])))),
-        # the same gh block is re-fetched once per super-chunk (or per
-        # chunk in grid mode) per row block. Deliberate: the kernel is
+            lambda c, i, scal: (c // CC,
+                                scal[0] + jnp.minimum(i, scal[3]))),
+        # the same gh block is re-fetched once per chunk per row
+        # block. Deliberate: the kernel is
         # one-hot-VPU-bound (~16 us compute vs ~80 ns DMA per step at
         # Rb=1024), and the alternative — a pre-sliced [2, R] gh
         # operand — costs an XLA copy of two full planes per call
@@ -669,28 +471,16 @@ def histogram_planar_pallas(data: jax.Array, start, count, *, num_bins: int,
             lambda s, i, scal: (gh_blk,
                                 scal[0] + jnp.minimum(i, scal[3]))),
     ]
-    if unroll:
-        grid = (CS, nblk)
-        out_specs = pl.BlockSpec((1, CC, 2 * Fc * Bh, Fc * Bl),
-                                 lambda s, i, scal: (s, 0, 0, 0))
-        out_shape = jax.ShapeDtypeStruct((CS, CC, 2 * Fc * Bh, Fc * Bl),
-                                         jnp.int32 if quant
-                                         else jnp.float32)
-        body = functools.partial(
-            _radix_planar_kernel, CC=CC, Fc=Fc, Bh=Bh, Bl=Bl,
-            bl_bits=bl_bits, dtype=dtype, code_bits=code_bits,
-            gh_off=gh_off, Rb=Rb, SP=SP, quant=quant)
-    else:
-        grid = (CS * CC, nblk)
-        out_specs = pl.BlockSpec((1, 2 * Fc * Bh, Fc * Bl),
-                                 lambda c, i, scal: (c, 0, 0))
-        out_shape = jax.ShapeDtypeStruct((CS * CC, 2 * Fc * Bh, Fc * Bl),
-                                         jnp.int32 if quant
-                                         else jnp.float32)
-        body = functools.partial(
-            _radix_planar_kernel_grid, CC=CC, Fc=Fc, Bh=Bh, Bl=Bl,
-            bl_bits=bl_bits, dtype=dtype, code_bits=code_bits,
-            gh_off=gh_off, Rb=Rb, SP=SP, quant=quant)
+    grid = (CS * CC, nblk)
+    out_specs = pl.BlockSpec((1, 2 * Fc * Bh, Fc * Bl),
+                             lambda c, i, scal: (c, 0, 0))
+    out_shape = jax.ShapeDtypeStruct((CS * CC, 2 * Fc * Bh, Fc * Bl),
+                                     jnp.int32 if quant
+                                     else jnp.float32)
+    body = functools.partial(
+        _radix_planar_kernel_grid, CC=CC, Fc=Fc, Bh=Bh, Bl=Bl,
+        bl_bits=bl_bits, dtype=dtype, code_bits=code_bits,
+        gh_off=gh_off, Rb=Rb, SP=SP, quant=quant)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -788,8 +578,9 @@ def hist_method(config, dataset=None) -> Optional[str]:
 
 def histogram(bins: jax.Array, grad: jax.Array, hess: jax.Array,
               num_bins: int, method: Optional[str] = None) -> jax.Array:
-    """Histogram [F, B, 2] by ``method`` (see hist_method); None is the
-    scatter oracle."""
+    """Histogram [F, B, 2] of row-major bin codes by ``method``: what
+    hist_method returns for a column-major caller. None is the scatter
+    oracle; anything else raises."""
     if method == "multival_pallas":
         # the multival kernels take packed row-wise codes, not [n, F]
         # bin matrices — learners route them through ops/multival.py
@@ -802,13 +593,12 @@ def histogram(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     if method == "radix_pallas_bf16":
         return histogram_radix_pallas(bins, grad, hess, num_bins,
                                       dtype=jnp.bfloat16)
-    if method == "radix":
-        return histogram_radix(bins, grad, hess, num_bins)
-    if method == "radix_bf16":
-        return histogram_radix(bins, grad, hess, num_bins, dtype=jnp.bfloat16)
-    if method == "pallas":
-        return histogram_pallas(bins, grad, hess, num_bins)
-    return histogram_scatter(bins, grad, hess, num_bins)
+    if method is None:
+        return histogram_scatter(bins, grad, hess, num_bins)
+    raise ValueError(
+        f"unknown histogram method {method!r}: hist_method() returns "
+        "None, 'radix_pallas' or 'radix_pallas_bf16' for a column-major "
+        "caller")
 
 
 # ---------------------------------------------------------------------------

@@ -51,17 +51,19 @@ import jax
 import jax.numpy as jnp
 
 LANE = 128          # TPU lane count; DMA offsets/sizes must align to it
-import os as _os
-DEF_TILE = int(_os.environ.get("LGBM_TPU_TILE", 4096))
+# base lane tile of a planar layout: the partition kernel is
+# per-step-overhead bound below it, and 8192 gains nothing (bigger
+# small-leaf windows offset the fewer steps; docs/PERF_NOTES.md)
+DEF_TILE = 4096
 # ceiling for the per-ladder-branch processing tile (see fused.py
 # _branch_tile): the partition/histogram kernels are per-STEP-overhead
 # bound (~4 us/step measured, scripts/part_micro.py), so large leaf
 # windows process in tiles up to this size
-MAX_TILE = int(_os.environ.get("LGBM_TPU_MAX_TILE", 32768))
+MAX_TILE = 32768
 # scoped-VMEM budget for the partition kernels' staging buffers (the
 # hardware limit is 16 MB; leave headroom for the pipeline's own
 # double-buffered block)
-PART_VMEM_BUDGET = int(_os.environ.get("LGBM_TPU_PART_VMEM", 13_000_000))
+PART_VMEM_BUDGET = 13_000_000
 
 
 def partition_vmem_bytes_at(P: int, S: int, method: str = "pallas2") -> int:

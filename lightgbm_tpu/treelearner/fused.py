@@ -44,7 +44,6 @@ from __future__ import annotations
 
 import contextlib
 import math
-import os
 from typing import Dict, NamedTuple, Optional
 
 import numpy as np
@@ -63,6 +62,10 @@ from ..ops import split as S
 from ..utils import log
 
 NEG_INF = jnp.float32(-jnp.inf)
+# growth factor of the REF path's capacity ladder: a factor-8 ladder
+# compiled a third faster but ran 12 % slower in skipped-step overhead
+# (docs/PERF_NOTES.md)
+LADDER_FACTOR = 4
 
 
 def bag_active(config: Config) -> bool:
@@ -259,13 +262,13 @@ class FusedSerialGrower:
         self.group_max_bin = dataset.group_max_bins
         # backend dispatch: ops/histogram.hist_method is the ONE shared
         # precision/layout choice for every learner; partition follows
-        # suit (LGBM_TPU_PART selects the carry-stream kernel
-        # generation). The dataset argument lets the occupancy-driven
-        # dispatcher pick the row-wise multival layout for wide-sparse
-        # shapes (ops/multival.py).
+        # suit (the two-stream kernel, lowered to the single-scratch
+        # one by the footprint test below). The dataset argument lets
+        # the occupancy-driven dispatcher pick the row-wise multival
+        # layout for wide-sparse shapes (ops/multival.py).
         self._hist_method = H.hist_method(config, dataset)
-        self._part_method = (os.environ.get("LGBM_TPU_PART", "pallas2")
-                             if self._hist_method is not None else "ref")
+        self._part_method = ("pallas2" if self._hist_method is not None
+                             else "ref")
         # Mosaic lowers on a TPU only. The dispatch above never selects
         # a kernel elsewhere; tests that force it (H._use_tpu patched)
         # run the same kernels through the Pallas interpreter.
@@ -446,18 +449,16 @@ class FusedSerialGrower:
         # width). The pallas paths no longer ladder: their block sweeps
         # ride a dynamic grid dimension (ops/plane.py / ops/histogram.py
         # cap=None), so ONE lowered kernel serves every leaf size, the
-        # while-body HLO holds one copy of each kernel instead of
-        # LGBM_TPU_LADDER x len(caps), and no step is ever launched past
+        # while-body HLO holds one copy of each kernel instead of one
+        # per ladder rung, and no step is ever launched past
         # the leaf window (the dynamic sweep subsumes the old
         # skipped-step cost model). Tile / row-block lengths are fixed
         # at the top-capacity choice — per-step overhead (~4 us) still
         # amortizes, small leaves just read one partially-valid block.
-        factor = int(np.clip(
-            int(os.environ.get("LGBM_TPU_LADDER", 4)), 2, 64))
         tile = self.layout.tile
         top = self.layout.num_lanes - self.layout.max_tile
         from ..ops.partition import capacity_ladder
-        self._caps = capacity_ladder(top, tile * 4, factor)
+        self._caps = capacity_ladder(top, tile * 4, LADDER_FACTOR)
         self._dyn_tile = self._branch_tile(top)
         self._dyn_hist_rb = self._branch_hist_rb(top)
         # jit entry points go through the AOT compile manager
@@ -1765,70 +1766,24 @@ class FusedSerialGrower:
             data = plane.set_f32(st.data, Ly.score, score2)
         return data, ta
 
-    def _next_quant_keys(self, k: int):
-        """[k, 2] u32 per-iteration stochastic-rounding keys from the
-        host-side iteration counter (deterministic across runs; each
+    def _next_quant_key(self):
+        """[2] u32 stochastic-rounding key of the next iteration, from
+        the host-side iteration counter (deterministic across runs; each
         boosting iteration gets a fresh fold_in of the base key)."""
-        Q.note_requantize(self.config.num_grad_quant_bins, k)
-        start = self._quant_iter
-        self._quant_iter += k
-        return jax.vmap(
-            lambda i: jax.random.fold_in(self._quant_base_key, i)
-        )(jnp.arange(start, start + k, dtype=jnp.uint32))
+        Q.note_requantize(self.config.num_grad_quant_bins)
+        i = self._quant_iter
+        self._quant_iter += 1
+        return jax.random.fold_in(self._quant_base_key, jnp.uint32(i))
 
-    def train_iter_persistent(self, data, shrinkage, bias, mask=None):
-        if mask is None:
-            mask = self.feature_masks_for_tree()
-        args = (self._tables(), data, mask, jnp.float32(shrinkage),
-                jnp.float32(bias), jnp.int32(self.actual_rows))
+    def train_iter_persistent(self, data, shrinkage, bias):
+        args = (self._tables(), data, self.feature_masks_for_tree(),
+                jnp.float32(shrinkage), jnp.float32(bias),
+                jnp.int32(self.actual_rows))
         if self._quant:
             # extra key arg ONLY under quant: the default path's call
             # arity (and so its cached executables) stays identical
-            return self._iter_jit(*args, self._next_quant_keys(1)[0])
+            return self._iter_jit(*args, self._next_quant_key())
         return self._iter_jit(*args)
-
-    def _iters_scan_jit_build(self, k: int):
-        """K boosting iterations in ONE dispatch: lax.scan over the
-        persistent iteration body (traced once, so compile cost matches
-        the single-iteration program): one host dispatch per K
-        iterations instead of one per iteration."""
-        quant = self._quant
-
-        def run(tables, data, masks, shrinkage, n_valid, keys=None):
-            with self._bind_tables(tables):
-                def step(d, xs):
-                    mask, key = xs if quant else (xs, None)
-                    d, ta = self._train_iter(d, mask, shrinkage,
-                                             jnp.float32(0.0),
-                                             n_valid=n_valid, key=key)
-                    return d, ta
-                xs = (masks, keys) if quant else masks
-                return jax.lax.scan(step, data, xs, length=k)
-
-        if self._mgr is not None:
-            entry = self._mgr.shared_entry(
-                f"fused/train_iters_k{k}", self._compile_signature(),
-                lambda: jax.jit(run, donate_argnums=1),
-                donate_argnums=(1,))
-        else:
-            entry = jax.jit(run, donate_argnums=1)  # tpulint: jit-ok(manager-disabled fallback branch)
-        return instrument_kernel(entry, "fused",
-                                 name=f"fused/train_iters_k{k}")
-
-    def train_iters_persistent(self, data, shrinkage, masks):
-        """masks: [K, F] stacked per-tree feature masks. Returns
-        (data, ta_stacked) where every array in ta_stacked has a leading
-        [K] axis (iteration k's tree = slice k)."""
-        k = int(masks.shape[0])
-        if getattr(self, "_iters_jit_k", None) is None:
-            self._iters_jit_k = {}
-        if k not in self._iters_jit_k:
-            self._iters_jit_k[k] = self._iters_scan_jit_build(k)
-        args = (self._tables(), data, masks, jnp.float32(shrinkage),
-                jnp.int32(self.actual_rows))
-        if self._quant:
-            return self._iters_jit_k[k](*args, self._next_quant_keys(k))
-        return self._iters_jit_k[k](*args)
 
     def _sync_scores(self, data):
         n = self.layout.num_rows
@@ -2089,20 +2044,6 @@ class FusedSerialGrower:
         return tree
 
 
-class TreeArrayBatch:
-    """Stacked tree arrays of K scan-batched iterations (leading [K]
-    axis on every array): one device→host fetch serves all K trees."""
-
-    def __init__(self, stack: Dict) -> None:
-        self.stack = stack
-        self._host: Optional[Dict] = None
-
-    def host(self) -> Dict:
-        if self._host is None:
-            self._host = jax.device_get(self.stack)
-        return self._host
-
-
 class PendingTree:
     """Lazily-materialized device tree: keeps the raw device arrays until
     a host consumer needs a real Tree, so the training loop never blocks
@@ -2111,40 +2052,18 @@ class PendingTree:
     Tree once and delegates to it, so consumers that read GBDT.models
     directly keep working without an explicit materialize pass.
 
-    Three sourcing modes for the arrays: direct (``tree_arrays`` given),
-    batched (``batch``+``index`` into a TreeArrayBatch), or queued
-    (``resolver`` — a callable that dispatches the owning driver's
-    queued iterations and then assigns ``batch``/``tree_arrays``)."""
+    ``tree_arrays`` is the grow program's output dict: device arrays
+    until GBDT._materialize_models swaps in their host copies."""
 
-    def __init__(self, grower: FusedSerialGrower,
-                 tree_arrays: Optional[Dict] = None, *,
-                 batch: Optional[TreeArrayBatch] = None,
-                 index: int = 0, resolver=None) -> None:
+    def __init__(self, grower: FusedSerialGrower, tree_arrays: Dict) -> None:
         self._tree: Optional[Tree] = None
         self.grower = grower
-        self._ta = tree_arrays
-        self.batch = batch
-        self.index = index
-        self.resolver = resolver
+        self.tree_arrays = tree_arrays
         self.pending_shrinkage = 1.0
         self.pending_bias = 0.0
         # host-cached leaf count (GBDT._batched_tree_stats): immutable
         # once the tree is grown, so one batched fetch serves forever
         self._n_leaves_host: Optional[int] = None
-
-    @property
-    def tree_arrays(self) -> Dict:
-        if self._ta is None:
-            if self.batch is None and self.resolver is not None:
-                self.resolver()           # dispatch queued iterations
-            if self._ta is None:
-                h = self.batch.host()
-                self._ta = {k: v[self.index] for k, v in h.items()}
-        return self._ta
-
-    @tree_arrays.setter
-    def tree_arrays(self, value: Dict) -> None:
-        self._ta = value
 
     def apply_shrinkage(self, rate: float) -> None:
         if self._tree is not None:
@@ -2179,8 +2098,7 @@ class PendingTree:
         # materialize once and delegate. Guard against recursion during
         # unpickling/copy before __init__ has run.
         if name.startswith("__") or name in ("_tree", "grower", "tree_arrays",
-                                             "_ta", "batch", "index",
-                                             "resolver", "pending_shrinkage",
+                                             "pending_shrinkage",
                                              "pending_bias"):
             raise AttributeError(name)
         return getattr(self.materialize(), name)

@@ -683,7 +683,7 @@ class FusedDataParallelGrower(FusedSerialGrower):
                                  * self.num_features * self.max_num_bin
                                  * 2 * 4)
 
-    def _mc_signature(self, extra: Optional[dict] = None):
+    def _mc_signature(self):
         """(sig, shareable) for the top-level shard_map entries. The
         per-shard fused grower skips manager registration (its programs
         mutate post-init), but THESE entries are built after that
@@ -696,8 +696,6 @@ class FusedDataParallelGrower(FusedSerialGrower):
         sig = self._compile_signature()
         sig["ds"] = ds_sig
         sig["mesh"] = (self.num_shards, self.shard_rows, self.global_rows)
-        if extra:
-            sig.update(extra)
         return sig, shareable
 
     # -- sharded state construction ------------------------------------
@@ -773,9 +771,7 @@ class FusedDataParallelGrower(FusedSerialGrower):
     # branch per leaf the way the host-loop learner's _hist_call does.
     # The quantization scales pmax across shards before packing (see
     # FusedSerialGrower._train_iter), so the int32 sums stay coherent.
-    def train_iter_persistent(self, data, shrinkage, bias, mask=None):
-        if mask is None:
-            mask = self.feature_masks_for_tree()
+    def train_iter_persistent(self, data, shrinkage, bias):
         quant = self._quant
         if self._iter_mc_jit is None:
             if quant:
@@ -798,59 +794,13 @@ class FusedDataParallelGrower(FusedSerialGrower):
                 "mc/train_iter", sig,
                 lambda: jax.jit(f, donate_argnums=0),  # tpulint: jit-ok(inside a shared_entry builder; the manager dispatches this jit)
                 donate_argnums=(0,), store=ok, profiled=True)
-        args = (data, self._n_per_shard, mask, jnp.float32(shrinkage),
-                jnp.float32(bias))
+        args = (data, self._n_per_shard, self.feature_masks_for_tree(),
+                jnp.float32(shrinkage), jnp.float32(bias))
         if quant:
-            args = args + (self._next_quant_keys(1)[0],)
+            args = args + (self._next_quant_key(),)
         with collective_span("fused_iter_psum", self._tree_psum_bytes,
                              axis="data"):
             return self._iter_mc_jit(*args)
-
-    def train_iters_persistent(self, data, shrinkage, masks):
-        """K sharded iterations in one dispatch (scan inside shard_map);
-        see FusedSerialGrower.train_iters_persistent."""
-        k = int(masks.shape[0])
-        quant = self._quant
-        if getattr(self, "_iters_mc_jit_k", None) is None:
-            self._iters_mc_jit_k = {}
-        if k not in self._iters_mc_jit_k:
-            if quant:
-                def body(data_l, nvalid_l, masks_, shr, keys):
-                    def step(d, xs):
-                        mask, key = xs
-                        d, ta = self._train_iter(d, mask, shr,
-                                                 jnp.float32(0.0),
-                                                 n_valid=nvalid_l[0],
-                                                 key=key)
-                        return d, ta
-                    return jax.lax.scan(step, data_l, (masks_, keys),
-                                        length=k)
-                in_specs = (P(None, "data"), P("data"), P(), P(), P())
-            else:
-                def body(data_l, nvalid_l, masks_, shr):
-                    def step(d, mask):
-                        d, ta = self._train_iter(d, mask, shr,
-                                                 jnp.float32(0.0),
-                                                 n_valid=nvalid_l[0])
-                        return d, ta
-                    return jax.lax.scan(step, data_l, masks_, length=k)
-                in_specs = (P(None, "data"), P("data"), P(), P())
-            f = functools.partial(
-                shard_map, mesh=self.mesh, check_vma=False,
-                in_specs=in_specs,
-                out_specs=(P(None, "data"), P()))(body)
-            from ..compile import get_manager
-            sig, ok = self._mc_signature({"k": k})
-            self._iters_mc_jit_k[k] = get_manager().shared_entry(
-                f"mc/train_iters_k{k}", sig,
-                lambda: jax.jit(f, donate_argnums=0),  # tpulint: jit-ok(inside a shared_entry builder; the manager dispatches this jit)
-                donate_argnums=(0,), store=ok)
-        args = (data, self._n_per_shard, masks, jnp.float32(shrinkage))
-        if quant:
-            args = args + (self._next_quant_keys(k),)
-        with collective_span("fused_iter_psum",
-                             k * self._tree_psum_bytes, axis="data"):
-            return self._iters_mc_jit_k[k](*args)
 
     def _sync_scores(self, data):
         from ..ops import plane

@@ -34,17 +34,8 @@ def main():
     hess = jnp.asarray(rng.rand(n).astype(np.float32))
     perm = jnp.arange(n, dtype=jnp.int32)
 
-    for name, fn in [
-        ("radix_f32", lambda: H.histogram_radix(bins, grad, hess, B)),
-        ("radix_bf16", lambda: H.histogram_radix(bins, grad, hess, B,
-                                                 dtype=jnp.bfloat16)),
-        ("scatter", lambda: H.histogram_scatter(bins, grad, hess, B)),
-    ]:
-        try:
-            t = timeit(lambda _=None: fn())
-            print(f"{name:14s} rows={n} {t * 1e3:8.2f} ms")
-        except Exception as e:
-            print(f"{name:14s} FAILED: {type(e).__name__}: {e}")
+    t = timeit(lambda: H.histogram_scatter(bins, grad, hess, B))
+    print(f"{'scatter':14s} rows={n} {t * 1e3:8.2f} ms")
 
     # leaf gather + histogram at half/quarter capacity
     for cap in (n, n // 4, n // 16):

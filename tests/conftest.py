@@ -9,6 +9,13 @@ _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (_flags + " --xla_force_host_platform_device_count=8").strip()
 
+# No background preload of the AOT store: a preload thread loads EVERY
+# executable any worker has stored, each worker then holds all of them
+# for life, and their memory mappings take a worker to the kernel's
+# per-process limit (vm.max_map_count; ROADMAP C12). An entry still
+# loads its own executable from the store at its first call.
+os.environ.setdefault("LGBM_TPU_AOT_PRELOAD", "0")
+
 import jax  # noqa: E402
 
 from lightgbm_tpu.compile import ensure_compile_cache  # noqa: E402
@@ -28,3 +35,4 @@ import pytest  # noqa: E402
 @pytest.fixture
 def rng():
     return np.random.RandomState(42)
+

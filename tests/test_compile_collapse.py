@@ -3,10 +3,10 @@
 program-count accounting (docs/COMPILE_CACHE.md, PERF_NOTES Round 10).
 
 The parity tests pin the load-bearing claim of the collapse: the
-grid-parameterized planar bodies are BIT-IDENTICAL to the legacy
-unrolled/static ones — integer bin counts and f32 partial sums in the
-same reduction order — so the single shared program can replace every
-ladder rung without a numerics review.
+dynamic-grid (``cap=None``) planar kernels are BIT-IDENTICAL to the
+static-cap ones — integer bin counts and f32 partial sums in the same
+reduction order — and both agree with the XLA oracles, so the single
+shared program can replace every ladder rung without a numerics review.
 """
 import os
 
@@ -18,7 +18,8 @@ import jax.numpy as jnp
 
 from lightgbm_tpu.compile import CorruptBlobError, ExecutableStore
 from lightgbm_tpu.ops import plane
-from lightgbm_tpu.ops.histogram import histogram_planar_pallas
+from lightgbm_tpu.ops.histogram import (histogram_planar_pallas,
+                                        histogram_scatter)
 from lightgbm_tpu.ops.partition import capacity_ladder
 
 
@@ -38,7 +39,7 @@ def _cap_for(layout, count, unit):
     return min(cap, layout.num_lanes - unit)
 
 
-# -- grid-parameterized histogram vs the legacy unrolled body -----------
+# -- static-cap grid vs dynamic grid vs the scatter oracle ---------------
 
 @pytest.mark.parametrize("code_bits,num_bins,start,count,quant", [
     (4, 16, 200, 1500, False),   # 4-bit packed codes, interior window
@@ -46,42 +47,50 @@ def _cap_for(layout, count, unit):
     (8, 255, 1800, 97, False),   # tail window, max radix
     (4, 16, 200, 1500, True),    # packed (qg<<16|qh) integer levels
 ])
-def test_hist_grid_matches_unrolled_bit_identical(code_bits, num_bins,
-                                                  start, count, quant):
-    """The feature-chunk grid dimension and the dynamic row-block grid
-    must reproduce the unrolled static-cap body EXACTLY (acceptance:
-    fresh-vs-unrolled histograms bit-identical), in both the f32 and
-    the quantized integer accumulation modes."""
+def test_hist_grid_static_dynamic_oracle(code_bits, num_bins, start, count,
+                                         quant):
+    """The dynamic row-block grid (cap=None) must reproduce the
+    static-cap grid EXACTLY, in both the f32 and the quantized integer
+    accumulation modes, and both must be the scatter oracle's histogram
+    of the window (exact for integer levels, f32 rounding otherwise)."""
     n, g = 2048, 7
     layout, data, codes = _make_state(n, g, seed=code_bits + num_bins,
                                       code_bits=code_bits,
                                       max_code=num_bins)
+    sel = slice(start, start + count)
     if quant:
-        # any int words will do for parity: the kernels must agree
-        # bit-for-bit whatever the packed levels are
+        # any int words will do: the kernels must agree bit-for-bit
+        # with the integer scatter whatever the packed levels are
         rng = np.random.RandomState(7)
         words = rng.randint(0, 1 << 24, size=(layout.num_lanes,),
                             dtype=np.int32)
         data = data.at[layout.grad].set(jnp.asarray(words))
+        grad, hess = words[sel] >> 16, words[sel] & 0xFFFF
+    else:
+        grad = np.asarray(plane.get_f32(data, layout.grad))[sel]
+        hess = np.asarray(plane.get_f32(data, layout.hess))[sel]
     kw = dict(num_bins=num_bins, num_cols=g, code_bits=code_bits,
               grad_plane=layout.grad, rows_per_block=256, interpret=True,
               quant=quant)
-    legacy = np.asarray(histogram_planar_pallas(
-        data, start, count, cap=_cap_for(layout, count, 256),
-        unroll=True, **kw))
     grid_static = np.asarray(histogram_planar_pallas(
         data, start, count, cap=_cap_for(layout, count, 256), **kw))
     grid_dyn = np.asarray(histogram_planar_pallas(
         data, jnp.int32(start), jnp.int32(count), cap=None, **kw))
-    np.testing.assert_array_equal(grid_static, legacy)
-    np.testing.assert_array_equal(grid_dyn, legacy)
+    histogram_planar_pallas.clear_cache()
+    np.testing.assert_array_equal(grid_dyn, grid_static)
+    want = np.asarray(histogram_scatter(
+        jnp.asarray(codes[sel]), jnp.asarray(grad), jnp.asarray(hess),
+        num_bins))
+    if quant:
+        np.testing.assert_array_equal(grid_static, want)
+    else:
+        np.testing.assert_allclose(grid_static, want, rtol=1e-5, atol=1e-4)
 
 
 def test_hist_grid_body_constant_size_in_width():
     """The compile-window claim itself: the traced program of the
     planar histogram has the SAME equation count at any column width —
-    width only moves the grid bounds — and the grid-parameterized body
-    is a constant chunk smaller than the CC-fold unrolled one. This is
+    width only moves the grid bounds. This is
     the CPU-side proof that the wide-EFB Mosaic lowering cliff
     (scripts/wide_hbm_repro.py --lower-proof) cannot come back: there
     is nothing width-proportional left to lower."""
@@ -98,10 +107,10 @@ def test_hist_grid_body_constant_size_in_width():
                         n += count_eqns(w.jaxpr)
         return n
 
-    def eqns_at(cols, unroll):
+    def eqns_at(cols):
         from lightgbm_tpu.ops.histogram import planar_grid_dims
-        # 255-bin geometry: CC=4 chunks per super-chunk, the deepest
-        # body unroll the legacy kernel pays
+        # 255-bin geometry: CC=4 chunks per super-chunk, the widest
+        # chunk select the body holds
         Fc, SP, CC, CS = planar_grid_dims(255, 8, cols)
         gp = -(-CS * SP // 8) * 8
         data = jax.ShapeDtypeStruct((gp + 8, 2048), jnp.int32)
@@ -110,19 +119,14 @@ def test_hist_grid_body_constant_size_in_width():
             return histogram_planar_pallas(
                 d, start, cnt, num_bins=255, num_cols=cols, code_bits=8,
                 grad_plane=gp, cap=None, rows_per_block=256,
-                interpret=True, unroll=unroll)
+                interpret=True)
 
         return count_eqns(jax.make_jaxpr(fn)(
             data, jax.ShapeDtypeStruct((), jnp.int32),
             jax.ShapeDtypeStruct((), jnp.int32)).jaxpr)
 
-    counts = [eqns_at(cols, False) for cols in (4, 32, 128)]
+    counts = [eqns_at(cols) for cols in (4, 32, 128)]
     assert counts[0] == counts[1] == counts[2], counts
-    # the grid body replaced the CC-fold chunk unroll: strictly smaller
-    # program, also width-constant (super-chunks already rode the grid)
-    unrolled = [eqns_at(cols, True) for cols in (4, 128)]
-    assert unrolled[0] == unrolled[1], unrolled
-    assert counts[0] < unrolled[0], (counts[0], unrolled[0])
 
 
 # -- dynamic-grid partition vs static cap vs XLA reference --------------

@@ -104,3 +104,42 @@ def test_fused_reject_reasons_are_named():
     cfg = Config.from_params(dict(P, objective="binary"))
     ds = BinnedDataset.from_matrix(X, cfg, label=y)
     assert fused_supported(cfg, ds, create_objective(cfg))
+
+
+def test_pending_tree_has_two_states():
+    """A PendingTree is device arrays until a host consumer asks, then a
+    materialised Tree: shrinkage and bias applied before and after
+    materialisation give the same leaves, any Tree attribute
+    materialises once, and an instance __init__ has not run on (copy,
+    unpickling) raises AttributeError instead of recursing."""
+    import copy
+
+    import jax
+    from lightgbm_tpu.treelearner.fused import PendingTree
+    X, y = make_binary()
+    bst = lgb.Booster(dict(P, objective="binary", num_leaves=7),
+                      lgb.Dataset(X, label=y))
+    bst.update()
+    t = bst._gbdt.models[0]
+    assert isinstance(t, PendingTree) and t._tree is None
+    assert isinstance(t.tree_arrays["leaf_value"], jax.Array)
+    before = np.asarray(t.leaf_values_device())
+    n = int(t.num_leaves)                  # a Tree attribute: materialises
+    assert t._tree is not None and n == int(t.tree_arrays["n_leaves"])
+    np.testing.assert_allclose(t.leaf_value[:n], before[:n], rtol=1e-6)
+    late = PendingTree(t.grower, t.tree_arrays)
+    early = PendingTree(t.grower, t.tree_arrays)
+    early.materialize()
+    for tree in (late, early):
+        tree.apply_shrinkage(0.5)
+        tree.add_bias(0.25)
+    assert late._tree is None              # still pending: folded lazily
+    np.testing.assert_allclose(np.asarray(late.leaf_values_device())[:n],
+                               early.leaf_value[:n], rtol=1e-6)
+    np.testing.assert_allclose(late.materialize().leaf_value[:n],
+                               early.leaf_value[:n], rtol=1e-6)
+    blank = PendingTree.__new__(PendingTree)
+    for name in ("_tree", "tree_arrays", "grower", "pending_shrinkage"):
+        with pytest.raises(AttributeError):
+            getattr(blank, name)
+    assert copy.copy(t)._tree is t._tree

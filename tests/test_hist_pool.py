@@ -170,7 +170,7 @@ def test_pool_rows_are_the_final_leaves_histograms(case, tmp_path):
     assert (g._forced_sched is not None) == (case == "forced_splits")
     assert g.any_categorical == (case == "categorical")
     quant = case == "quantized_i32"
-    key = g._next_quant_keys(1)[0] if quant else None
+    key = g._next_quant_key() if quant else None
     data = g.init_persistent_state(np.zeros(len(X), np.float32))
     Ly, L = g.layout, g.num_leaves
     if case == "data_parallel":

@@ -29,6 +29,7 @@ def drop_executables():
     yield
     plane.partition_pallas.clear_cache()
     plane.partition_pallas2.clear_cache()
+    histogram_radix_pallas.clear_cache()
 
 
 # ---------------------------------------------------------------------------
@@ -280,10 +281,18 @@ def test_compact_streams_dropped_subtract_is_exact():
 # histogram_radix_pallas vs histogram_scatter
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("num_bins", [16, 63, 255])
-def test_histogram_radix_pallas_interpret_matches_scatter(num_bins):
+@pytest.mark.parametrize("r,f,num_bins", [
+    (1500, 11, 16), (1500, 11, 63), (1500, 11, 255),
+    (1000, 5, 256),     # a full 256-bin radix
+    (777, 28, 63),      # the HIGGS width
+    (311, 3, 255),      # fewer rows than one block, fewer columns than a chunk
+    (500, 7, 16),       # the same at a chunk of 32 columns
+    (700, 5, 32),       # one block and a tail that is no power of two
+    (1000, 6, 64),
+])
+def test_histogram_radix_pallas_interpret_matches_scatter(
+        r, f, num_bins, drop_executables):
     rng = np.random.RandomState(num_bins)
-    r, f = 1500, 11
     bins = rng.randint(0, num_bins, size=(r, f)).astype(np.uint8)
     grad = rng.randn(r).astype(np.float32)
     hess = rng.rand(r).astype(np.float32)
@@ -291,7 +300,7 @@ def test_histogram_radix_pallas_interpret_matches_scatter(num_bins):
                                         jnp.asarray(hess), num_bins))
     got = np.asarray(histogram_radix_pallas(
         jnp.asarray(bins), jnp.asarray(grad), jnp.asarray(hess), num_bins,
-        rows_per_block=256, interpret=True))
+        interpret=True))       # the block H.histogram dispatches: 512 rows
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
 
 

@@ -301,6 +301,45 @@ def test_hist_method_dispatch(monkeypatch):
                     jnp.zeros(4), 4, method="multival_pallas")
 
 
+def test_hist_method_values_are_all_accepted(monkeypatch):
+    """Whatever hist_method() can return is something a consumer takes:
+    the column-major dispatch for None / radix_pallas[_bf16], the fused
+    grower (with the dataset handle) for multival_pallas. Anything else
+    — a method of a deleted kernel, a typo — raises: it used to fall
+    through to the scatter oracle in silence."""
+    import itertools
+    Xw, _ = make_wide_sparse(n=320)
+    dsw = BinnedDataset.from_matrix(Xw, Config.from_params(
+        {"min_data_in_leaf": 5}))
+    args = (jnp.zeros((4, 2), jnp.int32), jnp.ones(4), jnp.ones(4), 4)
+    for bad in ("radix", "radix_bf16", "pallas", "radix_palas"):
+        with pytest.raises(ValueError):
+            H.histogram(*args, method=bad)
+    taken = []
+    monkeypatch.setattr(
+        H, "histogram_radix_pallas",
+        lambda bins, grad, hess, num_bins, dtype=jnp.float32:
+        taken.append(dtype))
+    seen = set()
+    for use_tpu, dev, dt, layout, ds in itertools.product(
+            (False, True), ("tpu", "cpu"), ("bfloat16", "float32"),
+            ("auto", "planar", "multival"), (None, dsw)):
+        monkeypatch.setattr(H, "_use_tpu", lambda v=use_tpu: v)
+        m = H.hist_method(Config.from_params(
+            {"device_type": dev, "tpu_hist_dtype": dt,
+             "tpu_hist_layout": layout}), ds)
+        seen.add(m)
+        if m == "multival_pallas":
+            assert ds is not None and use_tpu and dev == "tpu"
+        else:
+            H.histogram(*args, method=m)      # does not raise
+            if m is not None:
+                assert taken.pop() == (jnp.float32 if dt == "float32"
+                                       else jnp.bfloat16)
+    assert seen == {None, "radix_pallas", "radix_pallas_bf16",
+                    "multival_pallas"}
+
+
 def test_dispatch_telemetry_counters(monkeypatch):
     from lightgbm_tpu.obs import registry as R
     reg = R.MetricsRegistry()

@@ -44,45 +44,6 @@ def test_histogram_scatter_matches_numpy(rng):
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
 
 
-def test_histogram_pallas_interpret_matches_scatter(rng):
-    n, f, B = 700, 5, 32
-    bins = rng.randint(0, B, size=(n, f)).astype(np.uint8)
-    grad = rng.randn(n).astype(np.float32)
-    hess = rng.rand(n).astype(np.float32)
-    want = np.asarray(H.histogram_scatter(jnp.asarray(bins), jnp.asarray(grad),
-                                          jnp.asarray(hess), B))
-    got = np.asarray(H.histogram_pallas(jnp.asarray(bins), jnp.asarray(grad),
-                                        jnp.asarray(hess), B,
-                                        rows_per_block=256, interpret=True))
-    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
-
-
-@pytest.mark.parametrize("n,f,B", [(500, 7, 16), (777, 28, 63),
-                                   (1000, 5, 256), (311, 3, 255)])
-def test_histogram_radix_matches_scatter(rng, n, f, B):
-    bins = rng.randint(0, B, size=(n, f)).astype(np.uint8)
-    grad = rng.randn(n).astype(np.float32)
-    hess = rng.rand(n).astype(np.float32)
-    want = np.asarray(H.histogram_scatter(jnp.asarray(bins), jnp.asarray(grad),
-                                          jnp.asarray(hess), B))
-    got = np.asarray(H.histogram_radix(jnp.asarray(bins), jnp.asarray(grad),
-                                       jnp.asarray(hess), B))
-    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
-
-
-def test_histogram_radix_row_chunking(rng):
-    # force the lax.scan multi-chunk path with a tiny row_chunk
-    n, f, B = 1000, 6, 64
-    bins = rng.randint(0, B, size=(n, f)).astype(np.uint8)
-    grad = rng.randn(n).astype(np.float32)
-    hess = rng.rand(n).astype(np.float32)
-    want = np.asarray(H.histogram_scatter(jnp.asarray(bins), jnp.asarray(grad),
-                                          jnp.asarray(hess), B))
-    got = np.asarray(H.histogram_radix(jnp.asarray(bins), jnp.asarray(grad),
-                                       jnp.asarray(hess), B, row_chunk=128))
-    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
-
-
 def test_leaf_histogram_respects_count(rng):
     n, f, B = 300, 4, 8
     bins = rng.randint(0, B, size=(n, f)).astype(np.uint8)

@@ -354,6 +354,32 @@ class TestQuarantine:
         assert bst.num_trees() == 5
         assert np.isfinite(bst.predict(X)).all()
 
+    def test_sentinel_dispatches_in_the_iteration_that_made_the_tree(self):
+        """Persistent tier, no valid set: every update() leaves one
+        PendingTree holding its own device arrays, and the sentinel's
+        check of that tree is dispatched in that iteration, under that
+        iteration's number (nothing is queued for a later flush)."""
+        import jax
+        from lightgbm_tpu.treelearner.fused import PendingTree
+        X, y = _make_data()
+        bst = lgb.Booster(dict(BASE, numeric_sentinels=True),
+                          lgb.Dataset(X, label=y))
+        g = bst._gbdt
+        assert g.execution_plan()["tier"] == "persistent-fused"
+        for i in range(3):
+            bst.update()
+            t = g.models[i]
+            assert isinstance(t, PendingTree) and t._tree is None
+            assert isinstance(t.tree_arrays["leaf_value"], jax.Array)
+            assert g._sentinel.checks == i + 1
+            assert [it for it, _ in g._sentinel._pending] \
+                == list(range(i + 1))
+        leaves = [t.tree_arrays["leaf_value"] for t in g.models]
+        assert len({id(a) for a in leaves}) == 3
+        g.sentinel_drain()
+        assert g._sentinel._pending == [] and not g._sentinel.pop_trips()
+        assert bst.num_trees() == 3
+
     def test_quarantine_iter_bounds_and_rebuild(self):
         X, y = _make_data()
         bst = _train(BASE, X, y, 4)
