@@ -227,12 +227,17 @@ class GBDT:
         from ..ops import histogram as H
         hist = (self._fused._hist_method if self._fused is not None
                 else H.hist_method(self.config, self.train_data))
-        return {"backend": jax.default_backend(),
+        plan = {"backend": jax.default_backend(),
                 "device_kind": dev[0].device_kind, "device_count": len(dev),
                 "tier": tier, "learner": type(grower).__name__,
                 "hist": hist or "scatter",
                 "partition": getattr(self._fused, "_part_method", "xla"),
                 "sampling": self._sampling_plan()}
+        if plan["sampling"] is not None:
+            # what assigns every row its leaf once rows are left out
+            plan["row_traverse"] = getattr(self._fused,
+                                           "row_traverse_method", "xla")
+        return plan
 
     def _sampling_plan(self) -> Optional[str]:
         """The row sampling each tree is grown under, None without."""

@@ -38,8 +38,15 @@ registers, so no primitive ever pays a per-row toll:
   (bin.h threshold semantics) are elementwise with prefetched
   scalars.
 
-The XLA reference implementation (`partition_ref`) is the portable
-CPU path and the correctness oracle for the kernel.
+- **The all-lane traverse** (row sampling: every row's leaf, out-of-bag
+  ones included) reuses that routing with the loop nest the other way
+  round: `traverse_planes_pallas` holds a lane tile of the code planes
+  in VMEM and replays ALL of a tree's splits on it, one pass over the
+  planes a tree.
+
+The XLA reference implementations (`partition_ref`,
+`traverse_planes_ref`) are the portable CPU path and the correctness
+oracles for the kernels.
 """
 from __future__ import annotations
 
@@ -346,31 +353,68 @@ def route_scalars(layout: PlaneLayout, feature, threshold, default_left,
                    jnp.asarray(miss_bin, jnp.int32), *efb, ic]), bits])
 
 
-def _route_from_col32(col32, rs):
-    """Shared routing math: packed plane word -> go_left (bool), given
-    the scalar vector rs (see route_scalars). All intermediates stay
-    int32 — Mosaic cannot select/broadcast i1 vectors.
+def _code_from_col32(col32, rs):
+    """The split column's bin code out of its packed plane word."""
+    return jax.lax.shift_right_logical(col32, rs[1]) & rs[2]
 
-    Categorical routing (rs[10] == 1) is bitset membership over the 8
-    prefetched words (dense_bin.hpp Split categorical case): the word
-    is selected by a masked sum, the bit by a per-lane variable shift
-    — no gather. Missing categoricals ignore default_left (they are
-    out-of-set -> right), mirroring ops/partition._decision_go_left."""
-    code = jax.lax.shift_right_logical(col32, rs[1]) & rs[2]
+
+def _efb_bin(code, rs):
+    """EFB bundle decode (io/efb.py:194): the member feature's bin from
+    the bundle column's code; out-of-band codes read as its skip bin."""
     rel = code - rs[7]
     inband = ((rel >= 0) & (rel < rs[8])).astype(jnp.int32)
     dec = rel + (rel >= rs[9]).astype(jnp.int32)
-    efb_bin = jnp.where(inband == 1, dec, rs[9])
-    binval = jnp.where(rs[6] == 1, efb_bin, code)
-    num_left = (binval <= rs[3]).astype(jnp.int32)
+    return jnp.where(inband == 1, dec, rs[9])
+
+
+def _num_left(binval, rs):
+    return (binval <= rs[3]).astype(jnp.int32)
+
+
+def _is_missing(binval, rs):
+    return (binval == rs[5]) & (rs[5] >= 0)
+
+
+def _cat_left(binval, rs):
+    """Bitset membership over the 8 prefetched words (dense_bin.hpp
+    Split categorical case): the word is selected by a masked sum, the
+    bit by a per-lane variable shift — no gather."""
     widx = jax.lax.shift_right_logical(binval, 5)
     word = jnp.zeros_like(binval)
     for w in range(CAT_WORDS):
         word = word + jnp.where(widx == w, rs[11 + w], 0)
-    cat_left = jax.lax.shift_right_logical(word, binval & 31) & 1
+    return jax.lax.shift_right_logical(word, binval & 31) & 1
+
+
+def _route_numerical(binval, rs):
+    """go_left of a numerical split: threshold compare, the missing bin
+    by default_left (bin.h threshold semantics)."""
+    is_miss = _is_missing(binval, rs).astype(jnp.int32)
+    return jnp.where(is_miss == 1, rs[4], _num_left(binval, rs)) == 1
+
+
+def _route_categorical(binval, rs):
+    """go_left of a categorical split. Missing categoricals ignore
+    default_left (they are out-of-set -> right), mirroring
+    ops/partition._decision_go_left."""
+    return _cat_left(binval, rs) == 1
+
+
+def _route_from_col32(col32, rs):
+    """Shared routing math: packed plane word -> go_left (bool), given
+    the scalar vector rs (see route_scalars), whatever the split's kind:
+    every piece is computed and the scalars rs[6] (EFB) and rs[10]
+    (categorical) select among them. The traverse kernel, whose scalars
+    sit in SMEM, branches on the same two and calls the same pieces. All
+    intermediates stay int32 — Mosaic cannot select/broadcast i1
+    vectors."""
+    code = _code_from_col32(col32, rs)
+    efb_bin = _efb_bin(code, rs)
+    binval = jnp.where(rs[6] == 1, efb_bin, code)
+    num_left = _num_left(binval, rs)
+    cat_left = _cat_left(binval, rs)
     dec_lr = jnp.where(rs[10] == 1, cat_left, num_left)
-    is_miss = ((binval == rs[5]) & (rs[5] >= 0)
-               & (rs[10] == 0)).astype(jnp.int32)
+    is_miss = (_is_missing(binval, rs) & (rs[10] == 0)).astype(jnp.int32)
     return jnp.where(is_miss == 1, rs[4], dec_lr) == 1
 
 
@@ -1040,6 +1084,165 @@ def partition_window(data, layout, start, count, rscal, *, method,
         return partition_pallas2(data, layout, start, count, rscal,
                                  cap=cap, tile=tile, interpret=interpret)
     return partition_ref(data, layout, start, count, rscal, cap=cap)
+
+
+# ---------------------------------------------------------------------------
+# all-lane traverse: a tree's splits replayed on a lane tile held in VMEM
+# ---------------------------------------------------------------------------
+
+TRAVERSE_REC = 1 + ROUTE_SCALARS   # a split's record: its slot, route_scalars
+# 128-lane rows of a tile (256K lanes, 1 MB a plane: 8 MB of VMEM with
+# three planes and the leaf ids double-buffered; 4096 rows do not fit),
+# and rows routed at a time (sixteen vregs an array). Timed on a v5e at
+# 3 planes x 22M lanes x 254 splits (PERF.md, PR 30): chunks of 32 /
+# 64 / 128 / 256 / 512 rows 34.1 / 26.6 / 24.3 / 25.6 / 32.8 ms.
+TRAVERSE_ROWS = 2048
+TRAVERSE_CHUNK = 128
+
+
+def _tree_routes(layout: PlaneLayout, ta, miss_bin, efb_dev, k):
+    """route_scalars of node(s) ``k`` of the tree arrays ``ta``
+    (treelearner/fused.py: split_feature, threshold_bin, default_left,
+    split_cat, split_bits [L - 1, 8]); ``miss_bin`` [F] is the missing
+    bin of every feature, -1 without."""
+    f = ta["split_feature"][k]
+    return route_scalars(layout, f, ta["threshold_bin"][k],
+                         ta["default_left"][k], miss_bin[f], efb_dev,
+                         is_cat=ta["split_cat"][k],
+                         cat_bitset=ta["split_bits"][k])
+
+
+def traverse_planes_ref(codes_planes: jax.Array, layout: PlaneLayout, ta,
+                        miss_bin, efb_dev=None) -> jax.Array:
+    """Leaf id of every lane of ``codes_planes`` [C, R] under the tree
+    ``ta`` in plain XLA, [R] i32: the splits replayed in the order they
+    were made, one elementwise pass over the split column's plane per
+    split. Node k split leaf slot s: the left child keeps s, the right
+    child is leaf k + 1 (Tree::Split numbering, tree.h:61), and a child
+    that is split later inherits its slot. The portable path, and the
+    oracle of `traverse_planes_pallas`."""
+    L = ta["split_feature"].shape[0] + 1
+
+    def step(k, carry):
+        leaf_of_lane, slot_of_node = carry
+        slot = slot_of_node[k]
+        rs = _tree_routes(layout, ta, miss_bin, efb_dev, k)
+        col32 = jax.lax.dynamic_index_in_dim(codes_planes, rs[0], axis=0,
+                                             keepdims=False)
+        go_right = ~_route_from_col32(col32, rs)
+        leaf_of_lane = jnp.where((leaf_of_lane == slot) & go_right, k + 1,
+                                 leaf_of_lane)
+        lc, rc = ta["left_child"][k], ta["right_child"][k]
+        # a leaf child (negative) indexes past the end and is dropped
+        slot_of_node = slot_of_node.at[jnp.where(lc >= 0, lc, L)].set(
+            slot, mode="drop")
+        slot_of_node = slot_of_node.at[jnp.where(rc >= 0, rc, L)].set(
+            k + 1, mode="drop")
+        return leaf_of_lane, slot_of_node
+
+    leaf_of_lane, _ = jax.lax.fori_loop(
+        0, ta["n_leaves"] - 1, step,
+        (jnp.zeros(codes_planes.shape[1], jnp.int32),
+         jnp.zeros(L - 1, jnp.int32)))
+    return leaf_of_lane
+
+
+def traverse_table(layout: PlaneLayout, ta, miss_bin,
+                   efb_dev=None) -> jax.Array:
+    """The flat i32 table `traverse_planes_pallas` prefetches into SMEM,
+    built once per tree: [n_leaves - 1, then per node k its record (the
+    leaf slot it split, its route_scalars vector)]."""
+    L = ta["split_feature"].shape[0] + 1
+    k = jnp.arange(L - 1, dtype=jnp.int32)
+    live = k < ta["n_leaves"] - 1
+    # a node's slot: the root's is 0, a right child's its parent's node
+    # index + 1, a left child's its parent's — resolved up the chain of
+    # left children by pointer doubling, not node by node
+    lc = jnp.where(live & (ta["left_child"] > 0), ta["left_child"], L)
+    rc = jnp.where(live & (ta["right_child"] > 0), ta["right_child"], L)
+    slot = jnp.full(L - 1, -1, jnp.int32).at[0].set(0).at[rc].set(
+        k + 1, mode="drop")
+    up = k.at[lc].set(k, mode="drop")
+    for _ in range((L - 2).bit_length()):
+        slot = jnp.where(slot < 0, slot[up], slot)
+        up = up[up]
+    rscals = jax.vmap(functools.partial(_tree_routes, layout, ta, miss_bin,
+                                        efb_dev))(k)
+    rec = jnp.concatenate([slot[:, None], rscals], axis=1)
+    return jnp.concatenate(
+        [jnp.asarray(ta["n_leaves"], jnp.int32).reshape(1) - 1,
+         rec.reshape(-1)])
+
+
+def _traverse_kernel(tbl, codes_ref, leaf_ref, *, rows, chunk):
+    """One lane tile: codes_ref [C, rows, 128] (lane r of the planes is
+    element (r // 128, r % 128): dense (8, 128) vregs), leaf_ref
+    [rows, 128]. The splits are the inner loop, in the order they were
+    made, each one routed by what its scalars say it is."""
+    from jax.experimental import pallas as pl
+
+    leaf_ref[...] = jnp.zeros_like(leaf_ref)
+
+    def split(k, _):
+        base = 1 + k * TRAVERSE_REC
+        slot = tbl[base]
+        rs = [tbl[base + 1 + i] for i in range(ROUTE_SCALARS)]
+
+        def sweep(route):
+            def rows_at(i, _):
+                r = pl.ds(pl.multiple_of(i * chunk, chunk), chunk)
+                go_left = route(_code_from_col32(codes_ref[rs[0], r, :], rs))
+                leaf = leaf_ref[r, :]
+                leaf_ref[r, :] = jnp.where((leaf == slot) & ~go_left,
+                                           k + 1, leaf)
+            jax.lax.fori_loop(0, rows // chunk, rows_at, None)
+
+        # one branch per kind of split, chosen by the scalars the generic
+        # routing selects with: a numerical split never pays the bitset
+        for efb in (0, 1):
+            for cat, route in ((0, _route_numerical), (1, _route_categorical)):
+                @pl.when((rs[6] == efb) & (rs[10] == cat))
+                def _(efb=efb, route=route):
+                    sweep(lambda code: route(
+                        _efb_bin(code, rs) if efb else code, rs))
+
+    jax.lax.fori_loop(0, tbl[0], split, None)
+
+
+# tpulint: jit-ok(kernel entry; dispatched through manager-registered learner entries)
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def traverse_planes_pallas(codes_planes: jax.Array, table: jax.Array, *,
+                           interpret: bool = False) -> jax.Array:
+    """Leaf id of every lane of ``codes_planes`` [C, R] under the tree
+    that ``table`` describes (traverse_table): [R] i32. The grid runs
+    over lane tiles and each tile replays all the splits with its code
+    planes and leaf ids resident in VMEM, so the planes are read once
+    and the leaf ids written once a TREE, where a loop over the splits
+    in XLA makes two passes over all R lanes a SPLIT."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    C, R = codes_planes.shape
+    unit = LANE * TRAVERSE_CHUNK
+    if R % unit:        # a layout whose lane tile shrank below the unit
+        codes_planes = jnp.pad(codes_planes, ((0, 0), (0, -R % unit)))
+    nrows = codes_planes.shape[1] // LANE
+    rows = min(TRAVERSE_ROWS, nrows)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(pl.cdiv(nrows, rows),),
+        in_specs=[pl.BlockSpec((C, rows, LANE), lambda t, tbl: (0, t, 0))],
+        out_specs=pl.BlockSpec((rows, LANE), lambda t, tbl: (t, 0)),
+    )
+    leaf = pl.pallas_call(
+        functools.partial(_traverse_kernel, rows=rows,
+                          chunk=TRAVERSE_CHUNK),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((nrows, LANE), jnp.int32),
+        name="traverse_planes_pallas",
+        interpret=interpret,
+    )(table, codes_planes.reshape(C, nrows, LANE))
+    return leaf.reshape(-1)[:R]
 
 
 # ---------------------------------------------------------------------------

@@ -1890,46 +1890,30 @@ class FusedSerialGrower:
         node = jax.lax.while_loop(cond, body, node)
         return -node - 1
 
+    @property
+    def row_traverse_method(self) -> str:
+        """What replays a tree's splits over the resident planes under
+        row sampling: the Pallas kernel where the partition's run."""
+        return "xla" if self._part_method == "ref" else "pallas"
+
     def traverse_planes(self, ta, codes_planes) -> jax.Array:
         """Leaf index of every lane of the resident planar codes (lane
         r = row r, out-of-bag rows included): the score update's side
         of GBDT::UpdateScore under row sampling. The tree's splits are
         replayed in the order they were made, each with the partition's
         own routing (plane.route_scalars: EFB decode, missing bin,
-        categorical bitset), as one elementwise pass over the split
-        column's plane — no per-row gather and no row-major table.
-        Node k split leaf slot s: the left child keeps s, the right
-        child is leaf k + 1 (Tree::Split numbering, tree.h:61), and a
-        child that is split later inherits its slot."""
-        L = self.num_leaves
-
-        def step(k, carry):
-            leaf_of_lane, slot_of_node = carry
-            slot = slot_of_node[k]
-            f = ta["split_feature"][k]
-            rs = plane.route_scalars(
-                self.layout, f, ta["threshold_bin"][k],
-                ta["default_left"][k], self.feature_miss_bin[f],
-                self._efb_dev, is_cat=ta["split_cat"][k],
-                cat_bitset=ta["split_bits"][k])
-            col32 = jax.lax.dynamic_index_in_dim(codes_planes, rs[0],
-                                                 axis=0, keepdims=False)
-            go_right = ~plane._route_from_col32(col32, rs)
-            leaf_of_lane = jnp.where((leaf_of_lane == slot) & go_right,
-                                     k + 1, leaf_of_lane)
-            lc, rc = ta["left_child"][k], ta["right_child"][k]
-            # a leaf child (negative) indexes past the end and is dropped
-            slot_of_node = slot_of_node.at[jnp.where(lc >= 0, lc, L)].set(
-                slot, mode="drop")
-            slot_of_node = slot_of_node.at[jnp.where(rc >= 0, rc, L)].set(
-                k + 1, mode="drop")
-            return leaf_of_lane, slot_of_node
-
-        leaf_of_lane, _ = jax.lax.fori_loop(
-            0, ta["n_leaves"] - 1, step,
-            (jnp.zeros(codes_planes.shape[1], jnp.int32),
-             jnp.zeros(L - 1, jnp.int32)))
-        return leaf_of_lane
+        categorical bitset) — no per-row gather and no row-major table.
+        Where the Pallas kernels run, one kernel makes one pass over
+        the planes with the splits as its inner loop; elsewhere the
+        splits are the outer loop of a pass each."""
+        if self.row_traverse_method == "xla":
+            return plane.traverse_planes_ref(
+                codes_planes, self.layout, ta, self.feature_miss_bin,
+                self._efb_dev)
+        table = plane.traverse_table(self.layout, ta, self.feature_miss_bin,
+                                     self._efb_dev)
+        return plane.traverse_planes_pallas(codes_planes, table,
+                                            interpret=self._interpret)
 
     # ------------------------------------------------------------------
     def _tree_mask_np(self) -> np.ndarray:

@@ -19,6 +19,13 @@ at these window sizes (my chip run, PR 21: 78.8 s and 69.1 s for one
 f32 and one int32 scatter over 73,728 x 28 codes), which is what this
 check would then mostly measure.
 
+Traverse: `traverse_planes_pallas` (the per-tree tier's all-row leaf
+assignment under row sampling: every split of a tree replayed on a lane
+tile held in VMEM) against `traverse_planes_ref`, the XLA loop that
+makes a pass over all lanes per split, at `expo700goss.train`'s
+geometry: 3 code planes x 22M lanes, 254 splits on bundled columns. The
+leaf ids must be EQUAL; both times are printed in ns a lane-split.
+
 `python scripts/kernel_check.py` runs the checks on random states and
 exits non-zero on any mismatch, or when JAX finds no TPU (Mosaic lowers
 nowhere else). `chip_smoke.py` imports the check functions and runs them
@@ -180,6 +187,101 @@ def random_state(n, g, seed, *, bits=8, tile=2048):
     return data, layout
 
 
+def random_tree(rng, num_leaves: int, n_splits: int, features, max_bin: int,
+                *, cat_features=(), chain: bool = False):
+    """Tree arrays (the dict treelearner/fused.py grows, on the host) of
+    a tree made by ``n_splits`` random splits in Tree::Split numbering:
+    node k splits a leaf slot s, its left child keeps s and its right
+    child is leaf k + 1. ``chain`` splits slot 0 every time (the longest
+    chain of left children a tree of that size can have); splits on
+    ``cat_features`` are categorical with bits in all eight words."""
+    i32 = np.int32
+    ta = {"n_leaves": i32(n_splits + 1),
+          "split_feature": np.zeros(num_leaves - 1, i32),
+          "threshold_bin": np.zeros(num_leaves - 1, i32),
+          "default_left": np.zeros(num_leaves - 1, bool),
+          "split_cat": np.zeros(num_leaves - 1, bool),
+          "split_bits": np.zeros((num_leaves - 1, plane.CAT_WORDS), i32),
+          "left_child": np.zeros(num_leaves - 1, i32),
+          "right_child": np.zeros(num_leaves - 1, i32)}
+    held_by = {0: None}            # leaf slot -> (node, side) that holds it
+    for k in range(n_splits):
+        s = 0 if chain else int(rng.randint(0, k + 1))
+        f = int(rng.choice(features))
+        ta["split_feature"][k] = f
+        ta["threshold_bin"][k] = rng.randint(0, max_bin)
+        ta["default_left"][k] = rng.rand() < 0.5
+        if f in cat_features:
+            ta["split_cat"][k] = True
+            ta["split_bits"][k] = rng.randint(
+                -2 ** 31, 2 ** 31, plane.CAT_WORDS, dtype=np.int64).astype(i32)
+        if held_by[s] is not None:
+            node, side = held_by[s]
+            ta[side][node] = k
+        ta["left_child"][k], ta["right_child"][k] = ~s, ~(k + 1)
+        held_by[s], held_by[k + 1] = (k, "left_child"), (k, "right_child")
+    return {key: jnp.asarray(v) for key, v in ta.items()}
+
+
+def check_traverse(codes_planes, layout, ta, miss_bin, efb_dev=None, *,
+                   interpret: bool = False, reps: int = 1):
+    """traverse_planes_pallas vs traverse_planes_ref on one tree: `ok`
+    iff every lane's leaf id agrees; host seconds of each (a blocked
+    call, after one that compiled) as ns a lane-split."""
+    t0 = time.perf_counter()
+    ref_fn = jax.jit(lambda cp, ta: plane.traverse_planes_ref(
+        cp, layout, ta, miss_bin, efb_dev))
+    got_fn = jax.jit(lambda cp, ta: plane.traverse_planes_pallas(
+        cp, plane.traverse_table(layout, ta, miss_bin, efb_dev),
+        interpret=interpret))
+
+    def timed(fn):
+        out = jax.block_until_ready(fn(codes_planes, ta))
+        t = time.perf_counter()
+        for _ in range(reps):
+            out = jax.block_until_ready(fn(codes_planes, ta))
+        return out, (time.perf_counter() - t) / reps
+
+    ref, ref_s = timed(ref_fn)
+    got, got_s = timed(got_fn)
+    work = max(codes_planes.shape[1] * (int(ta["n_leaves"]) - 1), 1)
+    return {"check": f"traverse/{codes_planes.shape[0]}x"
+                     f"{codes_planes.shape[1]}/{int(ta['n_leaves']) - 1} "
+                     "splits",
+            "leaves_seen": int(jnp.max(got)) + 1,
+            "xla_ns_per_lane_split": ref_s / work * 1e9,
+            "pallas_ns_per_lane_split": got_s / work * 1e9,
+            "ok": bool(jnp.all(ref == got)),
+            "seconds": round(time.perf_counter() - t0, 1)}
+
+
+def bundled_tables(num_features: int, num_cols: int):
+    """(group_of, offset_of, nslots_of, skip_of) of ``num_features``
+    features spread evenly over ``num_cols`` bundle columns, three codes
+    each from code 1 up, bin 0 skipped (a one-hot column's default):
+    the shape of `expo700goss`'s bundles."""
+    f = np.arange(num_features)
+    per_col = -(-num_features // num_cols)
+    dev = (f // per_col, 1 + (f % per_col) * 3, np.full(num_features, 3),
+           np.zeros(num_features))
+    return tuple(jnp.asarray(t, jnp.int32) for t in dev)
+
+
+def traverse_at_cell_geometry(seed: int = 0):
+    """`expo700goss.train`'s traverse: 22M rows in 3 planes of 8-bit
+    codes (11 bundle columns of 700 features), 255 leaves."""
+    features, cols, leaves = 700, 11, 255
+    layout = plane.make_layout(cols, 8, 22_000_000)
+    codes_planes = jax.random.bits(
+        jax.random.PRNGKey(seed), (layout.code_planes, layout.num_lanes),
+        jnp.uint32).view(jnp.int32)
+    ta = random_tree(np.random.RandomState(seed), leaves, leaves - 1,
+                     np.arange(features), 4)
+    return check_traverse(codes_planes, layout, ta,
+                          jnp.full(features, -1, jnp.int32),
+                          bundled_tables(features, cols), reps=3)
+
+
 # ---------------------------------------------------------------------------
 # Mosaic lower-and-compile of the kernels the dispatcher can select off
 # the HIGGS main path, from ShapeDtypeStructs at one real geometry each
@@ -317,6 +419,7 @@ def main() -> int:
             plane.route_scalars(layout4, 5, 7, 1, 15), kernel=kernel,
             dynamic=True))
     results.append(check_histogram(data4, layout4, 1000, 90_000, 16))
+    results.append(traverse_at_cell_geometry())
     for r in results:
         print(r, flush=True)
     compiled = [compile_only(*k) for k in off_main_path_kernels()]

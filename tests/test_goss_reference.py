@@ -201,6 +201,62 @@ def test_execution_plan_names_the_sampling(extra, sampling):
     assert plan["sampling"] == sampling
     assert plan["tier"] == ("per-tree-fused" if sampling
                             else "persistent-fused")
+    # off a TPU the partition is the XLA one, and so is the traverse; a
+    # run that leaves no row out assigns no leaf by traversing
+    assert plan.get("row_traverse") == ("xla" if sampling else None)
+
+
+# ------------------------------------------- the traverse kernel, end to end
+
+SAMPLED = {
+    "bagging": dict(boosting="gbdt", bagging_fraction=0.6, bagging_freq=1),
+    "pos_neg_bagging": dict(boosting="gbdt", pos_bagging_fraction=0.5,
+                            neg_bagging_fraction=0.8, bagging_freq=1),
+    "goss": dict(boosting="goss", learning_rate=0.5),
+    "rf": dict(boosting="rf", bagging_fraction=0.6, bagging_freq=1,
+               feature_fraction=0.8),
+    "goss_four_shards": dict(boosting="goss", learning_rate=0.5,
+                             tree_learner="data", tpu_mesh_shape=[4]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SAMPLED))
+def test_traverse_kernel_trains_the_ref_traverses_model(name, monkeypatch,
+                                                        tmp_path):
+    """Six trees with the Pallas kernels selected (interpreted here): the
+    model and the training scores with `traverse_planes_pallas` assigning
+    every row its leaf are, byte for byte, those with the XLA loop in its
+    place. One-hot fields in bundles, a sample from the third tree on
+    (GOSS) or from the first."""
+    from lightgbm_tpu.compile import reset_manager
+    from lightgbm_tpu.ops import histogram as H
+    from lightgbm_tpu.treelearner.fused import FusedSerialGrower
+    if name == "goss_four_shards" and len(jax.devices()) < 4:
+        pytest.skip("needs 4 (virtual) devices")
+    X, y = one_hot_rows(3000, seed=6)
+    params = dict(P, num_leaves=7, **SAMPLED[name])
+    monkeypatch.setattr(H, "_use_tpu", lambda: True)
+    monkeypatch.setenv("LGBM_TPU_WARMUP", "0")
+
+    def train(side):
+        # a compile cache a side: the two programs differ in nothing the
+        # manager's key names
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / side))
+        reset_manager()
+        bst = lgb.train(params, lgb.Dataset(X, label=y), num_boost_round=6,
+                        keep_training_booster=True)
+        assert bst._gbdt.execution_plan()["row_traverse"] == side
+        return (bst.model_to_string(),
+                np.asarray(bst._gbdt.get_training_score()).tobytes())
+
+    try:
+        got = train("pallas")
+        monkeypatch.setattr(FusedSerialGrower, "row_traverse_method", "xla")
+        want = train("xla")
+    finally:
+        reset_manager()
+    assert got[0].count("Tree=") == 6
+    assert got == want
 
 
 # ------------------------------------------------- the bag's capacity

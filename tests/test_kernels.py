@@ -351,3 +351,104 @@ def test_histogram_planar_pallas_interpret_matches_scatter(code_bits,
         jnp.asarray(codes[sel]), jnp.asarray(grad[sel]),
         jnp.asarray(hess[sel]), num_bins))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# traverse_planes_pallas vs traverse_planes_ref
+# ---------------------------------------------------------------------------
+
+def _kernel_check():
+    import os
+    import sys
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts"))
+    import kernel_check
+    return kernel_check
+
+
+# features 0..5 in 2 bundle columns, per feature (column, offset, slots,
+# skip bin): skip bins at the band's start, inside it and past it. Codes
+# below 64 cover each member's band, the other members' (out of band:
+# the skip bin) and code 0
+EFB6 = [(0, 1, 9, 0), (0, 10, 9, 4), (0, 19, 30, 30),
+        (1, 1, 20, 7), (1, 21, 20, 0), (1, 41, 5, 2)]
+
+# name -> (rows, columns, code bits, highest code + 1, leaves, splits,
+#          features split on, what else)
+TRAVERSE_CASES = {
+    # 4 columns a plane at 8 bits: column 1 in the first, 10 in the last
+    "numerical_8bit": (5000, 11, 8, 256, 31, 30, [1, 10], {}),
+    # 8 columns a plane at 4 bits, 20 columns in 3 planes
+    "numerical_4bit": (5000, 20, 4, 16, 31, 30, [3, 19], {}),
+    # 2 columns a plane at 16 bits, 5 columns in 3 planes
+    "numerical_16bit": (5000, 5, 16, 60000, 31, 30, [0, 4], {}),
+    "efb_bundled": (5000, 2, 8, 64, 31, 30, list(range(6)),
+                    {"efb": EFB6, "max_bin": 31}),
+    # codes 0..7 with bin 7 missing: a fifth of the rows go by
+    # default_left, which random_tree draws both ways
+    "missing_bin": (5000, 6, 8, 8, 31, 30, list(range(6)),
+                    {"miss_bin": 7, "max_bin": 7}),
+    "missing_bin_bundled": (5000, 2, 8, 64, 31, 30, list(range(6)),
+                            {"efb": EFB6, "miss_bin": 3, "max_bin": 20}),
+    # bits drawn in all eight words of the set; numerical splits between
+    "categorical": (5000, 6, 8, 256, 31, 30, list(range(6)),
+                    {"cat": [1, 2, 5]}),
+    "categorical_bundled": (5000, 2, 8, 64, 15, 14, list(range(6)),
+                            {"efb": EFB6, "cat": [0, 2, 4]}),
+    "stump": (5000, 6, 8, 256, 31, 0, [0], {}),
+    "two_leaves": (5000, 6, 8, 256, 2, 1, [3], {}),
+    "stopped_short": (5000, 6, 8, 256, 31, 9, list(range(6)), {}),
+    # every split of slot 0: 254 left children in a chain
+    "left_chain": (3000, 6, 8, 256, 255, 254, list(range(6)),
+                   {"chain": True}),
+    # 360,448 lanes: a whole tile of 2,048 x 128, a part of a second,
+    # and 60,448 pad lanes past the rows
+    "two_tiles_and_pad_lanes": (300_000, 6, 8, 256, 15, 14,
+                                list(range(6)), {}),
+}
+
+
+def _host_leaves(ta, bins):
+    """Leaf of every row by walking the tree's nodes (numerical splits,
+    no missing bin) over the decoded bins."""
+    node = np.zeros(len(bins), np.int64) if int(ta["n_leaves"]) > 1 \
+        else np.full(len(bins), -1, np.int64)
+    rows = np.flatnonzero(node >= 0)
+    feat, thr = np.asarray(ta["split_feature"]), np.asarray(
+        ta["threshold_bin"])
+    left, right = np.asarray(ta["left_child"]), np.asarray(ta["right_child"])
+    while rows.size:
+        at = node[rows]
+        node[rows] = np.where(bins[rows, feat[at]] <= thr[at], left[at],
+                              right[at])
+        rows = rows[node[rows] >= 0]
+    return ~node
+
+
+@pytest.mark.parametrize("case", sorted(TRAVERSE_CASES))
+def test_traverse_planes_pallas_interpret_matches_ref(case):
+    K = _kernel_check()
+    n, cols, bits, codes_hi, leaves, splits, features, extra = \
+        TRAVERSE_CASES[case]
+    rng = np.random.RandomState(len(case))
+    codes = rng.randint(0, codes_hi, size=(n, cols)).astype(
+        np.uint16 if bits == 16 else np.uint8)
+    layout = plane.make_layout(cols, bits, n)
+    cp = plane.build_codes_planes(jnp.asarray(codes), layout)
+    efb = tuple(jnp.asarray(t, jnp.int32) for t in zip(*extra["efb"])) \
+        if "efb" in extra else None
+    num_features = len(extra["efb"]) if efb else cols
+    ta = K.random_tree(rng, leaves, splits, features,
+                       extra.get("max_bin", codes_hi),
+                       cat_features=extra.get("cat", ()),
+                       chain=extra.get("chain", False))
+    miss = jnp.full(num_features, extra.get("miss_bin", -1), jnp.int32)
+    got = K.check_traverse(cp, layout, ta, miss, efb, interpret=True)
+    assert got["ok"], got
+    assert got["leaves_seen"] > min(splits, 3), got
+    if not (efb or extra.get("cat") or "miss_bin" in extra):
+        leaf = np.asarray(plane.traverse_planes_pallas(
+            cp, plane.traverse_table(layout, ta, miss), interpret=True))
+        np.testing.assert_array_equal(leaf[:n], _host_leaves(ta, codes))
+        # pad lanes hold code 0 in every column
+        assert (leaf[n:] == _host_leaves(ta, np.zeros((1, cols), int))).all()

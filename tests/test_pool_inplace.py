@@ -79,15 +79,16 @@ def as_on_tpu(monkeypatch):
     reset_manager()
 
 
-def _grower(extra):
+def _grower(extra, shape=(ROWS, COLS, LEAVES, MAX_BIN)):
+    rows, cols, leaves, max_bin = shape
     rng = np.random.RandomState(1)
-    X = rng.randn(ROWS, COLS).astype(np.float32)
+    X = rng.randn(rows, cols).astype(np.float32)
     if extra.get("objective") == "multiclass":
-        y = rng.randint(0, extra["num_class"], ROWS).astype(np.float64)
+        y = rng.randint(0, extra["num_class"], rows).astype(np.float64)
     else:
         y = (X[:, 0] + X[:, 1] > 0).astype(np.float64)
-    params = dict({"objective": "binary", "num_leaves": LEAVES,
-                   "max_bin": MAX_BIN, "min_data_in_leaf": 1, "verbose": -1},
+    params = dict({"objective": "binary", "num_leaves": leaves,
+                   "max_bin": max_bin, "min_data_in_leaf": 1, "verbose": -1},
                   **extra)
     g = lgb.Booster(params, lgb.Dataset(X, label=y, params=params))._gbdt._fused
     assert g._hist_method == "radix_pallas_bf16" and g._use_hist_pool
@@ -185,3 +186,60 @@ def test_no_pool_shaped_copy_in_the_v5e_program(case, as_on_tpu, request):
     assert not copies, (
         f"{copies}: the whole pool is copied on every split step; a pool "
         f"write reads the pre-write pool again (fused.py, `lgbm.pool`)")
+
+
+# ------------------------------------------------- the traverse's one kernel
+
+TRAVERSE_KERNEL = 'kernel_name = "traverse_planes_pallas"'
+
+
+def _scoped_names(text):
+    return [nm.split("/") for nm in re.findall(r'loc\("([^"]*)"', text)]
+
+
+@pytest.mark.parametrize("case", ["per_tree_sampled",
+                                  "data_parallel_sampled"])
+def test_sampled_grow_program_traverses_in_one_kernel(case, as_on_tpu,
+                                                      request, monkeypatch):
+    """Under `lgbm.row_traverse` the sampled grow program holds ONE
+    Pallas call, `traverse_planes_pallas`, and no loop: the XLA traverse
+    it replaced is a `while` over the splits whose carry is the [R] leaf
+    ids (and the search below finds that loop where it still runs)."""
+    if case.startswith("data_parallel") and len(jax.devices()) < 4:
+        pytest.skip("needs 4 (virtual) devices to build the grower")
+    g = _grower(CASES[case][0])
+    assert g.row_traverse_method == "pallas"
+    text = _lowered(case, g, request).as_text(debug_info=True)
+    assert text.count(TRAVERSE_KERNEL) == 1
+    assert text.count("call @traverse_planes_pallas(") == 1
+    line, = [ln for ln in text.splitlines() if "call @traverse_planes_pallas("
+             in ln]
+    call_loc = re.search(r"loc\((#loc\d+)\)\s*$", line).group(1)
+    where = re.search(rf'^{call_loc} = loc\("([^"]*)"', text, re.M).group(1)
+    assert "lgbm.row_traverse" in where.split("/"), where
+    loops = [nm for nm in _scoped_names(text)
+             if "lgbm.row_traverse" in nm and "while" in nm]
+    assert not loops, loops[:3]
+
+    monkeypatch.setattr(type(g), "row_traverse_method", "xla")
+    reset_manager()
+    ref = _lowered(case, _grower(CASES[case][0]),
+                   request).as_text(debug_info=True)
+    assert TRAVERSE_KERNEL not in ref
+    assert any("lgbm.row_traverse" in nm and "while" in nm
+               for nm in _scoped_names(ref))
+
+
+@pytest.mark.parametrize("shape, extra", [
+    # the two dense cells' rehearsal shapes (benchmarks/configs)
+    ((20000, 28, 255, 255), {"min_data_in_leaf": 20}),
+    ((6000, 200, 255, 63), {"min_sum_hessian_in_leaf": 100})],
+    ids=["higgs255", "epsilon63"])
+def test_persistent_program_holds_no_traverse_kernel(shape, extra, as_on_tpu,
+                                                     request):
+    """The persistent tier assigns leaves by its partition: its iteration
+    program has the three kernels it had and not the traverse's."""
+    g = _grower(extra, shape)
+    text = _lowered("persistent_f32", g, request).as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert "traverse_planes_pallas" not in text
