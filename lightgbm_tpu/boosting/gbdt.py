@@ -138,26 +138,33 @@ class GBDT:
         self._fused = None
         self._fused_state = None     # persistent planar state (device)
         self._score_dirty = False    # train_score stale vs _fused_state
-        reason = fused_reject_reason(config, train_data, objective)
-        if reason is None:
+        # tree_learner=data is the fused learner over the visible chips:
+        # sharded over the mesh where there are several (the persistent
+        # path when eligible, the per-tree sharded path otherwise:
+        # bagging, multiclass, custom fobj), and on one chip the serial
+        # learner itself
+        data_parallel = config.tree_learner == "data"
+        cfg_fused = config
+        if data_parallel:
+            import copy as _copy
+            cfg_fused = _copy.copy(config)
+            cfg_fused.tree_learner = "serial"
+        reason = fused_reject_reason(cfg_fused, train_data, objective)
+        if reason is None and data_parallel and len(jax.devices()) > 1:
+            from ..treelearner.parallel import FusedDataParallelGrower
+            self._fused = FusedDataParallelGrower(
+                train_data, config, objective)
+        elif reason is None:
             # canonical row bucket (compile/signature.py): pads the
             # planar layout so same-bucket datasets share executables
             from ..compile import bucket_rows
             self._fused = FusedSerialGrower(
-                train_data, config, objective,
+                train_data, cfg_fused, objective,
                 num_rows_bucket=bucket_rows(train_data.num_data))
-        elif config.tree_learner == "data" and len(jax.devices()) > 1:
-            # fused single-dispatch iterations sharded over the device
-            # mesh: the persistent path when eligible, the per-tree
-            # sharded path otherwise (bagging, multiclass, custom fobj)
-            import copy as _copy
-            cfg_serial = _copy.copy(config)
-            cfg_serial.tree_learner = "serial"
-            reason = fused_reject_reason(cfg_serial, train_data, objective)
-            if reason is None:
-                from ..treelearner.parallel import FusedDataParallelGrower
-                self._fused = FusedDataParallelGrower(
-                    train_data, config, objective)
+        if config.tree_learner != "serial" \
+                and type(self.tree_learner) is SerialTreeGrower \
+                and not getattr(self._fused, "is_multichip", False):
+            log.warning("Only one machine/chip: using serial tree learner")
         if self._fused is None and jax.default_backend() == "tpu" \
                 and reason not in (None, "tpu_fused=false") \
                 and config.tree_learner in ("serial", "data"):
@@ -237,6 +244,13 @@ class GBDT:
             # what assigns every row its leaf once rows are left out
             plan["row_traverse"] = getattr(self._fused,
                                            "row_traverse_method", "xla")
+        if self._fused is not None:
+            # where the [code_planes, lanes] planes are packed ("host":
+            # no device program, nothing to compile), and on a mesh the
+            # rows each chip owns
+            plan["codes_pack"] = self._fused.codes_pack
+            if self._fused.is_multichip:
+                plan["shard_rows"] = self._fused.shard_rows
         return plan
 
     def _sampling_plan(self) -> Optional[str]:
@@ -256,7 +270,7 @@ class GBDT:
         if config.tree_learner in ("serial", "feature", "data", "voting"):
             if config.tree_learner != "serial" and config.num_machines <= 1 \
                     and not config.tpu_mesh_shape:
-                log.warning("Only one machine/chip: using serial tree learner")
+                # init() says so where no sharded fused grower takes over
                 return SerialTreeGrower(train_data, config)
             if config.tree_learner == "serial":
                 return SerialTreeGrower(train_data, config)

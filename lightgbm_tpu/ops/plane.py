@@ -51,6 +51,7 @@ oracles for the kernels.
 from __future__ import annotations
 
 import functools
+from concurrent.futures import ThreadPoolExecutor
 from typing import NamedTuple, Optional
 
 import numpy as np
@@ -176,80 +177,58 @@ def i32_as_f32(x):
     return jax.lax.bitcast_convert_type(x, jnp.float32)
 
 
-def _pack_codes(codes: jax.Array, layout: PlaneLayout,
-                lanes: int) -> jax.Array:
-    """[n, G] u8/u16 bin codes -> [code_planes, lanes] i32 (little-
-    endian packing: column j occupies bits [j*bits % 32, ...) of plane
-    j*bits // 32; 4-bit mode packs two columns per byte)."""
+PACK_BLOCK_BYTES = 1 << 22   # host bytes one pack thread transposes at a time
+PACK_THREADS = 8
+
+
+def pack_codes_host(codes: np.ndarray, layout: PlaneLayout,
+                    lanes: Optional[int] = None) -> np.ndarray:
+    """[n, G] u8/u16 bin codes -> [code_planes, lanes] i32 ON THE HOST
+    (little-endian packing: column j occupies bits [j*bits % 32, ...) of
+    plane j*bits // 32; 4-bit mode packs two columns per byte). A row's
+    code bytes padded to ``code_planes * 4`` ARE its little-endian int32
+    words, so the pack is a view and one transposed copy — no device
+    program, whose compile grew with the row count (14.7 s a million
+    rows on XLA:TPU for the eager reshape -> bitcast -> transpose this
+    replaces). Rows are transposed in cache-sized blocks on a few
+    threads (numpy releases the GIL in the copy); lanes past ``n`` stay
+    zero."""
+    codes = np.asarray(codes)
     n, g = codes.shape
     bits = layout.code_bits
-    if bits == 4:
-        c = codes.astype(jnp.uint8)
-        if g % 2:
-            c = jnp.pad(c, ((0, 0), (0, 1)))
-        b = (c[:, 0::2] & 15) | (c[:, 1::2] << 4)
-    elif bits == 8:
-        b = codes.astype(jnp.uint8)
-    else:
-        # tpulint: tile-ok(deliberate 16b->8b split: each u16 code becomes two little-endian byte planes of the packed-plane layout)
-        b = jax.lax.bitcast_convert_type(
-            codes.astype(jnp.uint16), jnp.uint8).reshape(n, g * 2)
+    lanes = layout.num_lanes if lanes is None else lanes
     width = layout.code_planes * 4
-    if b.shape[1] < width:
-        b = jnp.pad(b, ((0, 0), (0, width - b.shape[1])))
-    if n < lanes:
-        b = jnp.pad(b, ((0, lanes - n), (0, 0)))
-    # [lanes, C, 4] -> bitcast i32 [lanes, C] -> transpose [C, lanes]
-    planes = jax.lax.bitcast_convert_type(
-        b.reshape(lanes, layout.code_planes, 4), jnp.int32)
-    return planes.T
+    out = np.empty((layout.code_planes, lanes), np.int32)
+    out[:, n:] = 0
+    block = max(LANE, PACK_BLOCK_BYTES // width // LANE * LANE)
 
+    def one(lo: int) -> None:
+        c = codes[lo:lo + block]
+        if bits == 8 and g == width and c.dtype == np.uint8 \
+                and c.flags.c_contiguous:
+            b = c               # whole words already: nothing to pad
+        else:
+            b = np.zeros((c.shape[0], width), np.uint8)
+            if bits == 4:
+                c = c.astype(np.uint8)
+                b[:, :(g + 1) // 2] = c[:, 0::2] & 15
+                b[:, :g // 2] |= c[:, 1::2] << 4
+            elif bits == 8:
+                b[:, :g] = c
+            else:
+                b[:, :2 * g] = c.astype("<u2").view(np.uint8)
+        out[:, lo:lo + c.shape[0]] = b.view("<i4").T
 
-def build_codes_planes(codes: jax.Array, layout: PlaneLayout) -> jax.Array:
-    """[n, G] u8/u16 bin codes -> [code_planes, R] i32."""
-    return _pack_codes(codes, layout, layout.num_lanes)
-
-
-def build_codes_planes_chunked(codes_host, layout: PlaneLayout,
-                               row_chunk: Optional[int] = None,
-                               chunk_bytes: int = 1 << 29) -> jax.Array:
-    """Pack HOST-resident bin codes into the planar layout in row
-    chunks, so the transient row-major device upload is bounded by
-    ``chunk_bytes`` instead of the full [N, G] matrix — at the Allstate
-    shape (13.2M x 581 bundles) a one-shot upload is 7.7 GB sitting
-    next to the 4.3 GB planar state and OOMs HBM before the async free
-    lands. The chunk is derived from BYTES, not rows, so wide datasets
-    with few rows are bounded the same way."""
-    n = codes_host.shape[0]
-    if row_chunk is None:
-        row_bytes = max(1, int(codes_host.shape[1])
-                        * np.dtype(codes_host.dtype).itemsize)
-        row_chunk = max(1 << 16, chunk_bytes // row_bytes)
-    if n <= row_chunk:
-        return build_codes_planes(jnp.asarray(codes_host), layout)
-    out = jnp.zeros((layout.code_planes, layout.num_lanes), jnp.int32)
-    # tpulint: jit-ok(one-time dataset binning at setup)
-    pack = jax.jit(functools.partial(_pack_codes, layout=layout,
-                                     lanes=row_chunk),
-                   static_argnames=())
-    # tpulint: jit-ok(one-time dataset binning at setup)
-    upd = jax.jit(lambda o, p, pos: jax.lax.dynamic_update_slice(
-        o, p, (0, pos)), donate_argnums=0)
-    pos = 0
-    while pos < n:
-        c = min(row_chunk, n - pos)
-        # dynamic_update_slice clamps out-of-range starts, so the final
-        # window is shifted LEFT to end inside the lane buffer —
-        # re-writing a prefix of already-written rows with identical
-        # values rather than letting the clamp misplace the chunk
-        start = min(pos, layout.num_lanes - row_chunk)
-        take = min(start + row_chunk, n) - start
-        chunk = np.asarray(codes_host[start:start + take])
-        if take < row_chunk:
-            chunk = np.pad(chunk, ((0, row_chunk - take), (0, 0)))
-        out = upd(out, pack(jnp.asarray(chunk)), jnp.int32(start))
-        pos += c
+    with ThreadPoolExecutor(PACK_THREADS) as pool:
+        list(pool.map(one, range(0, n, block)))
     return out
+
+
+def build_codes_planes(codes, layout: PlaneLayout, device=None) -> jax.Array:
+    """[n, G] u8/u16 bin codes -> [code_planes, R] i32 on ``device`` (the
+    default device when None): packed on the host, uploaded in its final
+    form, so the device never holds a row-major copy beside it."""
+    return jax.device_put(pack_codes_host(codes, layout), device)
 
 
 def build_data(layout: PlaneLayout, codes_planes: jax.Array,

@@ -200,12 +200,9 @@ class FusedSerialGrower:
     """Builds and owns the single-dispatch training-iteration program."""
 
     is_multichip = False
-
-    @property
-    def bins(self):
-        if self._bins_dev is None:
-            self._bins_dev = self.dataset.device_bins()
-        return self._bins_dev
+    # the code planes are packed on the host (plane.pack_codes_host) and
+    # uploaded in their final form; execution_plan() says so
+    codes_pack = "host"
 
     def __init__(self, dataset: BinnedDataset, config: Config,
                  objective=None, num_rows_override=None,
@@ -214,13 +211,12 @@ class FusedSerialGrower:
         self._num_rows_override = num_rows_override
         self.config = config
         self.objective = objective
-        # HBM budgeting: the row-major bin matrix is only needed to
-        # rebuild a checkpointed persistent state — upload it LAZILY so
-        # no training loop holds [N, G] u8 in HBM next to the planar
-        # state (13.2M x 500 groups = 6.6 GB; at G < 128 the TPU pads
-        # every row to a 128-lane tile). Row sampling gathers and
-        # traverses the resident code planes instead (_grow_tree)
-        self._bins_dev = None
+        # HBM budgeting: the row-major bin matrix never goes to the
+        # device (13.2M x 500 groups = 6.6 GB; at G < 128 the TPU pads
+        # every row to a 128-lane tile): the code planes are packed on
+        # the host (plane.pack_codes_host) and uploaded in their final
+        # form. Row sampling gathers and traverses the resident code
+        # planes instead (_grow_tree)
         self.num_features = dataset.num_features
         mappers = dataset.bin_mappers
         self.max_num_bin = max((m.num_bin for m in mappers), default=2)
@@ -516,28 +512,14 @@ class FusedSerialGrower:
     # ------------------------------------------------------------------
     def codes_planes(self) -> jax.Array:
         if self._codes_planes_dev is None:
-            # upload and pack are asynchronous: the stage is closed by
-            # one block
+            # host pack + one upload: no device program, so nothing of
+            # this stage compiles; the stage is closed by one block
             with span("fused/pack_codes", stage="state/pack_codes"):
                 # tpulint: sync-ok(set-up, once per state build, ahead of a compile-paying call that blocks anyway)
                 self._codes_planes_dev = jax.block_until_ready(
-                    self._build_codes_planes())
+                    plane.build_codes_planes(self.dataset.bins,
+                                             self.layout))
         return self._codes_planes_dev
-
-    def _build_codes_planes(self) -> jax.Array:
-        if self._bins_dev is not None:
-            return plane.build_codes_planes(self._bins_dev, self.layout)
-        if self.dataset.bins.nbytes > (1 << 31):
-            # chunked host->device packing: a one-shot row-major
-            # upload at wide-EFB scale (13.2M x 581 = 7.7 GB u8)
-            # OOMs HBM next to the planar state before the async
-            # free lands
-            return plane.build_codes_planes_chunked(
-                self.dataset.bins, self.layout)
-        # transient row-major upload; the persistent path never
-        # needs the row-major copy again
-        return plane.build_codes_planes(
-            jnp.asarray(self.dataset.bins), self.layout)
 
     # -- AOT compile manager integration -------------------------------
     def _tables(self) -> Dict:
@@ -675,7 +657,12 @@ class FusedSerialGrower:
             else:
                 self._iter_entry.add_spec(
                     (t_avals, data_aval, mask_aval, f32s, f32s, i32s))
-            self._sync_entry.add_spec((data_aval,))
+            # fused/sync_scores is NOT warmed up: no iteration needs it,
+            # and its [n]-sized scatter compiles for tens of seconds at
+            # 21M rows — in the background that lands inside the first
+            # iterations of a cold process (a benchmark's measured
+            # window) now that no device pack hides it. It is built when
+            # a host consumer first asks for the scores.
         elif self._score_from_partition:
             n = self.actual_rows
             cp_aval = aval((Ly.code_planes, Ly.num_lanes), jnp.int32)
@@ -1828,7 +1815,10 @@ class FusedSerialGrower:
         rid = jnp.asarray(np.asarray(rowid_lanes, np.int32))
         rid_n = rid[:n]
         aux_label, aux_weight = self.objective.persistent_aux()
-        cp = plane.build_codes_planes(self.bins[rid_n], Ly)
+        # gathered and packed on the host, like the first build
+        cp = plane.build_codes_planes(
+            np.asarray(self.dataset.bins)[np.asarray(rowid_lanes,
+                                                     np.int32)[:n]], Ly)
         lab = jnp.asarray(aux_label, jnp.float32)[rid_n]
         wgt = None if aux_weight is None \
             else jnp.asarray(aux_weight, jnp.float32)[rid_n]

@@ -675,13 +675,19 @@ class FusedDataParallelGrower(FusedSerialGrower):
         self._n_per_shard = jax.device_put(
             jnp.asarray(counts, jnp.int32),
             NamedSharding(self.mesh, P("data")))
+        self._iter_mc_entry = None
         self._iter_mc_jit = None
         self._grow_mc_tree_jit = None
-        # per-tree ICI estimate: one [F, B, 2] f32 child-histogram psum
-        # per split, num_leaves - 1 splits per tree
-        self._tree_psum_bytes = ((config.num_leaves - 1)
-                                 * self.num_features * self.max_num_bin
-                                 * 2 * 4)
+        # per-tree ICI estimate for the host-side collective accounting
+        # (benchmarks/harness/work_dp.py's formula at a full tree): the
+        # root's and each split's smaller child's [F, B, 2] f32
+        # histogram plus its i32 count, which a ring allreduce over D
+        # chips carries 2 (D - 1) / D times over every chip's links
+        D = self.num_shards
+        self._tree_psum_bytes = (
+            self.num_leaves
+            * (self.num_features * self.max_num_bin * 2 * 4 + 4)
+            * 2 * (D - 1) // D)
 
     def _mc_signature(self):
         """(sig, shareable) for the top-level shard_map entries. The
@@ -702,22 +708,21 @@ class FusedDataParallelGrower(FusedSerialGrower):
     def _pack_codes_per_device(self, sharding, shape):
         """([(device, shard)], [shard's [code_planes, R] codes on its
         device]) for a lane-sharded array of ``shape``: every shard is
-        packed ON the device that owns it from a host slice of the bin
-        matrix, so no device ever holds more than its own share."""
+        packed on the HOST from its slice of the bin matrix and uploaded
+        to the device that owns it in its final form, so no device ever
+        holds more than its own share and no device program is built."""
         from ..ops import plane
         sr, Ly = self.shard_rows, self.layout
         bins = np.asarray(self.dataset.bins)
         owned = [(dev, idx[1].start // Ly.num_lanes) for dev, idx in
                  sharding.addressable_devices_indices_map(shape).items()]
-        # every device's pack is enqueued before the one block, so the
-        # chips pack side by side and the stage reads their longest
+        # uploads are asynchronous: shard d+1 is packed while shard d is
+        # on its way, and the stage is closed by the one block
         with span("fused/pack_codes", stage="state/pack_codes"):
-            packed = []
-            for dev, d in owned:
-                with jax.default_device(dev):
-                    packed.append(plane.build_codes_planes(
-                        jnp.asarray(bins[d * sr:(d + 1) * sr]), Ly))
-            # tpulint: sync-ok(set-up, once per state build, after every device's pack is enqueued)
+            packed = [plane.build_codes_planes(bins[d * sr:(d + 1) * sr],
+                                               Ly, device=dev)
+                      for dev, d in owned]
+            # tpulint: sync-ok(set-up, once per state build, after every device's upload is enqueued)
             jax.block_until_ready(packed)
         return owned, packed
 
@@ -774,26 +779,32 @@ class FusedDataParallelGrower(FusedSerialGrower):
     def train_iter_persistent(self, data, shrinkage, bias):
         quant = self._quant
         if self._iter_mc_jit is None:
+            # the shard's body carries the serial entry's name, so the
+            # XLA module (and every op's scope path) reads
+            # `_entry_train_iter` on one chip and on four
             if quant:
-                def body(data_l, nvalid_l, mask_, shr, b, key):
+                def _entry_train_iter(data_l, nvalid_l, mask_, shr, b, key):
                     return self._train_iter(data_l, mask_, shr, b,
                                             n_valid=nvalid_l[0], key=key)
                 in_specs = (P(None, "data"), P("data"), P(), P(), P(), P())
             else:
-                def body(data_l, nvalid_l, mask_, shr, b):
+                def _entry_train_iter(data_l, nvalid_l, mask_, shr, b):
                     return self._train_iter(data_l, mask_, shr, b,
                                             n_valid=nvalid_l[0])
                 in_specs = (P(None, "data"), P("data"), P(), P(), P())
             f = functools.partial(
                 shard_map, mesh=self.mesh, check_vma=False,
                 in_specs=in_specs,
-                out_specs=(P(None, "data"), P()))(body)
+                out_specs=(P(None, "data"), P()))(_entry_train_iter)
             from ..compile import get_manager
             sig, ok = self._mc_signature()
-            self._iter_mc_jit = get_manager().shared_entry(
+            self._iter_mc_entry = get_manager().shared_entry(
                 "mc/train_iter", sig,
                 lambda: jax.jit(f, donate_argnums=0),  # tpulint: jit-ok(inside a shared_entry builder; the manager dispatches this jit)
                 donate_argnums=(0,), store=ok, profiled=True)
+            # the span of the serial dispatch: one name for one role
+            self._iter_mc_jit = instrument_kernel(
+                self._iter_mc_entry, "fused", name="fused/train_iter")
         args = (data, self._n_per_shard, self.feature_masks_for_tree(),
                 jnp.float32(shrinkage), jnp.float32(bias))
         if quant:
@@ -803,18 +814,30 @@ class FusedDataParallelGrower(FusedSerialGrower):
             return self._iter_mc_jit(*args)
 
     def _sync_scores(self, data):
+        """[n] f32 raw scores in row order: every shard scatters its own
+        rows into its own [shard_rows] slice, and one all-gather lines
+        the slices up (shard d owns rows [d*sr, (d+1)*sr)), where an
+        [n]-sized scatter and psum on every chip moved 2x the bytes and
+        held two [n] arrays a chip. The collective sits under a scope of
+        its own segment so that a profile keeps a host consumer's sync
+        apart from the iteration's allreduces."""
         from ..ops import plane
         Ly = self.layout
-        n = self.global_rows
+        n, sr = self.global_rows, self.shard_rows
 
         def body(data_l):
-            rowids = data_l[Ly.rowid]
-            score = plane.get_f32(data_l, Ly.score)
-            out = jnp.zeros(n, jnp.float32).at[rowids].set(
-                score, mode="drop", unique_indices=True)
-            return jax.lax.psum(out, "data")
+            with jax.named_scope("lgbm.score_sync"):
+                rowids = data_l[Ly.rowid]
+                local = rowids - jax.lax.axis_index("data") * sr
+                # pad lanes carry row id n: out of every shard's slice
+                local = jnp.where(rowids >= n, sr, local)
+                out = jnp.zeros(sr, jnp.float32).at[local].set(
+                    plane.get_f32(data_l, Ly.score), mode="drop",
+                    unique_indices=True)
+                with jax.named_scope("lgbm.allreduce"):
+                    return jax.lax.all_gather(out, "data", tiled=True)[:n]
 
-        with collective_span("scores_psum", n * 4, axis="data"):
+        with collective_span("scores_allgather", n * 4, axis="data"):
             return functools.partial(
                 shard_map, mesh=self.mesh, check_vma=False,
                 in_specs=(P(None, "data"),), out_specs=P())(body)(data)
@@ -905,7 +928,9 @@ class FusedDataParallelGrower(FusedSerialGrower):
             return jax.device_put(v.reshape(D, sr), spec_rows)
 
         if self._grow_mc_tree_jit is None:
-            self._grow_mc_tree_jit = self._grow_mc_jit_build()
+            # the span of the serial per-tree dispatch
+            self._grow_mc_tree_jit = instrument_kernel(
+                self._grow_mc_jit_build(), "fused", name="fused/grow_tree")
         with collective_span("fused_tree_psum", self._tree_psum_bytes,
                              axis="data"):
             ta, leaf = self._grow_mc_tree_jit(

@@ -483,3 +483,18 @@ def test_bench_sidecar_record_schema():
            "rows": 1048576, "iters": 20, "note": "extrapolated"}
     assert obs.validate_bench_record(rec) == []
     assert obs.validate_bench_record(json.loads(json.dumps(rec))) == []
+
+
+def test_only_what_an_iteration_dispatches_is_warmed_up(aot_env):
+    """Background warm-up runs beside the first iterations, so it holds
+    only the program they dispatch: a `fused/sync_scores` spec would be
+    compiled (tens of seconds at 21M rows) inside the first iterations of
+    a cold process, and no iteration needs it."""
+    rng = np.random.RandomState(3)
+    X = rng.randn(600, 5).astype(np.float32)
+    y = (X[:, 0] > 0).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": 7, "verbose": -1}
+    g = lgb.Booster(params, lgb.Dataset(X, label=y))._gbdt._fused
+    assert g.persistent_capable
+    assert len(g._iter_entry.specs) == 1
+    assert g._sync_entry.specs == [] and g._grow_entry.specs == []

@@ -106,7 +106,7 @@ def test_fused_dp_sampled_rows_match_serial():
     assert b_dp._gbdt.bag_data_cnt == 400 + 200
     assert g._cp_sh.shape == (g.layout.code_planes,
                               g.num_shards * g.layout.num_lanes)
-    assert g._bins_dev is None and not hasattr(g, "_bins_sh")
+    assert not hasattr(g, "_bins_dev") and not hasattr(g, "_bins_sh")
     p1, p2 = b_serial.predict(X), b_dp.predict(X)
     assert float(np.mean(np.abs(p1 - p2))) < 1e-4
 
@@ -210,3 +210,114 @@ def test_fused_dp_scores_sync():
     raw = np.asarray(b._gbdt.get_training_score())[0]
     pred_raw = b.predict(X, raw_score=True)
     np.testing.assert_allclose(raw, pred_raw, rtol=1e-3, atol=1e-4)
+
+
+# --------------------------------------------------------------------------
+# Four shards against the serial learner and the numpy reference where the
+# arithmetic is exact: with boost_from_average=false the first tree's
+# gradients are -label (regression, whole-number labels) or +-0.5 with a
+# hessian of 0.25 (binary), so every float32 sum is exact in any order and
+# the sharded learner owes the serial one the SAME tree, byte for byte.
+
+def _exact_case(objective, n, order):
+    rng = np.random.RandomState(5)
+    X = rng.randn(n, 6).astype(np.float32)
+    if order == "by_feature":
+        # contiguous shards then own disjoint ranges of column 0, so some
+        # shard owns no row of some leaf
+        X = X[np.argsort(X[:, 0], kind="stable")]
+    z = 2.0 * X[:, 0] + X[:, 1] ** 2 + 0.3 * rng.randn(n)
+    y = (z > 0.5).astype(np.float32) if objective == "binary" \
+        else np.round(z).astype(np.float32)
+    params = {"objective": objective, "boost_from_average": False,
+              "num_leaves": 15, "max_bin": 63, "min_data_in_leaf": 5,
+              "verbose": -1}
+    return X, y, params
+
+
+def _trees_text(bst):
+    return bst.model_to_string().split("\nparameters:")[0]
+
+
+@pytest.mark.parametrize("objective,n,order", [
+    ("regression", 6000, "drawn"),
+    ("regression", 6001, "drawn"),         # rows not divisible by 4
+    ("regression", 6001, "by_feature"),    # a shard without a leaf's rows
+    ("binary", 6001, "drawn"),
+    ("binary", 5999, "by_feature"),
+])
+def test_four_shards_grow_the_serial_tree_byte_for_byte(objective, n, order):
+    X, y, params = _exact_case(objective, n, order)
+    b_serial = _train(dict(params, tree_learner="serial"), X, y, rounds=1)
+    b_dp = _train(dict(params, tree_learner="data", tpu_mesh_shape=[4]),
+                  X, y, rounds=1)
+    g = b_dp._gbdt._fused
+    from lightgbm_tpu.treelearner.parallel import FusedDataParallelGrower
+    assert isinstance(g, FusedDataParallelGrower) and g.num_shards == 4
+    assert b_dp._gbdt._fused_persist
+    assert _trees_text(b_dp) == _trees_text(b_serial)
+    assert "num_leaves=15" in _trees_text(b_dp)
+
+
+@pytest.mark.parametrize("leaf", ["root", "left_child"])
+def test_the_shards_local_histograms_add_up_to_the_whole_tables(leaf):
+    """The share adds up: what each of four shards histograms of its own
+    rows sums to the numpy reference's histogram of the whole table, each
+    row counted once (the hessian channel of an L2 objective counts rows),
+    with rows not divisible by 4 and, for the left child, a shard that
+    owns no row of it and one that owns nothing else."""
+    import jax.numpy as jnp
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+    from benchmarks.reference import gbdt_numpy as ref
+    X, y, params = _exact_case("regression", 6001, "by_feature")
+    bst = _train(dict(params, num_leaves=2, tree_learner="data",
+                      tpu_mesh_shape=[4]), X, y, rounds=1)
+    gb = bst._gbdt
+    g, n, sr = gb._fused, len(y), gb._fused.shard_rows
+    tree, = ref.parse_model(bst.model_to_string())
+    in_leaf = np.ones(n, bool) if leaf == "root" \
+        else ref.leaf_of(tree, X) == 0
+    # a shard's rows of the leaf sit at the head of its lanes: the root
+    # split's left rows are partitioned to the front
+    counts = np.asarray([in_leaf[d * sr:(d + 1) * sr].sum()
+                         for d in range(4)], np.int32)
+    assert counts.sum() == in_leaf.sum() and counts[-1] < sr
+    if leaf == "left_child":
+        assert counts.min() == 0 and counts.max() == sr
+
+    def body(data_l, count_l):
+        return g._leaf_hist_switch(data_l, jnp.int32(0), count_l[0])[None]
+
+    local = np.asarray(jax.jit(shard_map(
+        body, mesh=g.mesh, in_specs=(P(None, "data"), P("data")),
+        out_specs=P("data"), check_vma=False))(
+            gb._fused_state, jnp.asarray(counts)))
+    assert local.shape[0] == 4
+    bins = np.asarray(gb.train_data.bins)
+    for f in range(bins.shape[1]):
+        want = ref.histogram(bins[in_leaf, f], -y[in_leaf].astype(np.float64),
+                             np.ones(int(in_leaf.sum())), local.shape[2])
+        np.testing.assert_array_equal(local[:, f, :, 0].sum(0), want[:, 0])
+        np.testing.assert_array_equal(local[:, f, :, 1].sum(0), want[:, 2])
+    # and every row is in exactly one shard's histogram
+    np.testing.assert_array_equal(local[:, 0, :, 1].sum(1), counts)
+
+
+def test_data_parallel_on_one_visible_chip_is_the_serial_fused_learner(
+        monkeypatch):
+    """`tree_learner=data` shards over the chips a process sees; where it
+    sees one, that is the serial learner on the fused tier and not the
+    host loop (the one-device rehearsal of the four-chip cell runs so)."""
+    from lightgbm_tpu.boosting import gbdt as gbdt_mod
+    from lightgbm_tpu.treelearner.fused import FusedSerialGrower
+    X, y, params = _exact_case("binary", 2000, "drawn")
+    b_serial = _train(dict(params, tree_learner="serial"), X, y, rounds=3)
+    one = jax.devices()[:1]
+    monkeypatch.setattr(gbdt_mod.jax, "devices", lambda *a: one)
+    b_data = _train(dict(params, tree_learner="data"), X, y, rounds=3)
+    monkeypatch.undo()
+    plan = b_data._gbdt.execution_plan()
+    assert type(b_data._gbdt._fused) is FusedSerialGrower
+    assert plan["tier"] == "persistent-fused" and "shard_rows" not in plan
+    assert _trees_text(b_data) == _trees_text(b_serial)

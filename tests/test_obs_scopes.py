@@ -66,7 +66,7 @@ def _iteration_program_text(config: str) -> str:
         if config == "data_parallel":
             if len(jax.devices()) < 8:
                 pytest.skip("needs 8 (virtual) devices")
-            lowered = g._iter_mc_jit.jit_fn().lower(
+            lowered = g._iter_mc_entry.jit_fn().lower(
                 gbdt._fused_state, g._n_per_shard, *common)
         else:
             lowered = jax.jit(g._entry_train_iter).lower(
@@ -104,8 +104,65 @@ def _host_events(trace_dir, prefix="lgbm:"):
     return out
 
 
-def test_updates_show_as_nested_spans_under_anyones_profiler(tmp_path):
-    bst = _booster(PARAMS["binary"])
+def test_every_collective_of_the_sharded_iteration_is_under_allreduce():
+    """`lgbm.allreduce` is the one collective scope: every all_reduce of
+    the lowered data-parallel iteration carries it in its location, so a
+    reader that sums the ops under the scope misses none."""
+    text = _iteration_program_text("data_parallel")
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    ops = re.findall(r'"stablehlo\.(all_reduce|all_gather|reduce_scatter|'
+                     r'all_to_all|collective_permute)"\(.*?\n\s*\}\) :'
+                     r'[^\n]* loc\((#loc\d+)\)', text, re.S)
+    ops += re.findall(r'"stablehlo\.(all_gather|all_to_all|'
+                      r'collective_permute)"\([^\n]* loc\((#loc\d+)\)$',
+                      text, re.M)
+    assert len(ops) >= 4        # root histogram + count, split histogram + count
+    for kind, loc in ops:
+        assert "lgbm.allreduce" in locs[loc].split("/"), (kind, locs[loc])
+
+
+def test_the_score_sync_keeps_its_collective_apart_from_the_iterations():
+    if len(jax.devices()) < 8:
+        pytest.skip("needs 8 (virtual) devices")
+    gbdt = _booster(PARAMS["data_parallel"])._gbdt
+    text = jax.jit(gbdt._fused._sync_scores).lower(
+        gbdt._fused_state).as_text(debug_info=True)
+    named = re.findall(r'loc\("([^"]*all_gather[^"]*)"', text)
+    assert named and all(
+        n.split("/")[-3:-1] == ["lgbm.score_sync", "lgbm.allreduce"]
+        for n in named), named
+    assert "all_reduce" not in text
+
+
+def test_execution_plan_says_what_a_sharded_run_is():
+    if len(jax.devices()) < 8:
+        pytest.skip("needs 8 (virtual) devices")
+    plan = _booster(PARAMS["data_parallel"])._gbdt.execution_plan()
+    assert plan["learner"] == "FusedDataParallelGrower"
+    assert plan["tier"] == "persistent-fused" and plan["device_count"] == 8
+    assert plan["shard_rows"] == -(-3000 // 8)
+    assert plan["codes_pack"] == "host"
+    serial = _booster(PARAMS["binary"])._gbdt.execution_plan()
+    assert serial["codes_pack"] == "host" and "shard_rows" not in serial
+
+
+def test_collective_accounting_is_the_work_functions_bytes():
+    """The host-side estimate of a sharded iteration's allreduce traffic
+    is benchmarks/harness/work_dp.py's formula at a full tree."""
+    if len(jax.devices()) < 8:
+        pytest.skip("needs 8 (virtual) devices")
+    from benchmarks.harness import work_dp
+    g = _booster(PARAMS["data_parallel"])._gbdt._fused
+    assert g._tree_psum_bytes == int(work_dp.allreduce_bytes(
+        [g.num_leaves - 1], g.num_features, g.max_num_bin - 1, g.num_shards))
+
+
+@pytest.mark.parametrize("config", ["binary", "data_parallel"])
+def test_updates_show_as_nested_spans_under_anyones_profiler(tmp_path,
+                                                             config):
+    if config == "data_parallel" and len(jax.devices()) < 8:
+        pytest.skip("needs 8 (virtual) devices")
+    bst = _booster(PARAMS[config])
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     jax.profiler.start_trace(str(tmp_path), profiler_options=options)

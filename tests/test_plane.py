@@ -149,20 +149,21 @@ def test_gh_update():
     np.testing.assert_array_equal(np.asarray(c2)[:len(codes)], codes)
 
 
-def test_build_codes_planes_chunked_matches_oneshot():
-    """Chunked host->device packing (bounded transient for wide-EFB
-    HBM budgets) must produce bit-identical planes to the one-shot
-    path, including the shifted final window."""
-    import jax.numpy as jnp
+def test_host_pack_in_blocks_matches_one_block(monkeypatch):
+    """The host pack transposes the rows in cache-sized blocks on a few
+    threads; whatever the block, the planes are the one-block planes,
+    the short last block included."""
     rng = np.random.RandomState(9)
-    for n, g, bits, chunk in [(5000, 11, 8, 1024), (3000, 9, 4, 999),
-                              (2048, 3, 16, 2048)]:
+    for n, g, bits, block_bytes in [(5000, 11, 8, 1 << 13),
+                                    (3000, 9, 4, 1 << 11),
+                                    (2048, 3, 16, 1 << 12)]:
         codes = rng.randint(0, 16 if bits == 4 else 200,
                             size=(n, g)).astype(np.uint16 if bits == 16
                                                 else np.uint8)
         layout = plane.make_layout(g, bits, n, tile=512)
-        want = np.asarray(plane.build_codes_planes(jnp.asarray(codes),
-                                                   layout))
-        got = np.asarray(plane.build_codes_planes_chunked(
-            codes, layout, row_chunk=chunk))
-        np.testing.assert_array_equal(got, want)
+        want = plane.pack_codes_host(codes, layout)
+        monkeypatch.setattr(plane, "PACK_BLOCK_BYTES", block_bytes)
+        assert n > block_bytes // (layout.code_planes * 4)
+        np.testing.assert_array_equal(plane.pack_codes_host(codes, layout),
+                                      want)
+        monkeypatch.undo()

@@ -122,7 +122,12 @@ class TestWatchdog:
     def test_deadman_trips_between_heartbeats(self):
         wd = Watchdog(0.08, poll_s=0.02).start()
         try:
-            time.sleep(0.25)
+            # no heartbeat: the deadman thread trips on its own; under a
+            # loaded machine (six test workers) it can be scheduled late,
+            # so wait for the trip and not for a fixed quarter second
+            deadline = time.monotonic() + 10.0
+            while wd.tripped is None and time.monotonic() < deadline:
+                time.sleep(0.02)
             with pytest.raises(HangTimeout) as ei:
                 wd.check()
             d = ei.value.diagnosis
