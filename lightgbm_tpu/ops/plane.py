@@ -23,12 +23,17 @@ registers, so no primitive ever pays a per-row toll:
   compacts its kept lanes in-register by ONE plan for all the streams
   it feeds (`_compact_streams`): the streams' keep rows stacked in the
   sublanes of one array, one prefix sum for their ranks, then an
-  LSB-first binary shift network — log2(S) rounds of `pltpu.roll` +
-  select in which the stacked shifts are rolled, masked and selected
-  once for all streams and never decremented (tests/test_kernels.py
-  holds it to numpy's stable partition, exhaustively at 16 lanes). The
-  tile then prepends the <128-lane carry from the previous step and
-  DMAs a fixed `[P, S+128]` chunk to a 128-aligned offset.
+  LSB-first binary shift network — log2(S + 128) rounds of
+  `pltpu.roll` + select in which the stacked shifts are rolled, masked
+  and selected once for all streams and never decremented
+  (tests/test_kernels.py holds it to numpy's stable partition,
+  exhaustively at 16 lanes). The length c < 128 of the carry the
+  previous step left over rides in the shifts, behind a static
+  128-lane lead, so the kept lanes come out of the network at lanes
+  [c, c + k) of a `[P, S+128]` chunk with no roll after it;
+  `_emit_stream` selects the carry's lanes into the first column,
+  stages the chunk once and DMAs it to a 128-aligned offset, and
+  reads the next carry as one aligned column of what it staged.
   Consecutive chunks overlap by design (the garbage tail of chunk k
   is rewritten as the carry head of chunk k+1), so writes are
   serialized DMA k.wait -> DMA k+1.start while compute overlaps.
@@ -81,9 +86,10 @@ def partition_vmem_bytes_at(P: int, S: int, method: str = "pallas2") -> int:
     planes) can exceed the 16 MB scoped limit. Widths are CALIBRATED to
     compiler-reported scoped allocations (Mosaic multi-buffers the
     pipeline block on top of the declared scratch): at P=152, S=4096
-    the compiler reports 21.97 MB for v2 and 18.25 MB for v1 (re-read
-    in PR 28, compiled for a described v5e) — ~8.8*S and ~7.3*S
-    lane-widths; a margin is added on both."""
+    the compiler reports 21.97 MB for v2 (~8.8*S lane-widths; re-read
+    in PR 32, compiled for a described v5e) and at P=200, S=4096
+    19.51 MB for v1 (~6*S; it fits the limit at P=152 since PR 32,
+    where it read 18.25 MB before); a margin is added on both."""
     width = 16 * S if method == "pallas2" else 8 * S
     return P * width * 4
 
@@ -449,47 +455,107 @@ def _lane_prefix(x, roll):
     return x
 
 
-def _compact_streams(x, keeps, roll=None):
+def _compact_streams(x, keeps, carries, roll=None):
     """In-tile stable compaction of the [P, S] tile ``x``, ONE plan for
     all of ``keeps`` (K <= 8 rows of [1, S] i32 0/1, one per output
-    stream). Returns (K compacted [P, S] copies, K kept counts): copy j
-    holds the lanes with ``keeps[j] == 1`` in their order, in its lanes
-    [0, count j); the lanes past that are garbage.
+    stream), each stream placed at its carry offset ``carries[j]`` (an
+    i32 scalar in [0, LANE)). Returns (K compacted [P, S + LANE] copies,
+    K kept counts): copy j holds the lanes with ``keeps[j] == 1`` in
+    their order, in its lanes [carries[j], carries[j] + count j); every
+    other lane is garbage.
 
     The K keep rows are stacked in the sublanes of one [8, S] array (row
     j by sublane iota + select; a [1, S] i32 row costs the vregs of an
-    [8, S] one, so the stack is free), which gets ONE prefix sum. Lane
-    i of stream j has to move down by shift = i - (rank - 1) lanes; the
-    LSB-first binary network does that in log2(S) rounds, round b
-    moving the lanes whose shift has bit b down by b. Every stream
-    rolls by the same S - b in the same round, so the stacked shifts
-    take one roll, one ``& b``, one compare and one select per round
-    for all K, and each stream's data takes its own row of the mask,
-    broadcast over the planes. A moved shift keeps its bit b: no later
-    round tests a bit at or below b, so clearing it would be dead work.
-    ``roll`` is `pltpu.roll` inside a kernel (tests pass `jnp.roll`)."""
+    [8, S] one, so the stack is free), which gets ONE prefix sum. The
+    tile and the stacked shifts then sit behind a static lead of LANE
+    lanes in [., S + LANE] arrays, so lane i of stream j has to move
+    DOWN by shift = LANE + i - (carry j + rank - 1) lanes, never up: the
+    carry offset rides in the network and no roll follows it. The shifts
+    stay non-negative and non-decreasing along a stream's kept lanes in
+    steps smaller than the lanes' distance, which is all the LSB-first
+    binary network needs to move lanes without collisions: round b moves
+    the lanes whose shift has bit b down by b. Every stream rolls by the
+    same amount in the same round, so the stacked shifts take one roll,
+    one ``& b``, one compare and one select per round for all K, and
+    each stream's data takes its own row of the mask, broadcast over the
+    planes. The rounds with b >= LANE move whole vreg columns and rotate
+    nothing. A moved shift keeps its bit b: no later round tests a bit
+    at or below b, so clearing it would be dead work. ``roll`` is
+    `pltpu.roll` inside a kernel (tests pass `jnp.roll`)."""
     if roll is None:
         from jax.experimental.pallas import tpu as pltpu
         roll = pltpu.roll
     S = x.shape[1]
+    W = S + LANE
     sub = jax.lax.broadcasted_iota(jnp.int32, (8, S), 0)
     keep8 = jnp.broadcast_to(keeps[-1], (8, S))
+    carry8 = jnp.full((8, S), carries[-1], jnp.int32)
     for j in range(len(keeps) - 2, -1, -1):
         keep8 = jnp.where(sub == j, keeps[j], keep8)
+        carry8 = jnp.where(sub == j, carries[j], carry8)
     ranks = _lane_prefix(keep8, roll)
-    sh = jnp.where(keep8 == 1, _lane_iota(S) - (ranks - 1), 0)
-    comps = [x] * len(keeps)
+    sh = jnp.where(keep8 == 1,
+                   _lane_iota(S) + (LANE + 1) - (carry8 + ranks), 0)
+    sh = jnp.concatenate([jnp.zeros((8, LANE), jnp.int32), sh], axis=1)
+    xw = jnp.concatenate([jnp.zeros((x.shape[0], LANE), x.dtype), x], axis=1)
+    comps = [xw] * len(keeps)
     b = 1
-    while b < S:
-        moved = roll(sh, S - b, 1)
+    while b < W:
+        moved = roll(sh, W - b, 1)
         take = moved & b
         for j in range(len(keeps)):
             comps[j] = jnp.where(
-                jnp.broadcast_to(take[j:j + 1], x.shape) != 0,
-                roll(comps[j], S - b, 1), comps[j])
+                jnp.broadcast_to(take[j:j + 1], xw.shape) != 0,
+                roll(comps[j], W - b, 1), comps[j])
         sh = jnp.where(take != 0, moved, sh)
         b *= 2
     return comps, [jnp.sum(k) for k in keeps]
+
+
+def _emit_stream(comp, k, smem, cursor, carry, slot, asteps, stgs, cbuf,
+                 sems, win_ref):
+    """One stream's carry-chunk write, for both partition kernels.
+    ``comp`` [P, S + LANE] holds the tile's k kept lanes from lane c on
+    (`_compact_streams` with the stream's carry length c =
+    ``smem[carry]``); the < LANE lanes the previous tile left over lie
+    in lanes [0, c) of ``cbuf``. One select on the first column puts
+    them in front, the chunk is staged ONCE into this step's buffer
+    (``stgs[slot]``: two, so that this step's build overlaps the
+    previous step's DMA) and DMA'd to the LANE-aligned cursor
+    ``smem[cursor]`` of the scratch window; waiting for the other
+    slot's DMA before starting this one serializes the overlapping
+    writes. The stream advances by adv = the whole columns of c + k,
+    and the next carry is the aligned column [adv, adv + LANE) of the
+    buffer just staged."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    W = comp.shape[1]
+    c = smem[carry]
+    written = pl.multiple_of(smem[cursor], LANE)
+    total = c + k
+    adv = pl.multiple_of((total // LANE) * LANE, LANE)
+    head = jnp.where(_lane_iota(LANE) < c, cbuf[...], comp[:, :LANE])
+
+    for s in (0, 1):
+        @pl.when(slot == s)
+        def _(s=s):
+            stg = stgs[s]
+            stg[:, :LANE] = head
+            stg[:, LANE:] = comp[:, LANE:]
+            # slot alternation follows ACTIVE steps, so the other slot's
+            # DMA is outstanding on every step but the first
+            @pl.when(asteps > 0)
+            def _():
+                pltpu.make_async_copy(
+                    stgs[1 - s], win_ref.at[:, pl.ds(0, W)],
+                    sems.at[1 - s]).wait()
+            pltpu.make_async_copy(
+                stg, win_ref.at[:, pl.ds(written, W)], sems.at[s]).start()
+            cbuf[...] = stg[:, pl.ds(adv, LANE)]
+
+    smem[cursor] = written + adv
+    smem[carry] = total - adv
 
 
 def _partition_kernel(scal, data_ref, dout_ref, win_ref, nleft_ref,
@@ -544,52 +610,14 @@ def _partition_kernel(scal, data_ref, dout_ref, win_ref, nleft_ref,
         nl_here = jnp.sum(jnp.where(side == 0,
                                     (valid & go_left).astype(jnp.int32), 0))
 
-        (comp,), (k,) = _compact_streams(x, [keep])
-
-        c = smem[2]
-        written = pl.multiple_of(smem[1], 128)
         # slot alternation must follow ACTIVE steps (skipped blocks do
         # not run): parity of an SMEM counter, not of the grid step
         asteps = smem[3]
         slot = jax.lax.rem(asteps, 2)
-        c_inv = jax.lax.rem(128 - c, 128)
-
-        # two buffers so this step's build overlaps the previous step's
-        # DMA; the wait-before-start serializes the overlapping writes
-        @pl.when(slot == 0)
-        def _():
-            stg0[:, :S] = comp
-            stg0[:, S:] = pltpu.roll(cbuf[...], c_inv, 1)
-            stg0[...] = pltpu.roll(stg0[...], c, 1)
-            @pl.when(asteps > 0)
-            def _():
-                pltpu.make_async_copy(
-                    stg1, win_ref.at[:, pl.ds(0, S + 128)], sems.at[1]).wait()
-            pltpu.make_async_copy(
-                stg0, win_ref.at[:, pl.ds(written, S + 128)],
-                sems.at[0]).start()
-
-        @pl.when(slot == 1)
-        def _():
-            stg1[:, :S] = comp
-            stg1[:, S:] = pltpu.roll(cbuf[...], c_inv, 1)
-            stg1[...] = pltpu.roll(stg1[...], c, 1)
-            pltpu.make_async_copy(
-                stg0, win_ref.at[:, pl.ds(0, S + 128)], sems.at[0]).wait()
-            pltpu.make_async_copy(
-                stg1, win_ref.at[:, pl.ds(written, S + 128)],
-                sems.at[1]).start()
-
-        # --- stream bookkeeping + next carry ---------------------------
-        total = c + k
-        adv = (total // 128) * 128
-        newc = total - adv
-        merged = jnp.where(slot == 0, stg0[...], stg1[...])
-        cbuf[...] = pltpu.roll(merged, jax.lax.rem((S + 128) - adv, S + 128),
-                               1)[:, :128]
+        (comp,), (k,) = _compact_streams(x, [keep], [smem[2]])
+        _emit_stream(comp, k, smem, 1, 2, slot, asteps, (stg0, stg1), cbuf,
+                     sems, win_ref)
         smem[0] = smem[0] + nl_here
-        smem[1] = written + adv
-        smem[2] = newc
         smem[3] = asteps + 1
 
         @pl.when((side == 1) & (t == t1))
@@ -727,9 +755,11 @@ def _partition_kernel2(scal, data_ref, dout_ref, win_ref, nleft_ref,
     R stream [rights|tail] carry-written into a second scratch region
     at fixed anchor `RB0 + S` (so its coordinates are independent of
     the — still unknown — boundary). Both come from one compaction
-    plan per tile (`_compact_streams` with the rows keep_l, keep_r: one
-    prefix sum, one stacked shift row, the two data networks advancing
-    round by round together). The two chunk-write chains are
+    plan per tile (`_compact_streams` with the rows keep_l, keep_r and
+    the two carry lengths: one prefix sum, one stacked shift row, the
+    two data networks advancing round by round together, each tile
+    coming out at its stream's carry offset) and one carry write each
+    (`_emit_stream`, v1's own). The two chunk-write chains are
     independent and interleave, halving the per-step wait latency of
     the v1 design, and the window is read once instead of twice.
 
@@ -780,48 +810,12 @@ def _partition_kernel2(scal, data_ref, dout_ref, win_ref, nleft_ref,
         asteps = smem[5]
         slot = jax.lax.rem(asteps, 2)
 
-        def emit(comp, k, cursor_slot, carry_slot, stg0, stg1, cbuf, sems):
-            """One stream's carry-chunk write (the v1 mechanism)."""
-            c = smem[carry_slot]
-            written = pl.multiple_of(smem[cursor_slot], 128)
-            c_inv = jax.lax.rem(128 - c, 128)
-
-            @pl.when(slot == 0)
-            def _():
-                stg0[:, :S] = comp
-                stg0[:, S:] = pltpu.roll(cbuf[...], c_inv, 1)
-                stg0[...] = pltpu.roll(stg0[...], c, 1)
-                @pl.when(asteps > 0)
-                def _():
-                    pltpu.make_async_copy(
-                        stg1, win_ref.at[:, pl.ds(0, S + 128)],
-                        sems.at[1]).wait()
-                pltpu.make_async_copy(
-                    stg0, win_ref.at[:, pl.ds(written, S + 128)],
-                    sems.at[0]).start()
-
-            @pl.when(slot == 1)
-            def _():
-                stg1[:, :S] = comp
-                stg1[:, S:] = pltpu.roll(cbuf[...], c_inv, 1)
-                stg1[...] = pltpu.roll(stg1[...], c, 1)
-                pltpu.make_async_copy(
-                    stg0, win_ref.at[:, pl.ds(0, S + 128)], sems.at[0]).wait()
-                pltpu.make_async_copy(
-                    stg1, win_ref.at[:, pl.ds(written, S + 128)],
-                    sems.at[1]).start()
-
-            total = c + k
-            adv = (total // 128) * 128
-            merged = jnp.where(slot == 0, stg0[...], stg1[...])
-            cbuf[...] = pltpu.roll(
-                merged, jax.lax.rem((S + 128) - adv, S + 128), 1)[:, :128]
-            smem[cursor_slot] = written + adv
-            smem[carry_slot] = total - adv
-
-        (compL, compR), (kL, kR) = _compact_streams(x, [keep_l, keep_r])
-        emit(compL, kL, 0, 1, stgL0, stgL1, cbufL, semL)
-        emit(compR, kR, 2, 3, stgR0, stgR1, cbufR, semR)
+        (compL, compR), (kL, kR) = _compact_streams(
+            x, [keep_l, keep_r], [smem[1], smem[3]])
+        _emit_stream(compL, kL, smem, 0, 1, slot, asteps, (stgL0, stgL1),
+                     cbufL, semL, win_ref)
+        _emit_stream(compR, kR, smem, 2, 3, slot, asteps, (stgR0, stgR1),
+                     cbufR, semR, win_ref)
 
         smem[4] = smem[4] + nl_here
         smem[5] = asteps + 1

@@ -37,11 +37,14 @@ def drop_executables():
 # ---------------------------------------------------------------------------
 
 def _make_state(n, g, seed, code_bits=8, tile=512, max_code=250,
-                persistent=True):
+                persistent=True, edit_codes=None):
     """`persistent`: label and score planes ride along, as in the
-    persistent tier's state; the per-tree tier's has neither."""
+    persistent tier's state; the per-tree tier's has neither.
+    `edit_codes(codes, rng)` may rewrite the drawn codes in place."""
     rng = np.random.RandomState(seed)
     codes = rng.randint(0, max_code, size=(n, g)).astype(np.uint8)
+    if edit_codes is not None:
+        edit_codes(codes, rng)
     grad = rng.randn(n).astype(np.float32)
     hess = rng.rand(n).astype(np.float32)
     layout = plane.make_layout(g, code_bits, n, with_label=persistent,
@@ -117,6 +120,76 @@ def test_partition_pallas_interpret_matches_ref(kernel, geom, dynamic,
     assert go_left[:nl].all() and not go_left[nl:].any()
 
 
+# Windows of >= 6 tiles whose kept counts per tile are GIVEN, so the
+# carry length of both streams takes the values that matter: (start,
+# count, lefts of the window's rows in each 512-lane tile it touches).
+# With start = 0 the L stream keeps a tile's lefts and the R stream its
+# 512 - lefts, so after each tile the carries are (L, R) = (1, 127),
+# (127, 1), (1, 127) by a wrap past 128 on both (127 + 130 = 257,
+# 1 + 382 = 383), (1, 127) with nothing / a whole tile kept (no advance
+# / a wrap from 127), (1, 127) the other way round, (0, 0) by exact
+# fills, then (127, 1). The second window starts inside tile 0 and ends
+# inside tile 7: the 385 pre-window rows ride the L stream (carry 1
+# before the first left), the tail rows the R stream, whose carry runs
+# 127, 126, 127, 127, 126, 127, 124.
+CARRY_WINDOWS = {
+    "aligned": (0, 4096, [1, 126, 130, 0, 512, 255, 127, 384]),
+    "offset": (385, 3500, [0, 1, 127, 128, 129, 511, 3, 300]),
+}
+CARRY_FEAT, CARRY_THR = 2, 100
+
+
+def _lefts_per_tile(start, count, lefts, tile=512):
+    """edit_codes for `_make_state`: of the window's rows in the t-th
+    tile it touches, exactly lefts[t] (seeded positions) go left under
+    CARRY_FEAT <= CARRY_THR."""
+    def edit(codes, rng):
+        for t, nl in enumerate(lefts):
+            lo = max(start, (start // tile + t) * tile)
+            hi = min(start + count, (start // tile + t + 1) * tile)
+            assert 0 <= nl <= hi - lo, (t, nl, lo, hi)
+            col = rng.randint(CARRY_THR + 1, 249, size=hi - lo)
+            col[rng.permutation(hi - lo)[:nl]] = rng.randint(
+                0, CARRY_THR + 1, size=nl)
+            codes[lo:hi, CARRY_FEAT] = col
+        assert hi == start + count
+    return edit
+
+
+@pytest.mark.parametrize("kernel", [plane.partition_pallas,
+                                    plane.partition_pallas2])
+@pytest.mark.parametrize("dynamic", [False, True], ids=["static", "dynamic"])
+@pytest.mark.parametrize("geom", ["higgs16", "pertree8"])
+@pytest.mark.parametrize("window", list(CARRY_WINDOWS))
+def test_partition_pallas_interpret_carry_lengths(kernel, dynamic, geom,
+                                                  window, drop_executables):
+    """Both kernels at the cells' plane counts, static `cap` and
+    `cap=None`, on windows whose tiles drive the streams' carry through
+    0, 1, 127, a wrap past 128, a tile that advances nothing and one
+    that is kept whole (CARRY_WINDOWS)."""
+    start, count, lefts = CARRY_WINDOWS[window]
+    edit = _lefts_per_tile(start, count, lefts)
+    g, persistent, planes = GEOMETRIES[geom]
+    layout, data, codes = _make_state(4096, g, seed=len(lefts),
+                                      persistent=persistent, edit_codes=edit)
+    assert layout.num_planes == planes and count >= 6 * layout.tile
+    rscal = plane.route_scalars(layout, CARRY_FEAT, CARRY_THR, 0,
+                                miss_bin=249)
+    cap = _cap_for(layout, count)
+    ref, nl_ref = plane.partition_ref(data, layout, start, count, rscal,
+                                      cap=cap)
+    got, nl_got = kernel(data, layout, start, count, rscal,
+                         cap=None if dynamic else cap, interpret=True)
+    assert int(nl_ref) == int(nl_got) == sum(lefts)
+    np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
+    rowids = np.asarray(got[layout.rowid])[start:start + count]
+    go_left = codes[rowids, CARRY_FEAT] <= CARRY_THR
+    assert go_left[:sum(lefts)].all() and not go_left[sum(lefts):].any()
+    # stable on both sides (the input's row ids were iota)
+    assert (np.diff(rowids[:sum(lefts)]) > 0).all()
+    assert (np.diff(rowids[sum(lefts):]) > 0).all()
+
+
 def test_partition_pallas_interpret_categorical_bitset():
     layout, data, codes = _make_state(2048, 6, seed=11)
     bin_set = {3, 17, 42, 128, 200}
@@ -173,12 +246,15 @@ def test_partition_pallas_interpret_stability(kernel):
 # _compact_streams (the kernels' one compaction primitive) vs numpy
 # ---------------------------------------------------------------------------
 
-def _compact(x, keeps):
+def _compact(x, keeps, carries=None):
     """The helper as a plain function: `jnp.roll` for `pltpu.roll`,
-    batched over the leading axis of every keep row."""
+    batched over the leading axis of every keep row; one carry length
+    per stream (0 when not given)."""
+    carries = [0] * len(keeps) if carries is None else carries
     fn = jax.jit(jax.vmap(
-        lambda *ks: plane._compact_streams(x, [k[None] for k in ks],
-                                           roll=jnp.roll)))
+        lambda *ks: plane._compact_streams(
+            x, [k[None] for k in ks], [jnp.int32(c) for c in carries],
+            roll=jnp.roll)))
     comps, counts = fn(*(jnp.asarray(k) for k in keeps))
     return [np.asarray(c) for c in comps], [np.asarray(c) for c in counts]
 
@@ -196,85 +272,111 @@ def _masks(s, n, seed):
     return np.concatenate([m, np.asarray(edges, np.int32)])
 
 
-def _assert_stable_partition(x, keep, comp, count):
-    """Every mask's kept lanes of x, in order, lead its compacted copy
-    (the lanes past the count are garbage)."""
+def _assert_stable_partition(x, keep, comp, count, carry=0):
+    """Every mask's kept lanes of x, in order, lie in its compacted
+    [P, S + 128] copy from lane `carry` on (every other lane is
+    garbage)."""
+    s = x.shape[1]
+    assert comp.shape[1:] == (x.shape[0], s + plane.LANE)
     np.testing.assert_array_equal(count, keep.sum(axis=1))
     # the kept lanes first, in order: a stable argsort of "dropped"
     want = x[:, np.argsort(1 - keep, axis=1, kind="stable")]
-    live = (np.arange(x.shape[1]) < count[:, None])[:, None, :]
+    live = (np.arange(s) < count[:, None])[:, None, :]
     np.testing.assert_array_equal(
-        np.where(live, comp, 0), np.where(live, want.transpose(1, 0, 2), 0))
+        np.where(live, comp[:, :, carry:carry + s], 0),
+        np.where(live, want.transpose(1, 0, 2), 0))
 
 
 _X128 = np.random.RandomState(7).randint(
     -2 ** 31, 2 ** 31, size=(16, 128), dtype=np.int64).astype(np.int32)
 
+CARRIES = (0, 1, 63, 127)       # a stream's carry length is in [0, 128)
+CARRY_PAIRS = [(a, b) for a in CARRIES for b in CARRIES]
+CARRY_PAIRS_FEW = [(0, 0), (1, 127), (63, 1), (127, 63)]
 
-@pytest.mark.parametrize("lanes,streams", [
-    (128, "one"), (128, "complement"), (128, "independent"),
-    (512, "complement")])
-def test_compact_streams_matches_numpy_stable_partition(lanes, streams):
+
+@pytest.mark.parametrize("lanes,streams,carries", [
+    *[(128, "one", (c,)) for c in CARRIES],
+    *[(128, "complement", cc) for cc in CARRY_PAIRS],
+    *[(128, "independent", cc) for cc in CARRY_PAIRS],
+    *[(512, "complement", cc) for cc in CARRY_PAIRS_FEW]])
+def test_compact_streams_matches_numpy_stable_partition(lanes, streams,
+                                                        carries):
     """K = 1; K = 2 as the v2 kernel stacks it (a row and its
-    complement); K = 2 with unrelated rows. 512 lanes reach the rounds
-    that shift by whole 128-lane columns."""
+    complement); K = 2 with unrelated rows; every stream at every carry
+    offset. 512 lanes reach the rounds that shift by whole 128-lane
+    columns."""
     x = np.tile(_X128, (1, lanes // 128)) + np.arange(lanes, dtype=np.int32)
     keep = _masks(lanes, 2000, seed=1)
     keeps = {"one": [keep], "complement": [keep, 1 - keep],
              "independent": [keep, _masks(lanes, 2000, seed=2)]}[streams]
-    comps, counts = _compact(x, keeps)
+    comps, counts = _compact(x, keeps, carries)
     assert len(comps) == len(counts) == len(keeps)
-    for k, comp, count in zip(keeps, comps, counts):
-        _assert_stable_partition(x, k, comp, count)
+    for k, comp, count, c in zip(keeps, comps, counts, carries):
+        _assert_stable_partition(x, k, comp, count, c)
 
 
-def test_compact_streams_two_rows_equal_two_calls():
+@pytest.mark.parametrize("carries", CARRY_PAIRS_FEW)
+def test_compact_streams_two_rows_equal_two_calls(carries):
     """Stacking changes no lane of either stream, garbage included."""
     keep_l = _masks(128, 2000, seed=3)
     keep_r = 1 - keep_l
-    (both_l, both_r), (kl, kr) = _compact(_X128, [keep_l, keep_r])
-    (one_l,), (k1,) = _compact(_X128, [keep_l])
-    (one_r,), (k2,) = _compact(_X128, [keep_r])
+    (both_l, both_r), (kl, kr) = _compact(_X128, [keep_l, keep_r], carries)
+    (one_l,), (k1,) = _compact(_X128, [keep_l], carries[:1])
+    (one_r,), (k2,) = _compact(_X128, [keep_r], carries[1:])
     np.testing.assert_array_equal(both_l, one_l)
     np.testing.assert_array_equal(both_r, one_r)
     np.testing.assert_array_equal(kl, k1)
     np.testing.assert_array_equal(kr, k2)
 
 
-def test_compact_streams_exhaustive_on_16_lanes():
-    """The LSB-first network is a stable compaction for EVERY keep mask
-    of a 16-lane tile (all 65,536), on both stacked streams."""
+@pytest.mark.parametrize("carries", CARRY_PAIRS)
+def test_compact_streams_exhaustive_on_16_lanes(carries):
+    """The LSB-first network behind its 128-lane lead is a stable
+    compaction to the carry offset for EVERY keep mask of a 16-lane
+    tile (all 65,536), on both stacked streams."""
     x = _X128[:8, :16]
     keep = (np.arange(1 << 16)[:, None] >> np.arange(16) & 1).astype(np.int32)
-    (comp_l, comp_r), (kl, kr) = _compact(x, [keep, 1 - keep])
-    _assert_stable_partition(x, keep, comp_l, kl)
-    _assert_stable_partition(x, 1 - keep, comp_r, kr)
+    (comp_l, comp_r), (kl, kr) = _compact(x, [keep, 1 - keep], carries)
+    _assert_stable_partition(x, keep, comp_l, kl, carries[0])
+    _assert_stable_partition(x, 1 - keep, comp_r, kr, carries[1])
 
 
-def test_compact_streams_dropped_subtract_is_exact():
-    """Before PR 28 a moved shift had its bit b cleared (`moved - b`).
-    No later round tests a bit at or below b, so the network routes
-    every lane the same with and without it, garbage included."""
-    def with_subtract(keep):
-        s = keep.shape[0]
-        keep = keep[None]
-        lane = jnp.arange(s, dtype=jnp.int32)[None]
-        ranks = jnp.cumsum(keep, axis=1)
-        sh = jnp.where(keep == 1, lane - (ranks - 1), 0)
-        comp = jnp.asarray(_X128)
-        b = 1
-        while b < s:
-            moved = jnp.roll(sh, s - b, 1)
-            m1 = (moved & b) != 0
-            comp = jnp.where(m1, jnp.roll(comp, s - b, 1), comp)
-            sh = jnp.where(m1, moved - b, sh)
-            b *= 2
-        return comp
+def _network_pr28(x, keep, subtract):
+    """The width-S network as it shipped before the carry rode in it
+    (PR 28; with `subtract` as before PR 28, when a moved shift had its
+    bit b cleared): kept lanes from lane 0 on."""
+    s = keep.shape[0]
+    keep = keep[None]
+    lane = jnp.arange(s, dtype=jnp.int32)[None]
+    ranks = jnp.cumsum(keep, axis=1)
+    sh = jnp.where(keep == 1, lane - (ranks - 1), 0)
+    comp = jnp.asarray(x)
+    b = 1
+    while b < s:
+        moved = jnp.roll(sh, s - b, 1)
+        m1 = (moved & b) != 0
+        comp = jnp.where(m1, jnp.roll(comp, s - b, 1), comp)
+        sh = jnp.where(m1, moved - b if subtract else moved, sh)
+        b *= 2
+    return comp
 
+
+@pytest.mark.parametrize("subtract", [False, True],
+                         ids=["pr28", "with_subtract"])
+def test_compact_streams_zero_carry_is_the_width_s_network(subtract):
+    """With the carries at 0 and the lead cut off it is the function it
+    replaced, garbage lanes aside; and that one routed every kept lane
+    the same with and without the `- b` on a moved shift (no later
+    round tests a bit at or below b)."""
     keep = _masks(128, 2000, seed=4)
-    want = np.asarray(jax.jit(jax.vmap(with_subtract))(jnp.asarray(keep)))
-    (got,), _ = _compact(_X128, [keep])
-    np.testing.assert_array_equal(got, want)
+    want = np.asarray(jax.jit(jax.vmap(
+        lambda k: _network_pr28(_X128, k, subtract)))(jnp.asarray(keep)))
+    for keeps in ([keep], [keep, 1 - keep]):
+        comps, counts = _compact(_X128, keeps)
+        live = (np.arange(128) < counts[0][:, None])[:, None, :]
+        np.testing.assert_array_equal(
+            np.where(live, comps[0][:, :, :128], 0), np.where(live, want, 0))
 
 
 # ---------------------------------------------------------------------------
