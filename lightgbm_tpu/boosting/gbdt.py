@@ -240,8 +240,9 @@ class GBDT:
                 "hist": hist or "scatter",
                 "partition": getattr(self._fused, "_part_method", "xla"),
                 "sampling": self._sampling_plan()}
-        if plan["sampling"] is not None:
-            # what assigns every row its leaf once rows are left out
+        if tier == "per-tree-fused" or plan["sampling"] is not None:
+            # what gives every row its leaf after a tree of the per-tree
+            # tier: the tree's splits replayed over the resident planes
             plan["row_traverse"] = getattr(self._fused,
                                            "row_traverse_method", "xla")
         if self._fused is not None:
@@ -251,6 +252,10 @@ class GBDT:
             plan["codes_pack"] = self._fused.codes_pack
             if self._fused.is_multichip:
                 plan["shard_rows"] = self._fused.shard_rows
+        if getattr(self.objective, "need_group", False):
+            # a ranking objective: the queries, their padded sizes and
+            # the pair terms its gradient program evaluates an iteration
+            plan["rank_grad"] = self.objective.rank_plan()
         return plan
 
     def _sampling_plan(self) -> Optional[str]:

@@ -1071,6 +1071,11 @@ TRAVERSE_REC = 1 + ROUTE_SCALARS   # a split's record: its slot, route_scalars
 # 64 / 128 / 256 / 512 rows 34.1 / 26.6 / 24.3 / 25.6 / 32.8 ms.
 TRAVERSE_ROWS = 2048
 TRAVERSE_CHUNK = 128
+# a tile's planes and leaf ids, double-buffered, stay inside this (the
+# scoped limit is 16 MB): past five planes the tile gives up rows, whole
+# chunks first (the 35 planes of `msltr137`: 256 rows, where 2,048 were
+# refused at 72 MB), then the chunk itself shrinks with the tile
+TRAVERSE_VMEM = 12 << 20
 
 
 def _tree_routes(layout: PlaneLayout, ta, miss_bin, efb_dev, k):
@@ -1200,7 +1205,12 @@ def traverse_planes_pallas(codes_planes: jax.Array, table: jax.Array, *,
     if R % unit:        # a layout whose lane tile shrank below the unit
         codes_planes = jnp.pad(codes_planes, ((0, 0), (0, -R % unit)))
     nrows = codes_planes.shape[1] // LANE
-    rows = min(TRAVERSE_ROWS, nrows)
+    fit = TRAVERSE_VMEM // (2 * 4 * LANE * (C + 1))
+    if fit >= TRAVERSE_CHUNK:
+        chunk = TRAVERSE_CHUNK
+        rows = min(TRAVERSE_ROWS, nrows, fit // chunk * chunk)
+    else:
+        rows = chunk = max(8, fit // 8 * 8)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(pl.cdiv(nrows, rows),),
@@ -1208,8 +1218,7 @@ def traverse_planes_pallas(codes_planes: jax.Array, table: jax.Array, *,
         out_specs=pl.BlockSpec((rows, LANE), lambda t, tbl: (t, 0)),
     )
     leaf = pl.pallas_call(
-        functools.partial(_traverse_kernel, rows=rows,
-                          chunk=TRAVERSE_CHUNK),
+        functools.partial(_traverse_kernel, rows=rows, chunk=chunk),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nrows, LANE), jnp.int32),
         name="traverse_planes_pallas",

@@ -1318,21 +1318,6 @@ class FusedSerialGrower:
         order = jnp.argsort(starts)
         return starts[order], order
 
-    def _pos_leaf(self, st: FusedTreeState):
-        """Leaf id per LANE via broadcast compare (no [N] gather): the
-        rank of each position among the sorted starts, then the tiny
-        order table applied as an equality-weighted reduction."""
-        sorted_starts, order = self._pos_leaf_terms(st)
-        pos = jnp.arange(self.layout.num_lanes, dtype=jnp.int32)
-        k = jnp.sum(pos[:, None] >= sorted_starts[None, :],
-                    axis=1).astype(jnp.int32) - 1
-        k = jnp.maximum(k, 0)
-        # order[k] without a per-row gather: sum_j order_j * [k == j]
-        L = self.num_leaves
-        lid = jnp.arange(L, dtype=jnp.int32)
-        return jnp.sum(jnp.where(k[:, None] == lid[None, :],
-                                 order[None, :], 0), axis=1).astype(jnp.int32)
-
     def _score_add_by_pos(self, st: FusedTreeState, leaf_vals):
         """Per-lane leaf value as a sum of step functions over the
         sorted window starts — fuses on the VPU, no [N] gather and no
@@ -1572,18 +1557,23 @@ class FusedSerialGrower:
         None). ``codes_planes`` / ``mv`` are the RESIDENT row-order
         planes ([code_planes, R] / slot-major [K, n]) and ``grad`` /
         ``hess`` are in row order. ``bag_cap`` None: every row is in
-        the bag, the tree grows on the resident planes as they lie and
-        the partition's own leaf assignment serves the score update.
-        Otherwise (bagging, GOSS, RF with rows left out) the first
-        ``bag_cap`` rows of ``perm`` — a static capacity that holds the
-        bag (_bag_capacity) — are gathered into lane order here, once
-        per TREE, and every row's leaf comes from replaying the tree's
-        splits over the resident planes. The data-parallel grower runs
-        this same function per shard."""
+        the bag (a ranking or custom objective, multiclass, DART, GOSS
+        before sampling starts) and the tree grows on a fresh planar
+        state laid out from the resident planes as they lie
+        (`lgbm.build_state`). Otherwise (bagging, GOSS, RF with rows
+        left out) the first ``bag_cap`` rows of ``perm`` — a static
+        capacity that holds the bag (_bag_capacity) — are gathered into
+        lane order here, once per TREE (`lgbm.bag_gather`). Either way
+        every row's leaf comes from replaying the tree's splits over
+        the resident planes (`lgbm.row_traverse`): one path, and no
+        row-sized scatter of the partition's leaf assignment back to
+        row order. The data-parallel grower runs this same function
+        per shard."""
         n = self.layout.num_rows
         if bag_cap is None:
-            data = plane.build_data(self.layout, codes_planes, grad, hess,
-                                    rowid=perm, mv=mv)
+            with jax.named_scope("lgbm.build_state"):
+                data = plane.build_data(self.layout, codes_planes, grad,
+                                        hess, rowid=perm, mv=mv)
         else:
             with jax.named_scope("lgbm.bag_gather"):
                 # ONE gather for codes, gradients and hessians: a [N]-
@@ -1605,14 +1595,8 @@ class FusedSerialGrower:
 
         leaf_of_row = None
         if compute_score_update:
-            if bag_cap is None:
-                pos_leaf = self._pos_leaf(st)
-                rowids = st.data[self.layout.rowid][:n]
-                leaf_of_row = jnp.zeros(n, jnp.int32).at[rowids].set(
-                    pos_leaf[:n], unique_indices=True)
-            else:
-                with jax.named_scope("lgbm.row_traverse"):
-                    leaf_of_row = self.traverse_planes(ta, codes_planes)[:n]
+            with jax.named_scope("lgbm.row_traverse"):
+                leaf_of_row = self.traverse_planes(ta, codes_planes)[:n]
         return ta, leaf_of_row
 
     def _bag_capacity(self, bag_cnt: int) -> int:

@@ -507,6 +507,12 @@ TRAVERSE_CASES = {
     # and 60,448 pad lanes past the rows
     "two_tiles_and_pad_lanes": (300_000, 6, 8, 256, 15, 14,
                                 list(range(6)), {}),
+    # 137 columns in 35 planes (msltr137): the tile gives up rows to fit
+    # VMEM, 256 x 128 lanes; a whole tile and a part of a second
+    "many_planes_smaller_tile": (40_000, 137, 8, 256, 31, 30,
+                                 [0, 68, 136], {}),
+    # 525 planes: the tile is smaller than a chunk, and the chunk follows
+    "planes_past_a_chunk": (3000, 2100, 8, 256, 15, 14, [5, 2099], {}),
 }
 
 
