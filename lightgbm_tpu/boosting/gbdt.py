@@ -1684,14 +1684,47 @@ def _score_add_entry():
                           static_argnames=("class_id",))
 
 
+def _kth_largest(x, k: int, digit_bits: int = 1):
+    """The k-th largest value of float32 [n] x, exactly and with no
+    sort. Each float goes to the uint32 whose unsigned order is the
+    float's (-0.0 as +0.0, as `lax.sort` compares them; sign bit set on
+    a non-negative, every bit inverted on a negative), and the answer —
+    the largest t with count(key >= t) >= k — is fixed from the top,
+    `digit_bits` bits a pass: one fused compare-and-count over [n]
+    against every value of the digit. 32 one-bit passes over 22M keys
+    take 1.2 ms on a v5e, where the sort took 73 ms to hand over one
+    element; wider digits make fewer passes of more compares and win
+    only past ~30M rows, where the key no longer stays on the chip
+    (scripts/kth_micro.py times 1, 4 and 8 bits; PERF.md section 6,
+    PR 35). A loop, not 32 unrolled passes: the benchmark books a
+    trace's ops by instruction name, and one body has few."""
+    assert x.dtype == jnp.float32 and 32 % digit_bits == 0
+    sign = jnp.uint32(1 << 31)
+    bits = jax.lax.bitcast_convert_type(
+        jnp.where(x == 0, jnp.float32(0), x), jnp.uint32)
+    key = jnp.where(bits >= sign, ~bits, bits | sign)
+    digits = jnp.arange(1, 1 << digit_bits, dtype=jnp.uint32)
+
+    def one_pass(i, t):
+        shift = jnp.uint32(32 - digit_bits) - jnp.uint32(digit_bits) * i
+        # counts fall as the digit grows: the digit is how many reach k
+        reach = jnp.sum(key[None, :] >= (t | (digits << shift))[:, None],
+                        axis=1, dtype=jnp.int32) >= k
+        return t | (jnp.sum(reach, dtype=jnp.uint32) << shift)
+
+    t = jax.lax.fori_loop(jnp.uint32(0), jnp.uint32(32 // digit_bits),
+                          one_pass, jnp.uint32(0))
+    return jax.lax.bitcast_convert_type(
+        jnp.where(t >= sign, t ^ sign, ~t), jnp.float32)
+
+
 def _largest_k_mask(x, k: int):
     """[n] bool: the k largest of x, equal values to the lower index —
     the rows `jax.lax.top_k(x, k)` picks, found from the exact k-th
-    largest value (one sort) instead of by scattering top_k's indices:
-    on a TPU a [n]-sized scatter costs seconds at 10^7 rows, a sort tens
-    of milliseconds."""
-    n = x.shape[0]
-    kth = jnp.sort(x)[n - k]
+    largest value (`_kth_largest`: a few counting passes, no sort)
+    instead of by scattering top_k's indices: on a TPU a [n]-sized
+    scatter costs seconds at 10^7 rows."""
+    kth = _kth_largest(x, k)
     above = x > kth
     tie = x == kth
     need = k - jnp.sum(above, dtype=jnp.int32)
@@ -1704,8 +1737,9 @@ def _goss_sample_device(grad, hess, seed, *, top_k: int, other_k: int):
     (n - top_k) / other_k, and the stable [bag | oob] permutation —
     all without host round-trips of [C, N] arrays, and without a
     [N]-sized scatter or gather: both selections are masks from an
-    exact k-th value, the weighting is a select, and the permutation is
-    one stable sort of the row ids by the mask, so both sides keep
+    exact k-th value (counting passes over the float bits, no sort),
+    the weighting is a select, and the permutation is the program's one
+    sort, a stable one of the row ids by the mask, so both sides keep
     ascending row order, exactly the host path's sorted-bag/oob
     layout."""
     with jax.named_scope("lgbm.goss_sample"):

@@ -119,6 +119,83 @@ def test_sampler_against_the_numpy_rule(n, rates, classes):
     assert np.array_equal(h2[:, rest], h[:, rest])
 
 
+# ------------------------------------------------- the k-th value alone
+
+def kth_case(name, n, rng):
+    if name == "normal":
+        return rng.normal(size=n).astype(np.float32)
+    if name == "four_values":               # heavy ties on the threshold
+        return rng.choice(np.float32([-1.5, 0.25, 2.0, 2.5]), n)
+    if name == "all_equal":
+        return np.full(n, 3.25, np.float32)
+    if name == "signed_zeros":
+        return rng.choice(np.float32([-0.0, 0.0, 1.0, -1.0]), n)
+    if name == "inf_and_denormals":
+        x = rng.normal(size=n).astype(np.float32)
+        for at, v in enumerate([np.inf, -np.inf, 1e-42, -1e-42, 1e-45]):
+            x[at::7] = v
+        return x
+    assert name == "top_rows_masked"        # the sampler's second call
+    r = rng.random(n).astype(np.float32)
+    return np.where(rng.random(n) < 0.2, np.float32(-1.0), r)
+
+
+@pytest.mark.parametrize("n, k", [(1, 1)] + [
+    (n, k) for n in (1000, 4097)            # 4097: no multiple of 128
+    for k in (1, 2, n // 5, n - 1, n)])
+@pytest.mark.parametrize("name", [
+    "normal", "four_values", "all_equal", "signed_zeros",
+    "inf_and_denormals", "top_rows_masked"])
+def test_kth_largest_is_the_sorts_element(name, n, k):
+    """The select against `np.sort(x)[n - k]`: the same float32, not a
+    neighbour (-0.0 and 0.0 are one value to every compare); the mask
+    against the index set of `jax.lax.top_k`, equal values to the lower
+    index."""
+    x = kth_case(name, n, np.random.default_rng(n + len(name)))
+    got = np.asarray(jax.jit(G._kth_largest, static_argnums=1)(
+        jnp.asarray(x), k))
+    assert got.dtype == np.float32 and got == np.sort(x)[n - k]
+    rows = np.flatnonzero(np.asarray(
+        jax.jit(G._largest_k_mask, static_argnums=1)(jnp.asarray(x), k)))
+    _, want = jax.lax.top_k(jnp.asarray(x), k)
+    assert np.array_equal(rows, np.sort(np.asarray(want)))
+
+
+def parents_rule(g, h, seed, top_k, other_k):
+    """`_goss_sample_device` as it stood before PR 35, in numpy: each
+    k-th value read off a full sort, ties to the lower row by a cumsum,
+    the permutation a stable argsort of the one-bit bag mask."""
+    def largest_k_mask(x, k):
+        kth = np.sort(x)[len(x) - k]
+        above, tie = x > kth, x == kth
+        return above | (tie & (np.cumsum(tie) <= k - above.sum()))
+    n = g.shape[1]
+    # the weight as the device sums it: float32, class by class
+    is_top = largest_k_mask(np.sum(np.abs(g * h), axis=0), top_k)
+    r = np.asarray(jax.random.uniform(jax.random.PRNGKey(seed), (n,)))
+    sampled = largest_k_mask(np.where(is_top, np.float32(-1.0), r), other_k)
+    multiply = np.float32((n - top_k) / other_k)
+    return (np.where(sampled, g * multiply, g),
+            np.where(sampled, h * multiply, h),
+            np.argsort(~(is_top | sampled), kind="stable").astype(np.int32))
+
+
+def test_sampler_is_the_parents_rule_to_the_bit():
+    rng = np.random.default_rng(35)
+    g = rng.normal(size=(3, 4097)).astype(np.float32)
+    h = rng.uniform(0.05, 0.25, size=(3, 4097)).astype(np.float32)
+    g[:, ::3] = g[:, 1::3]          # equal weights at the threshold too
+    h[:, ::3] = h[:, 1::3]
+    top_k, other_k = goss_counts(4097, 0.2, 0.1)
+    got = jax.jit(G._goss_sample_device,
+                  static_argnames=("top_k", "other_k"))(
+        jnp.asarray(g), jnp.asarray(h), jnp.int32(11),
+        top_k=top_k, other_k=other_k)
+    want = parents_rule(g, h, 11, top_k, other_k)
+    for a, b in zip(got, want):
+        assert np.asarray(a).tobytes() == b.tobytes()
+
+
 # ------------------------------------------------- through lgb.train
 
 @pytest.fixture(scope="module")
@@ -378,3 +455,40 @@ def test_per_tree_program_carries_scope(per_tree_programs, scope, program):
     if program == "grow":
         # the shared grower's own stages are there beside it
         assert any("lgbm.partition" in nm.split("/") for nm in names)
+
+
+def test_sampler_selects_without_a_sort(per_tree_programs):
+    """The two k-th values come from counting passes: the one sort left
+    is the permutation's, nothing scatters or gathers, and every op of
+    the program's body, the passes' loop bodies included, carries the
+    scope the benchmark books the sampler's time by (constants have no
+    location; a helper's own ops take the scope from the call, which is
+    in the body)."""
+    text = per_tree_programs["sampler"]
+    ops = re.findall(r'(?:stablehlo|chlo)\.[a-z_]+', text)
+    assert ops.count("stablehlo.sort") == 1, sorted(set(ops))
+    assert not [op for op in ops if "scatter" in op or "gather" in op]
+    assert ops.count("stablehlo.while") >= 2        # a loop a k-th value
+
+    locs = dict(re.findall(r"^(#loc\d+) = loc\((.*)\)$", text, re.M))
+
+    def names(ref):
+        """Every name the location holds, its call sites' included."""
+        return re.findall(r'"([^"]*)"', locs[ref]) + [
+            nm for inner in re.findall(r"#loc\d+", locs[ref])
+            for nm in names(inner)]
+
+    main = text.split("func.func public @main", 1)[1]
+    main = main.split("func.func private", 1)[0]
+    checked = 0
+    for ln in main.splitlines()[1:]:
+        op = re.search(r'(?:= |^\s+)"?((?:stablehlo|chlo)\.[a-z_]+|call)\b',
+                       ln)
+        ref = re.search(r"loc\((#loc\d+)\)\s*$", ln)
+        if not op or not ref or op.group(1) in ("stablehlo.constant",
+                                                "stablehlo.return"):
+            continue
+        checked += 1
+        assert any("lgbm.goss_sample" in nm.split("/")
+                   for nm in names(ref.group(1))), ln
+    assert checked > 100, checked
