@@ -16,6 +16,7 @@ from typing import Any, Dict, List, Optional, Sequence, Union
 import numpy as np
 
 from . import obs
+from .compile import get_manager
 from .config import Config
 from .io.dataset import BinnedDataset, _is_sparse
 from .utils import log
@@ -345,6 +346,7 @@ class Booster:
                 # Dataset._update_params semantics: later params win
                 train_set.params = {**(train_set.params or {}), **self.params}
             train_set.construct()
+            get_manager().phase = "first_call"
             self._train_set = train_set
             objective = create_objective(cfg)
             metrics = [m for m in (create_metric(nm, cfg) for nm in cfg.metric)
@@ -432,6 +434,7 @@ class Booster:
                 grad, hess = fobj(self._curr_pred_for_fobj(),
                                   self._train_set)
                 stopped = self.__boost(grad, hess)
+        get_manager().mark_update()
         if not self._setup_reported:
             # the first iteration paid for the state and the compile
             self._setup_reported = True

@@ -24,6 +24,9 @@ the compiler's own message. Every transition is counted in
 `compile.*` counters and the "compile"/"aot_load"/"aot_serialize"
 phase timers.
 
+Beside the sums, one always-on table by entry name, calls and builds
+(`snapshot_entries()`; docs/OBSERVABILITY.md "The executable table").
+
 Thread-safety: per-key locks serialize duplicate compiles (a warmup
 thread and the training thread asking for the same key compile once); a
 single trace lock serializes `.lower()` calls because entry builders may
@@ -40,9 +43,11 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 from jax._src import config as _jax_config
+from jax.profiler import TraceAnnotation
 from jax.experimental.serialize_executable import (deserialize_and_load,
                                                    serialize)
 
+from ..obs.spans import ANNOTATION_PREFIX
 from ..utils import log
 from . import signature as S
 from .store import (CorruptBlobError, ExecutableStore, min_compile_s,
@@ -55,15 +60,52 @@ _MAX_EXECUTABLES = 128
 # reads the per-thread count around .compile() to learn whether the
 # executable is fresh or was deserialized from jax's own cache
 _JAX_CACHE_HIT = "/jax/compilation_cache/cache_hits"
-_tls = threading.local()
+# one per executable jax needs, compiled or served by its cache
+_JAX_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+UNREGISTERED = "(unregistered)"
+_tls = threading.local()    # .jax_cache_hits, .building, .stray_hit
+_NO_NOTE = contextlib.nullcontext()
 
 
-def _on_jax_event(event: str, **_: Any) -> None:
+def _on_jax_event(event: str, *secs: float, fun_name: str = "",
+                  **_: Any) -> None:
+    """Listens to jax's plain and duration events alike. An executable
+    that arrives while no manager build is open on its thread (an eager
+    op, a conversion, a bare jit) is booked to `(unregistered)`."""
     if event == _JAX_CACHE_HIT:
         _tls.jax_cache_hits = getattr(_tls, "jax_cache_hits", 0) + 1
+        _tls.stray_hit = not getattr(_tls, "building", False)
+    elif event == _JAX_BACKEND_COMPILE and _MANAGER is not None \
+            and not getattr(_tls, "building", False):
+        hit, _tls.stray_hit = getattr(_tls, "stray_hit", False), False
+        _MANAGER.book_build(UNREGISTERED, "jax_cache" if hit else "compiled",
+                            xla_s=secs[0], count=1,
+                            slowest=[(secs[0], fun_name)])
 
 
 jax.monitoring.register_event_listener(_on_jax_event)
+jax.monitoring.register_event_duration_secs_listener(_on_jax_event)
+
+
+@contextlib.contextmanager
+def _building():
+    """What jax compiles on this thread meanwhile is a manager build's."""
+    outer, _tls.building = getattr(_tls, "building", False), True
+    try:
+        yield
+    finally:
+        _tls.building = outer
+
+
+def _timed(entry: Any, fn: Callable, args: Tuple, kwargs: Dict) -> Tuple:
+    """(fn(*args, **kwargs), wall s, calling-thread CPU s), annotated
+    `lgbm:<name>` unless an `instrument_kernel` of that name does it."""
+    with (TraceAnnotation(entry.annotation) if entry.annotation
+          else _NO_NOTE):
+        w0, c0 = time.perf_counter(), time.thread_time()
+        out = fn(*args, **kwargs)
+        cpu = time.thread_time() - c0       # inside the wall interval
+        return out, time.perf_counter() - w0, cpu
 
 
 def _count_donated_bytes(donate_argnums: Tuple[int, ...],
@@ -111,6 +153,7 @@ class SharedEntry:
                  store: bool = True, profiled: bool = False) -> None:
         self.manager = manager
         self.name = name
+        self.annotation: Optional[str] = ANNOTATION_PREFIX + name
         self.digest = digest
         self.donate_argnums = tuple(donate_argnums)
         # store=False: compile + share in-memory, but never persist —
@@ -158,7 +201,7 @@ class SharedEntry:
         if self.donate_argnums:
             _count_donated_bytes(self.donate_argnums, args)
         if not mgr.aot_enabled:
-            return self.jit_fn()(*args, **statics)
+            return mgr.call(self, self.jit_fn(), args, statics)
         key = self.key_for(args, statics)
         exe = mgr.executables.get(key)
         if exe is None:
@@ -168,13 +211,14 @@ class SharedEntry:
         # static args are baked into the compiled executable: call
         # positionally with the traced args only
         if key not in mgr.unproven:
-            return exe(*args)
+            return mgr.call(self, exe, args)
         try:
-            out = exe(*args)
+            out = mgr.call(self, exe, args)
         except Exception as exc:
             # a bad BLOB, found late; anything compiled here raises above
             mgr.drop_stored(self.name, key, exc)
-            return mgr.acquire(self, key, args, statics)(*args)
+            return mgr.call(self, mgr.acquire(self, key, args, statics),
+                            args)
         mgr.proven(key)
         return out
 
@@ -204,6 +248,7 @@ class JitEntry:
                  donate_argnums: Tuple[int, ...] = ()) -> None:
         self.manager = manager
         self.name = name
+        self.annotation: Optional[str] = ANNOTATION_PREFIX + name
         self.donate_argnums = tuple(donate_argnums)
         self._jfn = jfn
 
@@ -217,20 +262,26 @@ class JitEntry:
             return None
 
     def __call__(self, *args: Any, **kwargs: Any) -> Any:
+        mgr = get_manager()     # the live one: entries outlive a reset
         if self.donate_argnums:
             _count_donated_bytes(self.donate_argnums, args)
         before = self._cache_size()
-        t0 = time.perf_counter()
-        out = self._jfn(*args, **kwargs)
+        hits = getattr(_tls, "jax_cache_hits", 0)
+        with _building():
+            out, wall, cpu = _timed(self, self._jfn, args, kwargs)
         if before is not None:
             after = self._cache_size()
             if after is not None and after > before:
-                # first call traces+compiles+runs; attributing the whole
-                # call to compile slightly overcounts by one execution
-                self.manager.count("jit_compiles")
+                mgr.count("jit_compiles")
                 # each cache growth is one more distinct traced program
-                self.manager.count("programs", after - before)
-                self.manager.add_time("compile", time.perf_counter() - t0)
+                mgr.count("programs", after - before)
+                # trace, lower, XLA or jax's cache AND one execution, not
+                # split; none of it counted among the calls' seconds
+                served = getattr(_tls, "jax_cache_hits", 0) > hits
+                mgr.book_build(self.name, "jax_cache" if served
+                               else "compiled", call_s=wall)
+                wall = cpu = 0.0
+        mgr.book_call(self.name, wall, cpu)
         return out
 
 
@@ -242,6 +293,13 @@ class CompileManager:
         self.executables: "collections.OrderedDict[str, Any]" = \
             collections.OrderedDict()
         self.stats: Dict[str, float] = {}
+        # name -> [calls, call_wall_s, call_cpu_s, builds]; `phase` files a
+        # build (construct / first_call / steady: io/dataset.py, basic.py);
+        # `marks`: (perf_counter, thread_time, {name: (calls, wall, cpu)})
+        # at the end of each of the last 1,024 of `updates` update()s
+        self._rows: Dict[str, list] = {}
+        self.phase, self.updates = "construct", 0
+        self.marks: "collections.deque" = collections.deque(maxlen=1024)
         self._lock = threading.Lock()
         # RLock: _compile holds it across .lower(), whose trace re-enters
         # it through fused.py _bind_tables on the same thread
@@ -273,6 +331,55 @@ class CompileManager:
     def snapshot(self) -> Dict[str, float]:
         with self._lock:
             return dict(self.stats)
+
+    def book_call(self, name: str, wall: float, cpu: float) -> None:
+        with self._lock:
+            row = self._rows.setdefault(name, [0, 0.0, 0.0, []])
+            row[0] += 1
+            row[1] += wall
+            row[2] += cpu
+
+    def call(self, entry: Any, fn: Callable, args: Tuple,
+             kwargs: Optional[Dict[str, Any]] = None) -> Any:
+        """One call of `entry`: both clocks around the executable only."""
+        out, wall, cpu = _timed(entry, fn, args, kwargs or {})
+        self.book_call(entry.name, wall, cpu)
+        return out
+
+    def book_build(self, name: str, source: str, **seconds: Any) -> None:
+        """One build of `name`: phase, source (`compiled`, `jax_cache`,
+        `aot_store`), each step's seconds (`trace_lower_s`, `xla_s`, `load_s`;
+        `call_s`: a plain jit's whole first call), `updates` ended before
+        it. `(unregistered)` keeps one row a phase, source and `updates`."""
+        new = dict(phase=self.phase, source=source, at=time.perf_counter(),
+                   updates=self.updates, **seconds)
+        with self._lock:
+            builds = self._rows.setdefault(name, [0, 0.0, 0.0, []])[3]
+            old = name == UNREGISTERED and next(
+                (b for b in builds if (b["phase"], b["source"], b["updates"])
+                 == (new["phase"], source, new["updates"])), None)
+            if old:
+                top = sorted(old["slowest"] + new["slowest"])[-3:]
+                old.update(count=old["count"] + 1, slowest=top,
+                           xla_s=old["xla_s"] + new["xla_s"])
+            else:
+                builds.append(new)
+
+    def mark_update(self) -> None:
+        """The end of one `Booster.update()`."""
+        self.phase, self.updates = "steady", self.updates + 1
+        with self._lock:
+            self.marks.append((time.perf_counter(), time.thread_time(), {
+                n: tuple(r[:3]) for n, r in self._rows.items() if r[0]}))
+
+    def snapshot_entries(self) -> Dict[str, Dict[str, Any]]:
+        """{name: {calls, call_wall_s, call_cpu_s, builds: [...]}}, a
+        copy; wall less CPU is time blocked inside the runtime."""
+        with self._lock:
+            return {n: {"calls": r[0], "call_wall_s": r[1],
+                        "call_cpu_s": r[2],
+                        "builds": [dict(b) for b in r[3]]}
+                    for n, r in self._rows.items()}
 
     # -- registration ---------------------------------------------------
     def shared_entry(self, name: str, sig: Any,
@@ -324,7 +431,7 @@ class CompileManager:
                 statics: Dict[str, Any]) -> Any:
         """Executable for one concrete call: store load, else compile
         (+persist). `args` may be avals."""
-        with self._key_lock(key):
+        with self._key_lock(key), _building():
             exe = self.executables.get(key)
             if exe is not None:
                 self.count("cache_hits")
@@ -349,6 +456,7 @@ class CompileManager:
                 return None
             exe = load_executable(payload)
             self.add_time("aot_load", time.perf_counter() - t0)
+            self.book_build(name, "aot_store", load_s=time.perf_counter() - t0)
             self.count(counter)
             with self._lock:
                 self.unproven.add(key)
@@ -379,11 +487,14 @@ class CompileManager:
         t1 = time.perf_counter()
         hits = getattr(_tls, "jax_cache_hits", 0)
         with (_metadata_in_cache_key() if entry.profiled
-              else contextlib.nullcontext()):
+              else _NO_NOTE):
             exe = lowered.compile()
         from_jax_cache = getattr(_tls, "jax_cache_hits", 0) > hits
         elapsed = time.perf_counter() - t0
         self.add_time("compile", elapsed)
+        self.book_build(
+            entry.name, "jax_cache" if from_jax_cache else "compiled",
+            trace_lower_s=t1 - t0, xla_s=elapsed - (t1 - t0))
         # distinct-program accounting (obs schema v1.9): every real
         # compile is one program; `lowering_s` isolates the trace+lower
         # span from XLA compile proper
@@ -439,7 +550,8 @@ class CompileManager:
             with self._key_lock(key):
                 if key in self.executables:
                     continue
-                exe = self._load_from_store(key, key, "store_preloads")
+                exe = self._load_from_store("(preload)", key,
+                                            "store_preloads")
                 if exe is not None:
                     self._remember(key, exe)
                     n += 1

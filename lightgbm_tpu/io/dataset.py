@@ -27,6 +27,7 @@ from typing import Any, Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
+from ..compile import get_manager
 from ..config import Config
 from ..obs.spans import span
 from ..utils import log
@@ -341,6 +342,7 @@ class BinnedDataset:
         # --- sampling for bin finding (dataset_loader.cpp:120-165) ---
         sample_cnt = min(config.bin_construct_sample_cnt, n)
         rng = np.random.RandomState(config.data_random_seed)
+        get_manager().phase = "construct"
         with span("dataset/sample", stage="construct/sample"):
             if sample_cnt < n:
                 sample_idx = np.sort(rng.choice(n, size=sample_cnt,

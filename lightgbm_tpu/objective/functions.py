@@ -72,13 +72,24 @@ def _np_weighted_percentile(values: np.ndarray, weights: Optional[np.ndarray],
 def _gradient_kernel(fn):
     """The jit of a pointwise `get_gradients(self, score)` (static self),
     its ops under `lgbm.grad`: the scope the persistent program gives the
-    same step, so one reader serves the per-tree tiers too."""
+    same step, so one reader serves the per-tree tiers too. `self` carries
+    the labels: every dataset builds the entry anew (ROADMAP A6)."""
     @functools.wraps(fn)
     def scoped(self, score):
         with jax.named_scope("lgbm.grad"):
             return fn(self, score)
-    # tpulint: jit-ok(per-objective gradient kernel; static self, stable arity)
-    return jax.jit(scoped, static_argnums=0)
+    jitted = jax.jit(scoped, static_argnums=0)
+    entries = {}
+
+    @functools.wraps(fn)
+    def dispatch(self, score):
+        if self.name not in entries:
+            from ..compile import get_manager
+            entries[self.name] = get_manager().jit_entry(
+                f"objective/get_gradients/{self.name}", jitted)
+        return entries[self.name](self, score)
+    dispatch.lower = jitted.lower       # AOT introspection, as a jit's
+    return dispatch
 
 
 class ObjectiveFunction:

@@ -67,7 +67,8 @@ def _add_stage(stage: str, seconds: float) -> None:
 def setup_line() -> str:
     """`set-up: construct … (sample …, …), state … (…), compile … (…)`:
     what the stage table and the compile manager's always-on counters
-    gained since the last call, in seconds."""
+    gained since the last call, in seconds; then `; built: <entry>
+    <source> <seconds>` for each build since of a second or more."""
     from ..compile import get_manager
     now = {k: v[0] for k, v in stage_seconds().items()}
     snap = get_manager().snapshot()
@@ -82,10 +83,17 @@ def setup_line() -> str:
             groups.setdefault(group, []).append(
                 (part, total - _reported.get(key, 0.0)))
         _reported.update(now)
+        since = _reported.get("built", 0.0)
+        _reported["built"] = time.perf_counter()
+    built = [f"{name} {b['source']} {secs:.1f}"
+             for name, row in get_manager().snapshot_entries().items()
+             for b in row["builds"] if b["at"] > since and (secs := sum(
+                 v for k, v in b.items() if k.endswith("_s"))) >= 1.0]
     return "set-up: " + ", ".join(
         f"{group} {sum(dt for _, dt in parts):.1f} s ("
         + ", ".join(f"{part} {dt:.1f}" for part, dt in parts) + ")"
-        for group, parts in groups.items())
+        for group, parts in groups.items()) + (
+            "; built: " + ", ".join(built) if built else "")
 
 
 @contextlib.contextmanager
@@ -139,6 +147,8 @@ def instrument_kernel(fn, phase: str, name: Optional[str] = None,
     on the synchronous test path it covers the compute too."""
     label = name or f"kernel/{phase}"
     annotation = ANNOTATION_PREFIX + label
+    if getattr(fn, "annotation", None) == annotation:
+        fn.annotation = None    # a manager entry: this wrapper names it
     if collective is not None:
         coll_op, coll_bytes = collective[0], int(collective[1])
         coll_axis = collective[2] if len(collective) > 2 else ""
