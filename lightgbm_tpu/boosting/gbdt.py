@@ -213,9 +213,8 @@ class GBDT:
 
         # bagging state (reference GBDT::ResetBaggingConfig, gbdt.cpp:700)
         self._bag_rng = np.random.RandomState(config.bagging_seed)
-        self.bag_data_cnt = self.num_data
         self._full_perm = jnp.arange(self.num_data, dtype=jnp.int32)
-        self._perm = self._full_perm
+        self._set_bag(None, self.num_data)
         self._reset_boosting_state()
 
     def execution_plan(self) -> Dict[str, object]:
@@ -245,6 +244,13 @@ class GBDT:
             # tier: the tree's splits replayed over the resident planes
             plan["row_traverse"] = getattr(self._fused,
                                            "row_traverse_method", "xla")
+        if plan["sampling"] is not None:
+            # how a sampled tree's rows reach lane order: the fused
+            # tiers compact the bag flag with one pass of the partition
+            # kernel, the host loop indexes by the permutation
+            plan["bag_layout"] = ("partition-compaction"
+                                  if self._fused is not None
+                                  else "permutation")
         if self._fused is not None:
             # where the [code_planes, lanes] planes are packed ("host":
             # no device program, nothing to compile), and on a mesh the
@@ -352,6 +358,31 @@ class GBDT:
             self._fused_state = None
 
     # ------------------------------------------------------------------
+    def _set_bag(self, in_bag, count: int) -> None:
+        """The rows the next trees are grown on: ``in_bag`` a [n] bool
+        flag (host or device), None when no row is left out; ``count``
+        how many it keeps."""
+        if count >= self.num_data:
+            in_bag = None
+        self._in_bag = None if in_bag is None else jnp.asarray(in_bag)
+        self.bag_data_cnt = int(count)
+        self._perm_of_bag = None
+
+    @property
+    def _perm(self) -> jax.Array:
+        """The bag as a permutation, [bag rows | the rest], ascending on
+        both sides, for the consumers that index by it (the host-loop
+        learners, a checkpoint): derived from the flag on first read,
+        kept until the bag changes. The fused growers never ask."""
+        if self._in_bag is None:
+            return self._full_perm
+        if self._perm_of_bag is None:
+            flag = np.asarray(self._in_bag)
+            self._perm_of_bag = jnp.asarray(np.concatenate(
+                [np.flatnonzero(flag), np.flatnonzero(~flag)]
+            ).astype(np.int32))
+        return self._perm_of_bag
+
     def _bagging(self, iteration: int) -> None:
         """Per-iteration row subsetting (reference GBDT::Bagging,
         gbdt.cpp:209; pos/neg bagging for binary)."""
@@ -368,15 +399,11 @@ class GBDT:
             r = self._bag_rng.rand(n)
             keep = np.where(is_pos, r < cfg.pos_bagging_fraction,
                             r < cfg.neg_bagging_fraction)
-            bag = np.flatnonzero(keep)
         else:
             cnt = max(1, int(n * cfg.bagging_fraction))
-            bag = self._bag_rng.choice(n, size=cnt, replace=False)
-            bag.sort()
-        oob = np.setdiff1d(np.arange(n, dtype=np.int64), bag, assume_unique=True)
-        perm = np.concatenate([bag, oob]).astype(np.int32)
-        self._perm = jnp.asarray(perm)
-        self.bag_data_cnt = len(bag)
+            keep = np.zeros(n, bool)
+            keep[self._bag_rng.choice(n, size=cnt, replace=False)] = True
+        self._set_bag(keep, int(keep.sum()))
 
     # ------------------------------------------------------------------
     def train_one_iter(self, gradients: Optional[np.ndarray] = None,
@@ -587,7 +614,7 @@ class GBDT:
         k = self.num_tree_per_iteration
         for c in range(k):
             ta, leaf_of_row = self._fused.grow_device(
-                self._grad[c], self._hess[c], self._perm, self.bag_data_cnt)
+                self._grad[c], self._hess[c], self._in_bag)
             pending = PendingTree(self._fused, ta)
             pending.apply_shrinkage(self.shrinkage_rate)
             vals = pending.leaf_values_device()
@@ -977,8 +1004,8 @@ class GBDT:
         score = np.asarray(self.train_score.score[class_id])
         label = np.asarray(self.train_data.metadata.label)
         residual = label - score
-        if self.bag_data_cnt < self.num_data:
-            bag_rows = np.asarray(self._perm[:self.bag_data_cnt])
+        if self._in_bag is not None:
+            bag_rows = np.flatnonzero(np.asarray(self._in_bag))
             out = obj.renew_tree_output(leaf_idx[bag_rows], residual[bag_rows],
                                         tree.num_leaves)
         else:
@@ -1389,7 +1416,7 @@ class GBDT:
             "bag_rng": _pack_rng(self._bag_rng),
             "best_iter": int(self.best_iter),
         }
-        if self._perm is not self._full_perm:
+        if self._in_bag is not None:
             st["perm"] = np.asarray(self._perm)
         tl = self.tree_learner
         if getattr(tl, "_col_rng", None) is not None:
@@ -1443,11 +1470,15 @@ class GBDT:
         if "class_need_train" in state:
             self.class_need_train = [bool(v)
                                      for v in state["class_need_train"]]
-        self.bag_data_cnt = int(state.get("bag_data_cnt", self.num_data))
+        cnt = int(state.get("bag_data_cnt", self.num_data))
         if "bag_rng" in state:
             _unpack_rng(self._bag_rng, state["bag_rng"])
         if "perm" in state:
-            self._perm = jnp.asarray(np.asarray(state["perm"], np.int32))
+            keep = np.zeros(self.num_data, bool)
+            keep[np.asarray(state["perm"])[:cnt]] = True
+            self._set_bag(keep, cnt)
+        else:
+            self._set_bag(None, self.num_data)
         self.best_iter = int(state.get("best_iter", 0))
         tl = self.tree_learner
         if "tl_col_rng" in state and getattr(tl, "_col_rng", None) is not None:
@@ -1734,14 +1765,14 @@ def _largest_k_mask(x, k: int):
 def _goss_sample_device(grad, hess, seed, *, top_k: int, other_k: int):
     """Device-side GOSS round (reference goss.hpp:111-147): top_k rows
     by sum_c |g*h|, other_k uniform from the rest upweighted by
-    (n - top_k) / other_k, and the stable [bag | oob] permutation —
-    all without host round-trips of [C, N] arrays, and without a
-    [N]-sized scatter or gather: both selections are masks from an
-    exact k-th value (counting passes over the float bits, no sort),
-    the weighting is a select, and the permutation is the program's one
-    sort, a stable one of the row ids by the mask, so both sides keep
-    ascending row order, exactly the host path's sorted-bag/oob
-    layout."""
+    (n - top_k) / other_k. Returns the weighted (grad, hess) and the
+    bag as a [n] bool flag — all without host round-trips of [C, N]
+    arrays, and with no sort and no [N]-sized scatter or gather: both
+    selections are masks from an exact k-th value (counting passes over
+    the float bits), the weighting is a select, and the flag is the two
+    masks' union. What lays the flagged rows out in lane order, ascending
+    as the host path's sorted bag, is the grower's compaction pass
+    (treelearner/fused.py _compact_bag)."""
     with jax.named_scope("lgbm.goss_sample"):
         n = grad.shape[1]
         weight = jnp.sum(jnp.abs(grad * hess), axis=0)            # [n]
@@ -1753,8 +1784,8 @@ def _goss_sample_device(grad, hess, seed, *, top_k: int, other_k: int):
         multiply = jnp.float32((n - top_k) / other_k)
         grad = jnp.where(sampled[None, :], grad * multiply, grad)
         hess = jnp.where(sampled[None, :], hess * multiply, hess)
-        perm = jnp.argsort(~(is_top | sampled), stable=True).astype(jnp.int32)
-    return grad, hess, perm
+        in_bag = is_top | sampled
+    return grad, hess, in_bag
 
 
 @functools.lru_cache(maxsize=1)
@@ -1786,19 +1817,18 @@ class GOSS(GBDT):
         cfg = self.config
         n = self.num_data
         if iteration < int(1.0 / cfg.learning_rate):
-            self._perm = self._full_perm
-            self.bag_data_cnt = n
+            self._set_bag(None, n)
             return
         top_k = max(1, int(n * cfg.top_rate))
         other_k = max(1, min(int(n * cfg.other_rate), n - top_k))
-        if self._perm is self._full_perm:
+        if self._in_bag is None:
             log.info("GOSS sampling starts at iteration %d: top_k=%d "
                      "other_k=%d, %d of %d rows a tree", iteration, top_k,
                      other_k, top_k + other_k, n)
         seed = jnp.int32(self._bag_rng.randint(1 << 31))
-        self._grad, self._hess, self._perm = _goss_sample_entry()(
+        self._grad, self._hess, in_bag = _goss_sample_entry()(
             self._grad, self._hess, seed, top_k=top_k, other_k=other_k)
-        self.bag_data_cnt = top_k + other_k
+        self._set_bag(in_bag, top_k + other_k)
 
 
 class RF(GBDT):

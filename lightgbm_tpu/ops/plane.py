@@ -1287,3 +1287,15 @@ def set_gh_packed(data: jax.Array, layout: PlaneLayout, packed_f32):
     if gh.shape[1] < data.shape[1]:
         gh = jnp.pad(gh, ((0, 0), (0, data.shape[1] - gh.shape[1])))
     return jax.lax.dynamic_update_slice(data, gh, (layout.grad, 0))
+
+
+def sign_route_scalars(plane_idx: int):
+    """route_scalars of the "split" on the sign bit of plane
+    ``plane_idx``: the bit read as a one-bit code by the logical shift
+    of `_code_from_col32`, threshold 0, no missing bin, no EFB, not
+    categorical — a lane whose word is non-negative goes left. How a
+    per-row flag reaches the partition kernels, which know only
+    routing scalars (the bag of a sampled tree, treelearner/fused.py
+    _compact_bag)."""
+    return jnp.asarray([plane_idx, 31, 1, 0, 0, -1]
+                       + [0] * (ROUTE_SCALARS - 6), jnp.int32)

@@ -32,7 +32,7 @@ Coverage: numerical AND categorical features (one-vs-rest + sorted
 many-vs-many with the left-set bitset materialized on device and
 routed through the partition kernel's prefetched scalars), serial and
 sharded-data-parallel learners, any objective without leaf renewal,
-bagging via a host-provided permutation, per-tree feature_fraction,
+bagging via a per-row bag flag (_compact_bag), per-tree feature_fraction,
 max_depth, basic monotone constraints, L1/L2/max_delta_step/path
 smoothing, forced splits (BFS phase before the best-first loop) and
 feature_fraction_bynode (per-scan-event masks). Interaction
@@ -43,7 +43,6 @@ is named by fused_reject_reason and warned about loudly.
 from __future__ import annotations
 
 import contextlib
-import math
 from typing import Dict, NamedTuple, Optional
 
 import numpy as np
@@ -215,7 +214,7 @@ class FusedSerialGrower:
         # device (13.2M x 500 groups = 6.6 GB; at G < 128 the TPU pads
         # every row to a 128-lane tile): the code planes are packed on
         # the host (plane.pack_codes_host) and uploaded in their final
-        # form. Row sampling gathers and traverses the resident code
+        # form. Row sampling compacts and traverses the resident code
         # planes instead (_grow_tree)
         self.num_features = dataset.num_features
         mappers = dataset.bin_mappers
@@ -434,7 +433,6 @@ class FusedSerialGrower:
         # bagging the out-of-bag rows are never partitioned and the
         # fallback is the tree re-traversal
         self._score_from_partition = not bag_active(config)
-        self._bag_cap = None      # _bag_capacity
 
         # multi-chip: name of the mesh axis to psum histograms/counts
         # over (set by the data-parallel wrapper; None on one chip)
@@ -474,7 +472,7 @@ class FusedSerialGrower:
                 "fused/grow_tree", sig,
                 lambda: jax.jit(
                     self._entry_grow_tree,
-                    static_argnames=("compute_score_update", "bag_cap")),
+                    static_argnames=("compute_score_update",)),
                 profiled=True)
             self._iter_entry = self._mgr.shared_entry(
                 "fused/train_iter", sig,
@@ -497,8 +495,7 @@ class FusedSerialGrower:
         else:
             self._grow_jit = instrument_kernel(
                 jax.jit(self._entry_grow_tree,  # tpulint: jit-ok(manager-disabled fallback branch)
-                        static_argnames=("compute_score_update",
-                                         "bag_cap")),
+                        static_argnames=("compute_score_update",)),
                 "fused", name="fused/grow_tree")
             self._iter_jit = instrument_kernel(
                 jax.jit(self._entry_train_iter, donate_argnums=1),  # tpulint: jit-ok(manager-disabled fallback branch)
@@ -613,14 +610,13 @@ class FusedSerialGrower:
             "efb_hist": self._efb_hist is not None,
         }
 
-    def _entry_grow_tree(self, tables, codes_planes, grad, hess, perm,
-                         bag_cnt, feature_mask, mv=None,
-                         compute_score_update: bool = True,
-                         bag_cap: Optional[int] = None):
+    def _entry_grow_tree(self, tables, codes_planes, grad, hess, in_bag,
+                         n_valid, feature_mask, mv=None,
+                         compute_score_update: bool = True):
         with self._bind_tables(tables):
-            return self._grow_tree(codes_planes, grad, hess, perm,
-                                   bag_cnt, feature_mask, mv,
-                                   compute_score_update, bag_cap)
+            return self._grow_tree(codes_planes, grad, hess, in_bag,
+                                   n_valid, feature_mask, mv,
+                                   compute_score_update)
 
     def _entry_train_iter(self, tables, data, feature_mask, shrinkage,
                           bias, n_valid, key=None):
@@ -667,11 +663,10 @@ class FusedSerialGrower:
             n = self.actual_rows
             cp_aval = aval((Ly.code_planes, Ly.num_lanes), jnp.int32)
             fvec = aval((n,), jnp.float32)
-            perm_aval = aval((Ly.num_rows,), jnp.int32)
             mv_aval = (aval(self._mv_dev.shape, jnp.int32)
                        if self._mv_dev is not None else None)
             self._grow_entry.add_spec(
-                (t_avals, cp_aval, fvec, fvec, perm_aval, i32s, mask_aval,
+                (t_avals, cp_aval, fvec, fvec, None, i32s, mask_aval,
                  mv_aval), {"compute_score_update": True})
 
     def _branch_tile(self, cap: int) -> int:
@@ -835,7 +830,11 @@ class FusedSerialGrower:
         rscal = plane.route_scalars(self.layout, feature, thr, dl, miss_bin,
                                     self._efb_dev, is_cat=cat,
                                     cat_bitset=bits)
+        return self._partition(data, start, count, rscal)
 
+    def _partition(self, data, start, count, rscal):
+        """Stable partition of the window [start, start + count) by the
+        routing ``rscal`` with the grower's kernel: (data', nleft)."""
         if self._part_method in ("pallas", "pallas2"):
             # dynamic-grid partition: one lowered kernel for every leaf
             # size (ops/plane.py cap=None) — no capacity switch
@@ -1548,49 +1547,39 @@ class FusedSerialGrower:
         return jnp.where(valid, out, st.leaf_output).astype(jnp.float32)
 
     # ------------------------------------------------------------------
-    def _grow_tree(self, codes_planes, grad, hess, perm, bag_cnt,
+    def _grow_tree(self, codes_planes, grad, hess, in_bag, n_valid,
                    feature_mask, mv=None,
-                   compute_score_update: bool = True,
-                   bag_cap: Optional[int] = None):
+                   compute_score_update: bool = True):
         """Per-tree program for the non-persistent path. Returns
         (tree arrays dict, leaf_of_row [n] in ORIGINAL row order or
         None). ``codes_planes`` / ``mv`` are the RESIDENT row-order
-        planes ([code_planes, R] / slot-major [K, n]) and ``grad`` /
-        ``hess`` are in row order. ``bag_cap`` None: every row is in
-        the bag (a ranking or custom objective, multiclass, DART, GOSS
-        before sampling starts) and the tree grows on a fresh planar
-        state laid out from the resident planes as they lie
-        (`lgbm.build_state`). Otherwise (bagging, GOSS, RF with rows
-        left out) the first ``bag_cap`` rows of ``perm`` — a static
-        capacity that holds the bag (_bag_capacity) — are gathered into
-        lane order here, once per TREE (`lgbm.bag_gather`). Either way
-        every row's leaf comes from replaying the tree's splits over
-        the resident planes (`lgbm.row_traverse`): one path, and no
-        row-sized scatter of the partition's leaf assignment back to
-        row order. The data-parallel grower runs this same function
-        per shard."""
+        planes ([code_planes, R] / slot-major [K, n]), ``grad`` /
+        ``hess`` are in row order and ``n_valid`` (traced) counts the
+        real rows among them. Either way the planar state is laid out
+        from the resident planes as they lie. ``in_bag`` None, a static
+        fact: every row is in the tree (a ranking or custom objective,
+        multiclass, DART, GOSS before sampling starts) and that state is
+        the tree's (`lgbm.build_state`). Otherwise ``in_bag`` is the
+        bag as a [n] bool flag (bagging, GOSS, RF) and ONE stable pass
+        of the partition kernel over all rows compacts the flagged rows
+        to the front (`lgbm.bag_gather`, _compact_bag): its left count
+        is the bag's size, whatever that is, so one program serves
+        every bag. Every row's leaf comes from replaying the tree's
+        splits over the resident planes (`lgbm.row_traverse`): one
+        path, and no row-sized scatter of the partition's leaf
+        assignment back to row order. The data-parallel grower runs
+        this same function per shard, on the shard's slice of the
+        flag."""
         n = self.layout.num_rows
-        if bag_cap is None:
+        if in_bag is None:
             with jax.named_scope("lgbm.build_state"):
                 data = plane.build_data(self.layout, codes_planes, grad,
-                                        hess, rowid=perm, mv=mv)
+                                        hess, mv=mv)
+            bag_cnt = n_valid
         else:
             with jax.named_scope("lgbm.bag_gather"):
-                # ONE gather for codes, gradients and hessians: a [N]-
-                # sized gather pays its toll per index, not per plane
-                rows = perm[:bag_cap]
-                C, R = codes_planes.shape
-                src = jnp.concatenate(
-                    [codes_planes[:, :grad.shape[0]],
-                     plane.f32_as_i32(grad)[None],
-                     plane.f32_as_i32(hess)[None]], axis=0)
-                bag = jnp.take(src, rows, axis=1)
-                data = plane.build_data(
-                    self.layout,
-                    jnp.pad(bag[:C], ((0, 0), (0, R - bag_cap))),
-                    plane.i32_as_f32(bag[C]), plane.i32_as_f32(bag[C + 1]),
-                    rowid=rows,
-                    mv=None if mv is None else jnp.take(mv, rows, axis=1))
+                data, bag_cnt = self._compact_bag(
+                    codes_planes, grad, hess, in_bag, n_valid, mv)
         ta, st = self._grow_tree_core(data, bag_cnt, feature_mask)
 
         leaf_of_row = None
@@ -1599,44 +1588,49 @@ class FusedSerialGrower:
                 leaf_of_row = self.traverse_planes(ta, codes_planes)[:n]
         return ta, leaf_of_row
 
-    def _bag_capacity(self, bag_cnt: int) -> int:
-        """Static row capacity of the bag's gather. The first bag sets
-        it with a slack of eight standard deviations of a by-label draw
-        (a binomial's is at most sqrt(n) / 2), and it is kept while the
-        bag fits it with less than two slacks to spare: a bag whose
-        size is drawn anew each round (pos/neg bagging) keeps ONE grow
-        program. Rows between the bag and the capacity lie outside
-        every window (``bag_cnt`` is traced), so the capacity never
-        shows in a result."""
-        n = self.actual_rows
-        tile = self.layout.max_tile
-        slack = max(tile, 4 * math.isqrt(n))
-        cap = self._bag_cap
-        if cap is None or not cap - 2 * slack - tile <= bag_cnt <= cap:
-            cap = self._bag_cap = min(
-                -(-(int(bag_cnt) + slack) // tile) * tile, n)
-        return cap
+    def _compact_bag(self, codes_planes, grad, hess, in_bag, n_valid,
+                     mv=None):
+        """(planar state with the bag in lanes [0, count) in ascending
+        row order, count): the state of ALL rows, the flag riding the
+        row-id plane's sign bit (set = left out; pad lanes too), then a
+        stable split of the window [0, n_valid) on that bit by the
+        grower's own partition kernel at its largest tile — to the
+        kernel a split like any other, routed by scalars. No per-index
+        gather and no permutation: the rows left out end up behind the
+        bag, outside every window of the tree."""
+        Ly = self.layout
+        lanes = jnp.arange(Ly.num_lanes, dtype=jnp.int32)
+        keep = jnp.pad(in_bag, (0, Ly.num_lanes - in_bag.shape[0]))
+        data = plane.build_data(
+            Ly, codes_planes, grad, hess, mv=mv,
+            rowid=jnp.where(keep, lanes, lanes | jnp.int32(-1 << 31)))
+        return self._partition(data, jnp.int32(0), n_valid,
+                               plane.sign_route_scalars(Ly.rowid))
 
-    def grow_device(self, grad, hess, perm, bag_cnt,
+    @staticmethod
+    def _count_tree_layout(in_bag) -> None:
+        """One tree dispatched, by how its rows are laid out: a host
+        counter, no sync."""
+        from .. import obs
+        reg = obs.active()
+        if reg is not None:
+            reg.inc("fused.full_state_builds" if in_bag is None
+                    else "fused.bag_compactions")
+
+    def grow_device(self, grad, hess, in_bag=None,
                     compute_score_update=True):
-        """Returns (tree_arrays dict of device arrays, leaf_of_row)."""
-        if bag_cnt >= self.actual_rows:
-            # no row left out (also GOSS before sampling starts, a
-            # bagging round that keeps every row): perm is the identity
-            bag_cap = None
-            perm_dev = jnp.arange(self.layout.num_rows, dtype=jnp.int32)
-        else:
-            bag_cap = self._bag_capacity(bag_cnt)
-            perm_dev = jnp.asarray(perm, jnp.int32)
+        """Returns (tree_arrays dict of device arrays, leaf_of_row).
+        ``in_bag``: [n] bool device flag of the rows the tree is grown
+        on, None for all of them."""
+        self._count_tree_layout(in_bag)
         ta, leaf = self._grow_jit(self._tables(), self.codes_planes(),
-                                  grad, hess, perm_dev, jnp.int32(bag_cnt),
+                                  grad, hess, in_bag,
+                                  jnp.int32(self.actual_rows),
                                   self.feature_masks_for_tree(),
                                   self._mv_dev,
-                                  compute_score_update=compute_score_update,
-                                  bag_cap=bag_cap)
+                                  compute_score_update=compute_score_update)
         if leaf is not None and leaf.shape[0] != self.actual_rows:
-            # row-bucketed layout: pad lanes scattered into positions
-            # >= actual_rows (build_data's arange rowid continuation)
+            # row-bucketed layout: the traverse covers the pad rows too
             leaf = leaf[:self.actual_rows]
         return ta, leaf
 

@@ -856,74 +856,46 @@ class FusedDataParallelGrower(FusedSerialGrower):
                 shape, sharding, packed)
         return self._cp_sh
 
-    def _sharded_bag_views(self, perm, bag_cnt):
-        """Device-resident (per-shard local perms, per-shard counts) and
-        the static gather capacity that holds the fullest shard's bag
-        (None when no row is left out), for a bag. Cached on the perm
-        object so the k class trees of one iteration (and consecutive
-        no-bagging iterations) skip the O(n) host pass and the
-        [n]-sized upload entirely."""
-        key = (id(perm), int(bag_cnt))
-        if getattr(self, "_bag_cache_key", None) == key:
-            return self._bag_cache_val
-        D, sr, n = self.num_shards, self.shard_rows, self.global_rows
-        spec_rows = NamedSharding(self.mesh, P("data", None))
-        if bag_cnt >= n:
-            # no bagging: identity local perms, true per-shard row counts
-            perm_np = np.broadcast_to(
-                np.arange(sr, dtype=np.int32)[None], (D, sr))
-            counts = np.asarray(
-                [max(0, min(n - d * sr, sr)) for d in range(D)], np.int32)
-            bag_cap = None
-        else:
-            perm_np, counts = shard_bag_permutation(perm, bag_cnt, D, sr)
-            bag_cap = self._bag_capacity(int(counts.max()))
-        val = (jax.device_put(jnp.asarray(perm_np), spec_rows),
-               jax.device_put(jnp.asarray(counts),
-                              NamedSharding(self.mesh, P("data"))),
-               bag_cap)
-        self._bag_cache_key = key
-        self._bag_cache_ref = perm      # keep id() stable
-        self._bag_cache_val = val
-        return val
-
     def _grow_mc_jit_build(self):
-        def grow(cp, perm, cnt, g, h, mask, bag_cap):
-            def body(cp_l, perm_l, cnt_l, g_l, h_l, mask_):
+        rows = P("data", None)
+
+        def grow(cp, in_bag, cnt, g, h, mask):
+            def body(cp_l, flag_l, cnt_l, g_l, h_l, mask_):
                 # the serial per-tree program on the shard's own rows:
-                # local bag, local planes, psum'd histograms
+                # local planes, the shard's slice of the bag flag (its
+                # own compaction pass counts its bag), psum'd histograms
                 ta, leaf = self._grow_tree(
-                    cp_l, g_l[0], h_l[0], perm_l[0], cnt_l[0], mask_,
-                    bag_cap=bag_cap)
+                    cp_l, g_l[0], h_l[0],
+                    None if flag_l is None else flag_l[0], cnt_l[0], mask_)
                 return ta, leaf[:self.shard_rows][None]
 
+            # in_bag None (no row left out) is an empty subtree: its
+            # spec applies to nothing
             return functools.partial(
                 shard_map, mesh=self.mesh, check_vma=False,
-                in_specs=(P(None, "data"), P("data", None), P("data"),
-                          P("data", None), P("data", None), P()),
-                out_specs=(P(), P("data", None)))(body)(
-                    cp, perm, cnt, g, h, mask)
+                in_specs=(P(None, "data"), rows, P("data"), rows, rows, P()),
+                out_specs=(P(), rows))(body)(cp, in_bag, cnt, g, h, mask)
 
         from ..compile import get_manager
         sig, ok = self._mc_signature()
         return get_manager().shared_entry(
             "mc/grow_tree", sig,
-            lambda: jax.jit(grow, static_argnames=("bag_cap",)),  # tpulint: jit-ok(inside a shared_entry builder; the manager dispatches this jit)
+            lambda: jax.jit(grow),  # tpulint: jit-ok(inside a shared_entry builder; the manager dispatches this jit)
             store=ok)
 
-    def grow_device(self, grad, hess, perm, bag_cnt,
+    def grow_device(self, grad, hess, in_bag=None,
                     compute_score_update=True):
         """Sharded per-tree growth (reference
         data_parallel_tree_learner.cpp covers every config through one
         network layer; here every config runs the same while_loop
-        program per shard with psum'd histograms)."""
+        program per shard with psum'd histograms). ``in_bag`` as the
+        serial grower's: every shard gets its rows' slice of the flag,
+        with no host pass over the bag."""
         D, sr, n = self.num_shards, self.shard_rows, self.global_rows
-        perm_dev, counts_dev, bag_cap = self._sharded_bag_views(perm,
-                                                                bag_cnt)
         spec_rows = NamedSharding(self.mesh, P("data", None))
 
-        def pad_rows(v):
-            v = jnp.asarray(v, jnp.float32)
+        def over_shards(v):
+            """[n] -> [D, sr] over the mesh, zero (False) in the pad."""
             v = jnp.pad(v, (0, D * sr - v.shape[0]))
             return jax.device_put(v.reshape(D, sr), spec_rows)
 
@@ -931,15 +903,18 @@ class FusedDataParallelGrower(FusedSerialGrower):
             # the span of the serial per-tree dispatch
             self._grow_mc_tree_jit = instrument_kernel(
                 self._grow_mc_jit_build(), "fused", name="fused/grow_tree")
+        self._count_tree_layout(in_bag)
         with collective_span("fused_tree_psum", self._tree_psum_bytes,
                              axis="data"):
             ta, leaf = self._grow_mc_tree_jit(
-                self._codes_planes_sharded(), perm_dev, counts_dev,
-                pad_rows(grad), pad_rows(hess),
-                self.feature_masks_for_tree(), bag_cap=bag_cap)
+                self._codes_planes_sharded(),
+                None if in_bag is None else over_shards(in_bag),
+                self._n_per_shard,
+                over_shards(jnp.asarray(grad, jnp.float32)),
+                over_shards(jnp.asarray(hess, jnp.float32)),
+                self.feature_masks_for_tree())
         leaf_of_row = leaf.reshape(-1)[:n] if compute_score_update else None
         return ta, leaf_of_row
-
 
 
 def create_parallel_learner(kind: str, dataset: BinnedDataset,

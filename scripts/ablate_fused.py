@@ -12,16 +12,15 @@ cfg = Config.from_params({"objective":"binary","num_leaves":255,"max_bin":255,"v
 ds = BinnedDataset.from_matrix(X, cfg, label=y)
 grad = jnp.asarray(rng.randn(N).astype(np.float32))
 hess = jnp.asarray(np.ones(N, dtype=np.float32))
-perm = jnp.arange(N, dtype=jnp.int32)
 
 def time_grow(tag, grower):
     t0=time.time()
-    ta, lo = grower.grow_device(grad, hess, perm, N)
+    ta, lo = grower.grow_device(grad, hess)
     jax.block_until_ready(lo)
     compile_t = time.time()-t0
     t0=time.time()
     for _ in range(3):
-        ta, lo = grower.grow_device(grad, hess, perm, N)
+        ta, lo = grower.grow_device(grad, hess)
     jax.block_until_ready(lo)
     print(f"{tag}: compile {compile_t:.1f}s, steady {(time.time()-t0)/3*1e3:.0f} ms/tree", flush=True)
 

@@ -92,9 +92,11 @@ def test_fused_dp_uneven_shards():
 def test_fused_dp_sampled_rows_match_serial():
     """GOSS on shards of uneven size: each shard runs the serial grower's
     own per-tree program (FusedSerialGrower._grow_tree) on its resident
-    code planes — local bag gathered from them, every local row's leaf by
-    replaying the splits over them — so no shard holds a row-major table.
-    The third tree is the first grown on a sample."""
+    code planes — its slice of the bag flag compacted out of them by its
+    own partition pass, which counts the local bag; every local row's
+    leaf by replaying the splits over them — so no shard holds a
+    row-major table and the host makes no pass over the bag. The third
+    tree is the first grown on a sample."""
     X, y = _make(n=2001)
     base = {"objective": "binary", "boosting": "goss", "num_leaves": 7,
             "learning_rate": 0.5, "verbose": -1}
@@ -104,6 +106,9 @@ def test_fused_dp_sampled_rows_match_serial():
     from lightgbm_tpu.treelearner.parallel import FusedDataParallelGrower
     assert isinstance(g, FusedDataParallelGrower)
     assert b_dp._gbdt.bag_data_cnt == 400 + 200
+    assert b_dp._gbdt.execution_plan()["bag_layout"] == "partition-compaction"
+    assert b_dp._gbdt._perm_of_bag is None, "nobody asked for a permutation"
+    assert not hasattr(g, "_bag_cache_val")
     assert g._cp_sh.shape == (g.layout.code_planes,
                               g.num_shards * g.layout.num_lanes)
     assert not hasattr(g, "_bins_dev") and not hasattr(g, "_bins_sh")

@@ -33,7 +33,7 @@ CASES = {
     "persistent_quantized_i32": ({"use_quantized_grad": True}, "s32"),
     "per_tree": ({"objective": "multiclass", "num_class": 3}, "f32"),
     "data_parallel": ({"tree_learner": "data", "tpu_mesh_shape": [4]}, "f32"),
-    # row sampling: the bag gathered from the resident planes, every row's
+    # row sampling: the bag compacted out of the resident planes, every row's
     # leaf by replaying the splits over them (FusedSerialGrower._grow_tree)
     "per_tree_sampled": ({"bagging_fraction": 0.5, "bagging_freq": 1}, "f32"),
     "data_parallel_sampled": ({"tree_learner": "data", "tpu_mesh_shape": [4],
@@ -118,10 +118,9 @@ def _lowered(case, g, request):
         return g._grow_mc_jit_build().jit_fn().lower(
             aval((Ly.code_planes, D * Ly.num_lanes), jnp.int32,
                  sharding=on(None, "data")),
-            aval((D, sr), jnp.int32, sharding=on("data", None)),
+            aval((D, sr), jnp.bool_, sharding=on("data", None)),
             aval((D,), jnp.int32, sharding=on("data")), rows, rows,
-            aval((g.num_features,), jnp.bool_, sharding=on()),
-            bag_cap=g._bag_capacity(sr // 2))
+            aval((g.num_features,), jnp.bool_, sharding=on()))
     if case == "data_parallel":
         mesh = Mesh(np.asarray(request.getfixturevalue("four_chips")),
                     ("data",))
@@ -152,13 +151,11 @@ def _lowered(case, g, request):
                                         g._tables())
         args = (tables, aval((Ly.code_planes, Ly.num_lanes), jnp.int32),
                 aval((n,), jnp.float32), aval((n,), jnp.float32),
-                aval((n,), jnp.int32), aval((), jnp.int32),
+                aval((n,), jnp.bool_), aval((), jnp.int32),
                 aval((g.num_features,), jnp.bool_), None)
         return jax.jit(g._entry_grow_tree,
-                       static_argnames=("compute_score_update",
-                                        "bag_cap")).lower(
-            *_on(args, chip), compute_score_update=True,
-            bag_cap=g._bag_capacity(n // 2))
+                       static_argnames=("compute_score_update",)).lower(
+            *_on(args, chip), compute_score_update=True)
     if case == "per_tree":
         (args, statics), = g._grow_entry.specs
         return jax.jit(g._entry_grow_tree,
@@ -243,3 +240,50 @@ def test_persistent_program_holds_no_traverse_kernel(shape, extra, as_on_tpu,
     text = _lowered("persistent_f32", g, request).as_text()
     assert text.count("tpu_custom_call") == 3
     assert "traverse_planes_pallas" not in text
+
+
+# ------------------------------------------------- the bag's one kernel pass
+
+@pytest.mark.parametrize("case", ["per_tree_sampled",
+                                  "data_parallel_sampled"])
+def test_sampled_grow_program_compacts_the_bag_in_one_kernel_pass(
+        case, as_on_tpu, request):
+    """Under `lgbm.bag_gather` the sampled grow program calls the
+    partition kernel ONCE, the kernel the tree's splits run under
+    `lgbm.partition`, and nothing there sorts, gathers or
+    scatters: the bag reaches lane order by the kernel's own stable
+    compaction of a flag, not by a permutation."""
+    if case.startswith("data_parallel") and len(jax.devices()) < 4:
+        pytest.skip("needs 4 (virtual) devices to build the grower")
+    g = _grower(CASES[case][0])
+    text = _lowered(case, g, request).as_text(debug_info=True)
+    locs = dict(re.findall(r"^(#loc\d+) = loc\((.*)\)$", text, re.M))
+
+    def scope_path(ref):
+        """Every name the location holds, its call sites' included."""
+        return [part for nm in re.findall(r'"([^"]*)"', locs[ref])
+                for part in nm.split("/")] + [
+            part for inner in re.findall(r"#loc\d+", locs[ref])
+            for part in scope_path(inner)]
+
+    kernel = re.compile(rf"call @partition_{g._part_method}(_\d+)?")
+    sites = {"lgbm.bag_gather": [], "lgbm.partition": []}
+    under_bag = []
+    for ln in text.splitlines():
+        ref = re.search(r"loc\((#loc\d+)\)\s*$", ln)
+        op = re.search(r'(?:= |^\s+)"?(?:func\.)?'
+                       r'((?:stablehlo|chlo)\.[a-z_]+|call @\w+)', ln)
+        if not ref or not op or ref.group(1) not in locs:
+            continue
+        path = scope_path(ref.group(1))
+        for scope, calls in sites.items():
+            if scope in path and kernel.fullmatch(op.group(1)):
+                calls.append(ln)
+        if "lgbm.bag_gather" in path:
+            under_bag.append(op.group(1))
+    assert len(sites["lgbm.bag_gather"]) == 1, sites
+    assert len(sites["lgbm.partition"]) >= 1, sites
+    assert len(under_bag) > 5, under_bag
+    assert not [op for op in under_bag
+                if "sort" in op or "gather" in op or "scatter" in op], \
+        sorted(set(under_bag))

@@ -280,7 +280,7 @@ def test_unsampled_grow_program_routes_rows_as_the_persistent_tier():
     grad = (np.full(len(y), p0) - y).astype(np.float32)
     hess = np.full(len(y), p0 * (1 - p0), np.float32)
     ta, leaf_of_row = gbdt._fused.grow_device(
-        jnp.asarray(grad), jnp.asarray(hess), gbdt._perm, gbdt.bag_data_cnt)
+        jnp.asarray(grad), jnp.asarray(hess))
     want = persistent.predict(X, pred_leaf=True).reshape(-1)
     assert int(ta["n_leaves"]) == persistent._gbdt.models[0].num_leaves
     assert np.array_equal(np.asarray(leaf_of_row), want)
