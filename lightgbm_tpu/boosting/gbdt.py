@@ -14,6 +14,7 @@ program over the score array. The host drives the iteration loop.
 """
 from __future__ import annotations
 
+import collections
 import functools
 import os
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -34,6 +35,8 @@ from ..utils import log
 
 K_EPSILON = 1e-15
 K_MODEL_VERSION = "v3"
+# DART draws kept on the host for readers: (iteration, dropped iterations)
+DROP_HISTORY = 1024
 
 
 def parse_tree_blocks(text: str) -> List[Tree]:
@@ -882,6 +885,9 @@ class GBDT:
 
     def rollback_one_iter(self) -> None:
         """reference GBDT::RollbackOneIter (gbdt.cpp:421)."""
+        if self._replays_on_device:
+            self._rollback_by_replay()
+            return
         self._materialize_models()
         self._invalidate_fused_state()
         if self.iter <= 0:
@@ -896,6 +902,96 @@ class GBDT:
                 vs.add_tree(tree, c, miss)
         del self.models[-k:]
         self.iter -= 1
+
+    # ------------------------------------------------------------------
+    # whole trees replayed over the resident planes (fused, one chip)
+    # ------------------------------------------------------------------
+    @property
+    def _replays_on_device(self) -> bool:
+        """Score surgery by whole trees (DART's drop and normalize, a
+        rollback) replays them over the resident code planes: the fused
+        learner on one chip, outside the persistent tier."""
+        f = self._fused
+        return (f is not None and not f.is_multichip
+                and not self._fused_persist)
+
+    def _replay_source(self, tree):
+        """(tree arrays, scale, bias) of a model, whose output on a row
+        is the arrays' output x scale + bias."""
+        from ..treelearner.fused import PendingTree
+        if isinstance(tree, PendingTree) and tree._tree is None:
+            return (tree.tree_arrays, tree.pending_shrinkage,
+                    tree.pending_bias)
+        host = tree._tree if isinstance(tree, PendingTree) else tree
+        return self._fused.replay_arrays(host), 1.0, 0.0
+
+    def _replay_subtract(self, forest, rows, weights, biases, kmax: int):
+        """score[c] -= biases[c] + sum over j of weights[c][j] x the
+        output of the forest's row rows[c][j] on every training row, by
+        one call of `boosting/dart_replay`; returns what it took off,
+        [K, n] on the device. The index vector is kmax long whatever the
+        count, padded by its last entry at weight 0."""
+        K = self.num_tree_per_iteration
+        idx = np.zeros((K, kmax), np.int32)
+        w = np.zeros((K, kmax), np.float32)
+        count = np.zeros(K, np.int32)
+        for c in range(K):
+            r = list(rows[c])
+            idx[c, :len(r)] = r
+            idx[c, len(r):] = r[-1] if r else 0
+            w[c, :len(r)] = weights[c]
+            count[c] = len(r)
+        g = self._fused
+        self.train_score.score, dropped = _dart_replay_entry()(
+            forest.routes, forest.values, g.codes_planes(), idx, w, count,
+            np.asarray(biases, np.float32), self.train_score.score,
+            method=g.row_traverse_method, interpret=g._interpret)
+        return dropped
+
+    def _valid_add(self, vs, tree, class_id: int, factor: float) -> None:
+        """A validation set's score += factor x the tree's output, the
+        tree left as it is."""
+        from ..treelearner.fused import PendingTree
+        bins = vs.dataset.device_bins()
+        if isinstance(tree, PendingTree) and tree._tree is None:
+            leaf = self._fused._valid_traverse_jit(tree.tree_arrays, bins)
+        else:
+            tree = tree._tree if isinstance(tree, PendingTree) else tree
+            leaf = tree.leaf_index_binned(
+                bins, self.tree_learner.feature_miss_bin,
+                efb=vs.dataset.device_bundle_tables())
+        vs.score = _score_add_entry()(
+            vs.score, tree.leaf_values_device() * factor, leaf,
+            class_id=class_id)
+
+    def _rollback_by_replay(self) -> None:
+        """The last iteration's trees taken off the training score by
+        one replay over the resident planes, each at its own scale: no
+        tree is materialized and no row-major table is uploaded."""
+        if self.iter <= 0:
+            return
+        k = self.num_tree_per_iteration
+        trees = self.models[-k:]
+        forest, rows, scales, biases, kmax = self._last_trees_in_forest(k)
+        self._replay_subtract(forest, [[r] for r in rows],
+                              [[s] for s in scales], biases, kmax)
+        for c, tree in enumerate(trees):
+            for vs in self.valid_score:
+                self._valid_add(vs, tree, c, -1.0)
+        del self.models[-k:]
+        self.iter -= 1
+
+    def _last_trees_in_forest(self, k: int):
+        """(forest, rows, scales, biases, kmax) that replay the last k
+        trees: here tables of their own and a replay of one tree."""
+        from ..treelearner.fused import ForestTables
+        forest = ForestTables(self._fused, step=k)
+        forest.reserve(k)
+        src = [self._replay_source(t) for t in self.models[-k:]]
+        for c, (ta, _, _) in enumerate(src):
+            forest.put(c, ta)
+        return (forest, list(range(k)), [s for _, s, _ in src],
+                [b for _, _, b in src], 1)
 
     # ------------------------------------------------------------------
     # numeric-health quarantine (robust/sentinel.py)
@@ -1572,7 +1668,20 @@ class GBDT:
 
 
 class DART(GBDT):
-    """Dropout boosting (reference dart.hpp:23)."""
+    """Dropout boosting (reference dart.hpp:23: DroppingTrees, Normalize).
+
+    Each iteration draws the dropped set J on the host, takes the dropped
+    trees' output D = sum_{j in J} s_j tree_j off the training score, grows
+    the new tree at shrinkage lr / (k + 1) (lr / (lr + k) in xgboost mode)
+    against what is left, and puts k / (k + 1) D (k / (lr + k)) back, each
+    dropped tree's scale s_j going by the same factor. On the fused
+    learner of one chip D is ONE replay of the dropped trees over the
+    resident code planes (`boosting/dart_replay`, plane.replay_forest_*)
+    from the forest's tables on the device (fused.ForestTables), with
+    every s_j a float64 on the host: no tree is fetched, no row-major
+    table is uploaded, and the iteration makes no blocking sync. Elsewhere
+    (the host loop, a mesh) the dropped trees are materialized and walked
+    one by one, the oracle path."""
 
     def init(self, config, train_data, objective, metrics):
         super().init(config, train_data, objective, metrics)
@@ -1581,28 +1690,72 @@ class DART(GBDT):
         self.sum_weight = 0.0
         self.drop_index: List[int] = []
         self.shrinkage_rate = config.learning_rate
+        # the device replay: the forest's tables, the model each row was
+        # put from, and each row's current output = row x scale + bias
+        self._forest = None
+        self._forest_trees: list = []
+        self._forest_scale: List[float] = []
+        self._forest_bias: List[float] = []
+        self._dropped = None            # this iteration's D, [K, n] device
+        # (iteration, dropped iterations) of the last DROP_HISTORY draws
+        self.drop_history = collections.deque(maxlen=DROP_HISTORY)
+
+    def execution_plan(self) -> Dict[str, object]:
+        plan = super().execution_plan()
+        if self._replays_on_device:
+            plan["dart"] = {"replay": self._fused.row_traverse_method,
+                            "kmax": self._kmax()}
+        return plan
+
+    def _kmax(self) -> int:
+        """Length of the dropped-index vector a replay takes: max_drop,
+        or without a cap the forest's capacity."""
+        if self.config.max_drop > 0:
+            return int(self.config.max_drop)
+        from ..treelearner.fused import FOREST_STEP
+        cap = 0 if self._forest is None else self._forest.routes.shape[0]
+        return max(FOREST_STEP, cap)
 
     def checkpoint_state(self) -> Dict:
         st = super().checkpoint_state()
         st["dart"] = {"drop_rng": _pack_rng(self._drop_rng),
                       "tree_weight": [float(w) for w in self.tree_weight],
                       "sum_weight": float(self.sum_weight)}
+        if self._forest is not None and self._forest.count:
+            # the replay's own numbers, so a resumed run takes off and
+            # puts back exactly what an uninterrupted one does
+            n = self._forest.count
+            st["dart"].update(
+                forest_scale=[float(v) for v in self._forest_scale],
+                forest_bias=[float(v) for v in self._forest_bias],
+                forest_values=np.asarray(self._forest.values[:n]))
         return st
 
     def restore_checkpoint_state(self, state: Dict, model_text: str) -> None:
         super().restore_checkpoint_state(state, model_text)
+        self._forest, self._dropped = None, None
         d = state.get("dart")
         if d:
             _unpack_rng(self._drop_rng, d["drop_rng"])
             self.tree_weight = [float(w) for w in d["tree_weight"]]
             self.sum_weight = float(d["sum_weight"])
             self.drop_index = []
+        if d and "forest_values" in d and self._replays_on_device \
+                and len(d["forest_scale"]) == len(self.models):
+            self._sync_forest()
+            n = len(self.models)
+            f = self._forest
+            f.values = f.values.at[:n].set(
+                jnp.asarray(np.asarray(d["forest_values"], np.float32)))
+            self._forest_scale = [float(v) for v in d["forest_scale"]]
+            self._forest_bias = [float(v) for v in d["forest_bias"]]
 
     def _on_quarantine(self, idx: int) -> None:
         # keep the dropout weights aligned with the surviving forest
         if idx < len(self.tree_weight):
             self.sum_weight -= self.tree_weight[idx]
             del self.tree_weight[idx]
+        self._forest = None
 
     def train_one_iter(self, gradients=None, hessians=None) -> bool:
         if gradients is None or hessians is None:
@@ -1613,6 +1766,9 @@ class DART(GBDT):
             if not self.config.uniform_drop:
                 self.tree_weight.append(self.shrinkage_rate)
                 self.sum_weight += self.shrinkage_rate
+        self._dropped = None
+        if self._replays_on_device:
+            self._sync_forest()
         return res
 
     def _dropping_trees(self) -> None:
@@ -1639,14 +1795,18 @@ class DART(GBDT):
                         self.drop_index.append(self.num_init_iteration + i)
                         if len(self.drop_index) >= cfg.max_drop:
                             break
+        self.drop_history.append((self.iter, tuple(self.drop_index)))
         k = self.num_tree_per_iteration
-        miss = self.tree_learner.feature_miss_bin
-        self._materialize_models()
-        for i in self.drop_index:
-            for c in range(k):
-                t = self.models[i * k + c]
-                t.apply_shrinkage(-1.0)
-                self.train_score.add_tree(t, c, miss)
+        if self._replays_on_device:
+            self._drop_on_device()
+        else:
+            miss = self.tree_learner.feature_miss_bin
+            self._materialize_models()
+            for i in self.drop_index:
+                for c in range(k):
+                    t = self.models[i * k + c]
+                    t.apply_shrinkage(-1.0)
+                    self.train_score.add_tree(t, c, miss)
         if not self.config.xgboost_dart_mode:
             self.shrinkage_rate = self.config.learning_rate / (1.0 + len(self.drop_index))
         else:
@@ -1656,28 +1816,86 @@ class DART(GBDT):
                 self.shrinkage_rate = self.config.learning_rate / \
                     (self.config.learning_rate + len(self.drop_index))
 
+    def _drop_on_device(self) -> None:
+        """score -= D by one replay of the dropped trees; D is kept for
+        _normalize."""
+        if not self.drop_index:
+            return
+        k = self.num_tree_per_iteration
+        self._sync_forest()
+        rows = [[i * k + c for i in self.drop_index] for c in range(k)]
+        self._dropped = self._replay_subtract(
+            self._forest, rows,
+            [[self._forest_scale[t] for t in r] for r in rows],
+            [sum(self._forest_bias[t] for t in r) for r in rows],
+            self._kmax())
+        from .. import obs
+        reg = obs.active()
+        if reg is not None:
+            reg.inc("dart.trees_replayed", k * len(self.drop_index))
+            reg.inc("dart.drop_rounds")
+
+    def _last_trees_in_forest(self, k: int):
+        """The resident forest's rows, at the replay shape the drops
+        compiled: a rollback puts no table and compiles nothing."""
+        self._sync_forest()
+        rows = list(range(len(self.models) - k, len(self.models)))
+        return (self._forest, rows, [self._forest_scale[t] for t in rows],
+                [self._forest_bias[t] for t in rows], self._kmax())
+
+    def _sync_forest(self) -> None:
+        """Every model's row in the forest's tables: the rows from the
+        first model that is not the one a row was put from (trees taken
+        off the end by a rollback or a trimmed tail) are put again, the
+        new ones as they were grown, their scale and bias kept here."""
+        from ..treelearner.fused import ForestTables, PendingTree
+        if self._forest is None:
+            self._forest = ForestTables(self._fused)
+            self._forest_trees = []
+        kept, n = self._forest_trees, len(self.models)
+        same = 0
+        while same < min(len(kept), n) and (
+                self.models[same] is kept[same]
+                or (isinstance(kept[same], PendingTree)
+                    and self.models[same] is kept[same]._tree)):
+            same += 1
+        del kept[same:], self._forest_scale[same:], self._forest_bias[same:]
+        f = self._forest
+        f.reserve(n)
+        for t in range(same, n):
+            ta, scale, bias = self._replay_source(self.models[t])
+            f.put(t, ta)
+            kept.append(self.models[t])
+            self._forest_scale.append(scale)
+            self._forest_bias.append(bias)
+        f.count = n
+
     def _normalize(self) -> None:
         cfg = self.config
         k_drop = float(len(self.drop_index))
         k = self.num_tree_per_iteration
-        miss = self.tree_learner.feature_miss_bin
-        self._materialize_models()
-        for i in self.drop_index:
-            for c in range(k):
-                t = self.models[i * k + c]
-                if not cfg.xgboost_dart_mode:
-                    t.apply_shrinkage(1.0 / (k_drop + 1.0))
-                    for vs in self.valid_score:
-                        vs.add_tree(t, c, miss)
-                    t.apply_shrinkage(-k_drop)
-                    self.train_score.add_tree(t, c, miss)
-                else:
-                    t.apply_shrinkage(self.shrinkage_rate)
-                    for vs in self.valid_score:
-                        vs.add_tree(t, c, miss)
-                    t.apply_shrinkage(-k_drop / cfg.learning_rate)
-                    self.train_score.add_tree(t, c, miss)
-            if not cfg.uniform_drop:
+        if self._replays_on_device:
+            self._normalize_on_device(k_drop)
+        else:
+            miss = self.tree_learner.feature_miss_bin
+            self._materialize_models()
+            for i in self.drop_index:
+                for c in range(k):
+                    t = self.models[i * k + c]
+                    if not cfg.xgboost_dart_mode:
+                        t.apply_shrinkage(1.0 / (k_drop + 1.0))
+                        for vs in self.valid_score:
+                            vs.add_tree(t, c, miss)
+                        t.apply_shrinkage(-k_drop)
+                        self.train_score.add_tree(t, c, miss)
+                    else:
+                        t.apply_shrinkage(self.shrinkage_rate)
+                        for vs in self.valid_score:
+                            vs.add_tree(t, c, miss)
+                        t.apply_shrinkage(-k_drop / cfg.learning_rate)
+                        self.train_score.add_tree(t, c, miss)
+        if not cfg.uniform_drop:
+            for i in self.drop_index:
                 j = i - self.num_init_iteration
                 if not cfg.xgboost_dart_mode:
                     self.sum_weight -= self.tree_weight[j] / (k_drop + 1.0)
@@ -1685,6 +1903,27 @@ class DART(GBDT):
                 else:
                     self.sum_weight -= self.tree_weight[j] / (k_drop + cfg.learning_rate)
                     self.tree_weight[j] *= k_drop / (k_drop + cfg.learning_rate)
+
+    def _normalize_on_device(self, k_drop: float) -> None:
+        """score += f D, f = k / (k + 1) (k / (k + lr) in xgboost mode);
+        each dropped tree is scaled by f, and a validation set, which
+        never had it taken off, gains (f - 1) of it."""
+        if self._dropped is None:
+            return
+        lr = self.config.learning_rate
+        f = k_drop / (k_drop + (lr if self.config.xgboost_dart_mode
+                                else 1.0))
+        self.train_score.score = _dart_add_back_entry()(
+            self.train_score.score, self._dropped, np.float32(f))
+        k = self.num_tree_per_iteration
+        for i in self.drop_index:
+            for c in range(k):
+                t = i * k + c
+                for vs in self.valid_score:
+                    self._valid_add(vs, self.models[t], c, f - 1.0)
+                self.models[t].apply_shrinkage(f)
+                self._forest_scale[t] *= f
+                self._forest_bias[t] *= f
 
 
 def _score_add_device(score, leaf_values, leaf_of_row, *, class_id: int):
@@ -1713,6 +1952,45 @@ def _manager_entry(name: str, fn, **jit_kwargs):
 def _score_add_entry():
     return _manager_entry("boosting/score_add", _score_add_device,
                           static_argnames=("class_id",))
+
+
+def _dart_replay_device(routes, values, codes_planes, idx, w, count, bias,
+                        score, *, method: str, interpret: bool):
+    """(score - D, D): D[c] = bias[c] + sum over j < count[c] of w[c, j]
+    x the output on every row of the forest's tree idx[c, j] (routes /
+    values: fused.ForestTables), by one replay over the resident code
+    planes a class; idx's length is the grid's static tree extent."""
+    from ..ops import plane
+    with jax.named_scope("lgbm.dart_replay"):
+        n = score.shape[1]
+        out = []
+        for c in range(idx.shape[0]):
+            vals = values[idx[c]] * w[c][:, None]
+            sel = jnp.concatenate([count[c:c + 1], idx[c]])
+            if method == "pallas":
+                d = plane.replay_forest_pallas(codes_planes, routes, vals,
+                                               sel, interpret=interpret)
+            else:
+                d = plane.replay_forest_ref(codes_planes, routes, vals, sel)
+            out.append(d[:n] + bias[c])
+        dropped = jnp.stack(out)
+        return score - dropped, dropped
+
+
+@functools.lru_cache(maxsize=1)
+def _dart_replay_entry():
+    return _manager_entry("boosting/dart_replay", _dart_replay_device,
+                          static_argnames=("method", "interpret"))
+
+
+def _dart_add_back_device(score, dropped, factor):
+    with jax.named_scope("lgbm.dart_replay"):
+        return score + factor * dropped
+
+
+@functools.lru_cache(maxsize=1)
+def _dart_add_back_entry():
+    return _manager_entry("boosting/dart_add_back", _dart_add_back_device)
 
 
 def _kth_largest(x, k: int, digit_bits: int = 1):

@@ -1152,39 +1152,69 @@ def traverse_table(layout: PlaneLayout, ta, miss_bin,
          rec.reshape(-1)])
 
 
-def _traverse_kernel(tbl, codes_ref, leaf_ref, *, rows, chunk):
-    """One lane tile: codes_ref [C, rows, 128] (lane r of the planes is
-    element (r // 128, r % 128): dense (8, 128) vregs), leaf_ref
-    [rows, 128]. The splits are the inner loop, in the order they were
-    made, each one routed by what its scalars say it is."""
+def _replay_split(tbl, k, codes_ref, leaf_ref, *, rows, chunk, value=None):
+    """Split ``k`` of the tree whose table ``tbl`` (traverse_table) sits
+    in SMEM, replayed over a lane tile: codes_ref [C, rows, 128] (lane r
+    of the planes is element (r // 128, r % 128): dense (8, 128) vregs),
+    leaf_ref [rows, 128] the leaf slot of every lane so far. The lanes in
+    the split's slot that go right move to slot k + 1. With ``value`` =
+    (val_ref, left, right) the lanes in the slot also take the output of
+    the child they went to, so once every split is replayed val_ref
+    holds each lane's leaf value: no lookup by leaf id. One branch per
+    kind of split, chosen by the scalars the generic routing selects
+    with: a numerical split never pays the bitset."""
     from jax.experimental import pallas as pl
 
+    base = 1 + k * TRAVERSE_REC
+    slot = tbl[base]
+    rs = [tbl[base + 1 + i] for i in range(ROUTE_SCALARS)]
+
+    def sweep(route):
+        def rows_at(i, _):
+            r = pl.ds(pl.multiple_of(i * chunk, chunk), chunk)
+            go_left = route(_code_from_col32(codes_ref[rs[0], r, :], rs))
+            leaf = leaf_ref[r, :]
+            leaf_ref[r, :] = jnp.where((leaf == slot) & ~go_left,
+                                       k + 1, leaf)
+            if value is not None:
+                val_ref, left, right = value
+                val_ref[r, :] = jnp.where(leaf == slot,
+                                          jnp.where(go_left, left, right),
+                                          val_ref[r, :])
+        jax.lax.fori_loop(0, rows // chunk, rows_at, None)
+
+    for efb in (0, 1):
+        for cat, route in ((0, _route_numerical), (1, _route_categorical)):
+            @pl.when((rs[6] == efb) & (rs[10] == cat))
+            def _(efb=efb, route=route):
+                sweep(lambda code: route(
+                    _efb_bin(code, rs) if efb else code, rs))
+
+
+def _traverse_kernel(tbl, codes_ref, leaf_ref, *, rows, chunk):
+    """One lane tile, one tree: the splits are the inner loop, in the
+    order they were made."""
     leaf_ref[...] = jnp.zeros_like(leaf_ref)
 
     def split(k, _):
-        base = 1 + k * TRAVERSE_REC
-        slot = tbl[base]
-        rs = [tbl[base + 1 + i] for i in range(ROUTE_SCALARS)]
-
-        def sweep(route):
-            def rows_at(i, _):
-                r = pl.ds(pl.multiple_of(i * chunk, chunk), chunk)
-                go_left = route(_code_from_col32(codes_ref[rs[0], r, :], rs))
-                leaf = leaf_ref[r, :]
-                leaf_ref[r, :] = jnp.where((leaf == slot) & ~go_left,
-                                           k + 1, leaf)
-            jax.lax.fori_loop(0, rows // chunk, rows_at, None)
-
-        # one branch per kind of split, chosen by the scalars the generic
-        # routing selects with: a numerical split never pays the bitset
-        for efb in (0, 1):
-            for cat, route in ((0, _route_numerical), (1, _route_categorical)):
-                @pl.when((rs[6] == efb) & (rs[10] == cat))
-                def _(efb=efb, route=route):
-                    sweep(lambda code: route(
-                        _efb_bin(code, rs) if efb else code, rs))
+        _replay_split(tbl, k, codes_ref, leaf_ref, rows=rows, chunk=chunk)
 
     jax.lax.fori_loop(0, tbl[0], split, None)
+
+
+def _replay_tile(C: int, R: int, per_row: int):
+    """(padded lane count, rows, chunk) of the replay kernels' lane tile:
+    ``per_row`` i32 words of VMEM a 128-lane row beside the C code planes'
+    (each block double-buffered)."""
+    unit = LANE * TRAVERSE_CHUNK
+    nrows = (R + -R % unit) // LANE
+    fit = TRAVERSE_VMEM // (4 * LANE * (2 * C + per_row))
+    if fit >= TRAVERSE_CHUNK:
+        chunk = TRAVERSE_CHUNK
+        rows = min(TRAVERSE_ROWS, nrows, fit // chunk * chunk)
+    else:
+        rows = chunk = max(8, fit // 8 * 8)
+    return nrows * LANE, rows, chunk
 
 
 # tpulint: jit-ok(kernel entry; dispatched through manager-registered learner entries)
@@ -1201,16 +1231,10 @@ def traverse_planes_pallas(codes_planes: jax.Array, table: jax.Array, *,
     from jax.experimental.pallas import tpu as pltpu
 
     C, R = codes_planes.shape
-    unit = LANE * TRAVERSE_CHUNK
-    if R % unit:        # a layout whose lane tile shrank below the unit
-        codes_planes = jnp.pad(codes_planes, ((0, 0), (0, -R % unit)))
-    nrows = codes_planes.shape[1] // LANE
-    fit = TRAVERSE_VMEM // (2 * 4 * LANE * (C + 1))
-    if fit >= TRAVERSE_CHUNK:
-        chunk = TRAVERSE_CHUNK
-        rows = min(TRAVERSE_ROWS, nrows, fit // chunk * chunk)
-    else:
-        rows = chunk = max(8, fit // 8 * 8)
+    lanes, rows, chunk = _replay_tile(C, R, 2)
+    if lanes != R:      # a layout whose lane tile shrank below the unit
+        codes_planes = jnp.pad(codes_planes, ((0, 0), (0, lanes - R)))
+    nrows = lanes // LANE
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(pl.cdiv(nrows, rows),),
@@ -1225,6 +1249,151 @@ def traverse_planes_pallas(codes_planes: jax.Array, table: jax.Array, *,
         interpret=interpret,
     )(table, codes_planes.reshape(C, nrows, LANE))
     return leaf.reshape(-1)[:R]
+
+
+# ---------------------------------------------------------------------------
+# forest replay: the weighted sum of many trees' outputs in one pass
+# ---------------------------------------------------------------------------
+
+def replay_widths(num_leaves: int):
+    """(route words, value words) of one tree's row in a forest's tables:
+    traverse_table's [1 + (L - 1) x TRAVERSE_REC] i32 and replay_values'
+    [1 + 2 (L - 1)] f32, each padded to the 1,024-word tile XLA gives a
+    1-D array, which is the block Mosaic must see in SMEM."""
+    n = max(num_leaves - 1, 1)
+    pad = lambda w: w + -w % 1024
+    return pad(1 + n * TRAVERSE_REC), pad(1 + 2 * n)
+
+
+def replay_values(ta) -> jax.Array:
+    """f32 [1 + 2 (L - 1)] of the tree ``ta``: the output a lane takes
+    before any split (the root leaf's value: a tree of one leaf is only
+    that), then per node k the outputs of its left and right child: a
+    leaf's value, an internal node's own. Node k's children are the two
+    slots `_replay_split` sends its lanes to."""
+    lv = ta["leaf_value"].astype(jnp.float32)
+    iv = ta["internal_value"].astype(jnp.float32)
+    L = lv.shape[0]
+
+    def out(child):
+        leaf = jnp.clip(-child - 1, 0, L - 1)
+        node = jnp.clip(child, 0, max(L - 2, 0))
+        return jnp.where(child < 0, lv[leaf], iv[node])
+
+    pair = jnp.stack([out(ta["left_child"]), out(ta["right_child"])], axis=1)
+    return jnp.concatenate([lv[:1], pair.reshape(-1)])
+
+
+def replay_forest_ref(codes_planes: jax.Array, routes: jax.Array,
+                      values: jax.Array, sel: jax.Array) -> jax.Array:
+    """Sum over the trees j < sel[0] of ``values``' tree j's output on
+    every lane of ``codes_planes`` [C, R], in plain XLA: [R] f32.
+    ``routes`` [T, W] holds the forest's traverse_table rows and tree j's
+    is routes[sel[1 + j]]; ``values`` [kmax, Wv] holds the replay_values
+    of those trees in that order, already scaled by their weights. The
+    portable path, and the oracle of `replay_forest_pallas`."""
+    R = codes_planes.shape[1]
+
+    def tree(j, acc):
+        tbl = routes[sel[1 + j]]
+        vals = values[j]
+
+        def split(k, carry):
+            leaf, val = carry
+            rec = jax.lax.dynamic_slice(tbl, (1 + k * TRAVERSE_REC,),
+                                        (TRAVERSE_REC,))
+            rs = rec[1:]
+            col32 = jax.lax.dynamic_index_in_dim(codes_planes, rs[0], axis=0,
+                                                 keepdims=False)
+            go_left = _route_from_col32(col32, rs)
+            here = leaf == rec[0]
+            val = jnp.where(here, jnp.where(go_left, vals[1 + 2 * k],
+                                            vals[2 + 2 * k]), val)
+            return jnp.where(here & ~go_left, k + 1, leaf), val
+
+        _, val = jax.lax.fori_loop(
+            0, tbl[0], split, (jnp.zeros(R, jnp.int32),
+                               jnp.full(R, vals[0], jnp.float32)))
+        return acc + val
+
+    return jax.lax.fori_loop(0, sel[0], tree, jnp.zeros(R, jnp.float32))
+
+
+def _forest_kernel(sel, tbl, vals, codes_ref, out_ref, leaf_ref, val_ref, *,
+                   rows, chunk):
+    """Grid step (tile, j): tree j's splits replayed over the tile, its
+    output added to the tile's sum. The tile's code planes stay in VMEM
+    while j runs (their block index does not move with j), tree j's
+    route table and weighted values come to SMEM by their own blocks,
+    and the steps past the sel[0] trees to replay do nothing."""
+    from jax.experimental import pallas as pl
+
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(j < sel[0])
+    def _():
+        leaf_ref[...] = jnp.zeros_like(leaf_ref)
+        val_ref[...] = jnp.full(val_ref.shape, vals[0], jnp.float32)
+
+        def split(k, _):
+            _replay_split(tbl, k, codes_ref, leaf_ref, rows=rows,
+                          chunk=chunk, value=(val_ref, vals[1 + 2 * k],
+                                              vals[2 + 2 * k]))
+
+        jax.lax.fori_loop(0, tbl[0], split, None)
+        out_ref[...] += val_ref[...]
+
+
+# tpulint: jit-ok(kernel entry; dispatched through manager-registered boosting entries)
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def replay_forest_pallas(codes_planes: jax.Array, routes: jax.Array,
+                         values: jax.Array, sel: jax.Array, *,
+                         interpret: bool = False) -> jax.Array:
+    """`replay_forest_ref` as one Pallas pass: [R] f32. The grid runs
+    over (lane tile, tree): a tile's code planes are read from HBM once
+    for all the trees, each tree's splits run as in
+    `traverse_planes_pallas` (the same `_replay_split`) carrying every
+    lane's output along with its slot, and the sum is written once a
+    tile. ``sel`` = [count, the row of routes of each tree]; its length
+    less one is the grid's static tree extent (rows past the count repeat
+    the last, so their blocks are not fetched again)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    C, R = codes_planes.shape
+    T, W = routes.shape
+    kmax, Wv = values.shape
+    lanes, rows, chunk = _replay_tile(C, R, 4)
+    if lanes != R:
+        codes_planes = jnp.pad(codes_planes, ((0, 0), (0, lanes - R)))
+    nrows = lanes // LANE
+    smem = pltpu.SMEM
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(pl.cdiv(nrows, rows), kmax),
+        in_specs=[
+            pl.BlockSpec((W,), lambda t, j, sel: (sel[1 + j],),
+                         memory_space=smem),
+            pl.BlockSpec((Wv,), lambda t, j, sel: (j,), memory_space=smem),
+            pl.BlockSpec((C, rows, LANE), lambda t, j, sel: (0, t, 0)),
+        ],
+        out_specs=pl.BlockSpec((rows, LANE), lambda t, j, sel: (t, 0)),
+        scratch_shapes=[pltpu.VMEM((rows, LANE), jnp.int32),
+                        pltpu.VMEM((rows, LANE), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        functools.partial(_forest_kernel, rows=rows, chunk=chunk),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((nrows, LANE), jnp.float32),
+        name="replay_forest_pallas",
+        interpret=interpret,
+    )(sel, routes.reshape(-1), values.reshape(-1),
+      codes_planes.reshape(C, nrows, LANE))
+    return out.reshape(-1)[:R]
 
 
 # ---------------------------------------------------------------------------

@@ -52,7 +52,7 @@ import jax.numpy as jnp
 from ..config import Config
 from ..io.dataset import BinnedDataset
 from ..io.binning import BIN_CATEGORICAL
-from ..models.tree import Tree
+from ..models.tree import K_CATEGORICAL_MASK, K_DEFAULT_LEFT_MASK, Tree
 from ..obs import instrument_kernel, span
 from ..ops import histogram as H
 from ..ops import plane
@@ -1952,7 +1952,7 @@ class FusedSerialGrower:
             else mappers[f].bin_to_value(int(tb))
             for i, (f, tb) in enumerate(zip(inner_feat,
                                             ta["threshold_bin"][:ni]))]
-        from ..models.tree import K_CATEGORICAL_MASK, _to_bitset
+        from ..models.tree import _to_bitset
         dt = np.zeros(max(ni, 1), dtype=np.int8)
         cat_nodes = ta.get("split_cat")
         for i, f in enumerate(inner_feat):
@@ -1995,6 +1995,39 @@ class FusedSerialGrower:
         tree.leaf_depth[:k] = ta["leaf_depth"][:k]
         return tree
 
+    def replay_arrays(self, tree: Tree) -> Dict:
+        """The tree arrays a replay reads (REPLAY_KEYS, at this grower's
+        num_leaves) of a host Tree: what materialize_tree turned into the
+        tree, back again, for a tree that is no longer pending (restored
+        from a checkpoint, materialized by a host consumer). Its leaf and
+        internal values are the tree's as they stand now."""
+        L, n = self.num_leaves, tree.num_leaves - 1
+        dt = np.asarray(tree.decision_type[:n]).astype(np.uint8)
+        cat = (dt & K_CATEGORICAL_MASK) != 0
+        bits = np.zeros((L - 1, plane.CAT_WORDS), np.int32)
+        for i in np.flatnonzero(cat):
+            c = int(tree.threshold_in_bin[i])
+            lo, hi = tree.cat_boundaries_inner[c:c + 2]
+            words = np.asarray(tree.cat_threshold_inner[lo:hi], np.uint64)
+            bits[i, :len(words)] = words.astype(np.uint32).view(np.int32)
+
+        def nodes(a, dtype):
+            out = np.zeros(L - 1, dtype)
+            out[:n] = np.asarray(a[:n])
+            return out
+
+        leaf = np.zeros(L, np.float32)
+        leaf[:n + 1] = tree.leaf_value[:n + 1]
+        return {"n_leaves": np.int32(tree.num_leaves),
+                "split_feature": nodes(tree.split_feature_inner, np.int32),
+                "threshold_bin": nodes(tree.threshold_in_bin, np.int32),
+                "default_left": nodes((dt & K_DEFAULT_LEFT_MASK) != 0, bool),
+                "split_cat": nodes(cat, bool), "split_bits": bits,
+                "left_child": nodes(tree.left_child, np.int32),
+                "right_child": nodes(tree.right_child, np.int32),
+                "leaf_value": leaf,
+                "internal_value": nodes(tree.internal_value, np.float32)}
+
 
 class PendingTree:
     """Lazily-materialized device tree: keeps the raw device arrays until
@@ -2005,7 +2038,11 @@ class PendingTree:
     directly keep working without an explicit materialize pass.
 
     ``tree_arrays`` is the grow program's output dict: device arrays
-    until GBDT._materialize_models swaps in their host copies."""
+    until GBDT._materialize_models swaps in their host copies. Until
+    then the tree's output is ``leaf_value * pending_shrinkage +
+    pending_bias``, and the calls that made it are kept in order, so a
+    late materialize() repeats exactly the float64 steps an early one
+    would have been put through."""
 
     def __init__(self, grower: FusedSerialGrower, tree_arrays: Dict) -> None:
         self._tree: Optional[Tree] = None
@@ -2013,21 +2050,26 @@ class PendingTree:
         self.tree_arrays = tree_arrays
         self.pending_shrinkage = 1.0
         self.pending_bias = 0.0
+        self._pending_ops: list = []
         # host-cached leaf count (GBDT._batched_tree_stats): immutable
         # once the tree is grown, so one batched fetch serves forever
         self._n_leaves_host: Optional[int] = None
 
     def apply_shrinkage(self, rate: float) -> None:
+        """Tree::Shrinkage: the whole output, a bias already added too."""
         if self._tree is not None:
             self._tree.apply_shrinkage(rate)
         else:
             self.pending_shrinkage *= rate
+            self.pending_bias *= rate
+            self._pending_ops.append(("apply_shrinkage", rate))
 
     def add_bias(self, val: float) -> None:
         if self._tree is not None:
             self._tree.add_bias(val)
         else:
             self.pending_bias += val
+            self._pending_ops.append(("add_bias", val))
 
     def leaf_values_device(self):
         if self._tree is not None:
@@ -2038,10 +2080,8 @@ class PendingTree:
     def materialize(self) -> Tree:
         if self._tree is None:
             tree = self.grower.materialize_tree(self.tree_arrays)
-            if self.pending_shrinkage != 1.0:
-                tree.apply_shrinkage(self.pending_shrinkage)
-            if self.pending_bias != 0.0:
-                tree.add_bias(self.pending_bias)
+            for op, val in self._pending_ops:
+                getattr(tree, op)(val)
             self._tree = tree
         return self._tree
 
@@ -2051,6 +2091,77 @@ class PendingTree:
         # unpickling/copy before __init__ has run.
         if name.startswith("__") or name in ("_tree", "grower", "tree_arrays",
                                              "pending_shrinkage",
-                                             "pending_bias"):
+                                             "pending_bias", "_pending_ops"):
             raise AttributeError(name)
         return getattr(self.materialize(), name)
+
+
+# what a replay reads of a tree's arrays (plane.traverse_table and
+# plane.replay_values)
+REPLAY_KEYS = ("n_leaves", "split_feature", "threshold_bin", "default_left",
+               "split_cat", "split_bits", "left_child", "right_child",
+               "leaf_value", "internal_value")
+# trees a forest's tables grow by: the replay compiles once per step, so
+# a run of a few hundred trees never compiles it inside a timed stretch
+FOREST_STEP = 512
+
+
+def _forest_put(routes, values, t, ta, miss, efb, *, layout):
+    """Row ``t`` of the forest's tables: the tree's traverse_table and
+    replay_values, in place (the tables are donated). Under the scope the
+    grow program builds the same table in, `lgbm.row_traverse`: XLA gives
+    the two programs' table ops the same names, and a trace read by
+    instruction name then books them to one scope either way."""
+    with jax.named_scope("lgbm.row_traverse"):
+        tb = plane.traverse_table(layout, ta, miss, efb)
+        vv = plane.replay_values(ta)
+        routes = jax.lax.dynamic_update_slice(routes, tb[None], (t, 0))
+        values = jax.lax.dynamic_update_slice(values, vv[None], (t, 0))
+    return routes, values
+
+
+_FOREST_PUT: list = []
+
+
+def _forest_put_entry():
+    if not _FOREST_PUT:
+        from ..compile import get_manager
+        jitted = jax.jit(_forest_put, static_argnames=("layout",),  # tpulint: jit-ok(registered by jit_entry on the next line; the manager counts its compiles)
+                         donate_argnums=(0, 1))
+        _FOREST_PUT.append(get_manager().jit_entry(
+            "fused/forest_put", jitted, donate_argnums=(0, 1)))
+    return _FOREST_PUT[0]
+
+
+class ForestTables:
+    """The replay tables of a forest's trees, resident on the device:
+    row t of ``routes`` [capacity, W] i32 is tree t's traverse_table and
+    row t of ``values`` [capacity, Wv] f32 its replay_values, as the
+    tree was grown (the boosting layer keeps each tree's scale on the
+    host). ``count`` rows are filled; the capacity grows in steps of
+    ``step`` trees. plane.replay_forest_* read them."""
+
+    def __init__(self, grower: FusedSerialGrower,
+                 step: int = FOREST_STEP) -> None:
+        self.grower = grower
+        self.step = step
+        self.width = plane.replay_widths(grower.num_leaves)
+        self.routes = jnp.zeros((0, self.width[0]), jnp.int32)
+        self.values = jnp.zeros((0, self.width[1]), jnp.float32)
+        self.count = 0
+
+    def reserve(self, n: int) -> None:
+        cap = -(-n // self.step) * self.step
+        have = self.routes.shape[0]
+        if cap > have:
+            grow = ((0, cap - have), (0, 0))
+            self.routes = jnp.pad(self.routes, grow)
+            self.values = jnp.pad(self.values, grow)
+
+    def put(self, t: int, tree_arrays: Dict) -> None:
+        """Tree ``t``'s row from its tree arrays (device or host)."""
+        g = self.grower
+        ta = {k: tree_arrays[k] for k in REPLAY_KEYS}
+        self.routes, self.values = _forest_put_entry()(
+            self.routes, self.values, jnp.int32(t), ta,
+            g.feature_miss_bin, g._efb_dev, layout=g.layout)
