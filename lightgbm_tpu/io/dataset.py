@@ -27,6 +27,7 @@ from typing import Any, Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
+from .. import native, obs
 from ..compile import get_manager
 from ..config import Config
 from ..obs.spans import span
@@ -52,17 +53,32 @@ def _csc_col(data, f: int):
     return data.indices[start:end], data.data[start:end]
 
 
-def _reject_inf_feature(vals: np.ndarray, names, f: int) -> None:
+def _reject_inf_feature(names, f: int, count: int) -> None:
     """±Inf feature values corrupt bin boundaries and flow silently into
-    histogram sums; reject at construction, naming the column. NaN stays
-    legal — it is the missing-value representation (reference
-    BinMapper::ValueToBin routes NaN through the NA bin)."""
-    inf = np.isinf(vals)
-    if inf.any():
+    histogram sums; reject at construction, naming the column and its
+    ``count`` of them. NaN stays legal — it is the missing-value
+    representation (reference BinMapper::ValueToBin routes NaN through
+    the NA bin)."""
+    if count:
         log.fatal(
             "Feature '%s' (column %d) contains %d infinite value(s); "
             "replace them with NaN (missing) or clip to a finite range",
-            names[f] if f < len(names) else str(f), f, int(inf.sum()))
+            names[f] if f < len(names) else str(f), f, count)
+
+
+# the longest category table the row pass keeps for one feature; a
+# categorical feature with a larger category is binned column by column
+_ROW_PASS_MAX_CATEGORY = 1 << 16
+
+
+def _row_pass_len(m: BinMapper) -> int:
+    """How many bounds the row pass searches for mapper ``m`` (the last
+    one clips: NaN's bin under MISSING_NAN is not searched), or the
+    length of its category table; 0 where it keeps no row-pass form."""
+    if m.bin_type == BIN_NUMERICAL:
+        return m.num_bin - (1 if m.missing_type == MISSING_NAN else 0)
+    top = max((c for c in m.categorical_2_bin if c >= 0), default=-1) + 1
+    return max(top, 1) if top <= _ROW_PASS_MAX_CATEGORY else 0
 
 
 class Metadata:
@@ -331,7 +347,7 @@ class BinnedDataset:
             ds.max_bin = reference.max_bin
             ds._monotone_constraints = reference._monotone_constraints
             ds.bundles = reference.bundles
-            ds._apply_mappers(data)
+            ds._apply_mappers(data, config.num_threads)
             return ds
 
         if categorical_feature is None:
@@ -423,26 +439,40 @@ class BinnedDataset:
                     max_conflict_rate=config.efb_max_conflict_rate)
                 if ds.bundles.is_trivial:
                     ds.bundles = None
-        ds._apply_mappers(data)
+        ds._apply_mappers(data, config.num_threads)
         return ds
 
-    def _apply_mappers(self, data: np.ndarray) -> None:
+    def _apply_mappers(self, data: np.ndarray, num_threads: int = 0) -> None:
         """Push every row through the mappers into the packed bin-code
         matrix: [N, F_used] per-feature codes when bundling is trivial,
         [N, num_groups] bundle codes otherwise (reference
         FeatureGroup::PushData / Bin::Push; sparse inputs touch only
         their stored entries — never densified)."""
         with span("dataset/bin_rows", stage="construct/bin_rows"):
-            self.bins = self._bin_rows(data)
+            self.bins = self._bin_rows(data, num_threads)
         self.num_data = data.shape[0]
         with span("dataset/occupancy", stage="construct/occupancy"):
             self._measure_occupancy()
 
-    def _bin_rows(self, data: np.ndarray) -> np.ndarray:
+    def _bin_rows(self, data, num_threads: int = 0) -> np.ndarray:
+        """The code matrix, one column a group (a feature when bundling
+        is trivial). A dense float32 / float64 matrix goes through the
+        native row pass (`_bin_rows_native`), every group it can bin;
+        the rest, and sparse input, column by column. Both give the
+        same codes byte for byte (tests/test_native.py)."""
         n = data.shape[0]
         sparse = _is_sparse(data)
         mappers = self.bin_mappers
         bt = self.bundles
+        if bt is None or bt.is_trivial:
+            groups = [[i] for i in range(len(mappers))]
+            wide = any(m.num_bin > 256 for m in mappers)
+        else:
+            groups = bt.groups
+            wide = int(bt.group_num_bins.max()) > 256
+        dtype = np.uint16 if wide else np.uint8
+        bins = np.empty((n, len(groups)), dtype=dtype)
+        row_inf = self._bin_rows_native(data, groups, bins, num_threads)
 
         def col_bins(i: int):
             """(row_indices_or_None, codes) for used feature i; None row
@@ -451,53 +481,96 @@ class BinnedDataset:
             if sparse:
                 idx, vals = _csc_col(data, f)
                 vals = np.asarray(vals, dtype=np.float64)
-                _reject_inf_feature(vals, self.feature_names, f)
+                _reject_inf_feature(self.feature_names, f,
+                                    int(np.isinf(vals).sum()))
                 return idx, mappers[i].values_to_bins(vals)
             col = np.asarray(data[:, f], dtype=np.float64)
-            _reject_inf_feature(col, self.feature_names, f)
+            _reject_inf_feature(self.feature_names, f,
+                                int(np.isinf(col).sum()))
             return None, mappers[i].values_to_bins(col)
 
-        if bt is None or bt.is_trivial:
-            f_used = len(mappers)
-            dtype = np.uint8 if all(m.num_bin <= 256 for m in mappers) \
-                else np.uint16
-            bins = np.empty((n, f_used), dtype=dtype)
-            for i in range(f_used):
+        # group by group in order, so that the first infinite column
+        # named is the same whichever path binned it
+        for g, members in enumerate(groups):
+            if members[0] in row_inf:
+                for i in members:
+                    _reject_inf_feature(self.feature_names,
+                                        self.real_feature_index[i],
+                                        row_inf[i])
+            elif len(members) == 1:
+                i = members[0]
                 idx, codes = col_bins(i)
                 if idx is None:
-                    bins[:, i] = codes.astype(dtype)
+                    bins[:, g] = codes.astype(dtype)
                 else:
-                    zero_bin = mappers[i].value_to_bin(0.0)
-                    bins[:, i] = dtype(zero_bin)
-                    bins[idx, i] = codes.astype(dtype)
-        else:
-            dtype = np.uint8 if int(bt.group_num_bins.max()) <= 256 \
-                else np.uint16
-            bins = np.empty((n, bt.num_groups), dtype=dtype)
-            for g, members in enumerate(bt.groups):
-                if len(members) == 1:
-                    i = members[0]
+                    bins[:, g] = dtype(mappers[i].value_to_bin(0.0))
+                    bins[idx, g] = codes.astype(dtype)
+            else:
+                # shared column: code 0 = every member at its
+                # most-frequent bin; later members overwrite on the
+                # (conflict-budgeted) overlapping rows
+                code = np.zeros(n, dtype=dtype)
+                for i in members:
                     idx, codes = col_bins(i)
-                    if idx is None:
-                        bins[:, g] = codes.astype(dtype)
-                    else:
-                        bins[:, g] = dtype(mappers[i].value_to_bin(0.0))
-                        bins[idx, g] = codes.astype(dtype)
-                else:
-                    # shared column: code 0 = every member at its
-                    # most-frequent bin; later members overwrite on the
-                    # (conflict-budgeted) overlapping rows
-                    code = np.zeros(n, dtype=dtype)
-                    for i in members:
-                        idx, codes = col_bins(i)
-                        mfb = bt.skip_of[i]
-                        keep = codes != mfb
-                        rows = np.flatnonzero(keep) if idx is None else idx[keep]
-                        b = codes[keep]
-                        slot = b - (b > mfb)
-                        code[rows] = (bt.offset_of[i] + slot).astype(dtype)
-                    bins[:, g] = code
+                    mfb = bt.skip_of[i]
+                    keep = codes != mfb
+                    rows = np.flatnonzero(keep) if idx is None else idx[keep]
+                    b = codes[keep]
+                    slot = b - (b > mfb)
+                    code[rows] = (bt.offset_of[i] + slot).astype(dtype)
+                bins[:, g] = code
+        reg = obs.active()
+        if reg is not None:
+            reg.inc("dataset.bin_rows_fused_cols", len(row_inf))
+            reg.inc("dataset.bin_rows_column_cols",
+                    len(mappers) - len(row_inf))
         return bins
+
+    def _bin_rows_native(self, data, groups, bins: np.ndarray,
+                         num_threads: int) -> Dict[int, int]:
+        """Bin every group whose members all have a row-pass form into
+        ``bins`` by ONE native pass over the rows of ``data``
+        (native/binning.cpp lgbt_bin_rows): the rows read where they lie,
+        once from memory, their codes written row-major. Returns {used
+        feature: count of +-inf} for the features it binned; empty where
+        the input or the library does not allow the pass."""
+        if not self.bin_mappers or not native.row_pass_input(data) \
+                or max(self.real_feature_index) >= data.shape[1]:
+            return {}
+        mappers = self.bin_mappers
+        plan = [(g, i) for g, members in enumerate(groups)
+                if all(_row_pass_len(mappers[i]) for i in members)
+                for i in members]
+        if not plan:
+            return {}
+        features = []
+        for g, i in plan:
+            m = mappers[i]
+            members = groups[g]
+            place = {}
+            if len(members) > 1:
+                place = dict(
+                    mode=(native.FIRST_MEMBER if i == members[0]
+                          else native.LATER_MEMBER),
+                    skip=int(self.bundles.skip_of[i]),
+                    offset=int(self.bundles.offset_of[i]))
+            if m.bin_type == BIN_NUMERICAL:
+                how = dict(bounds=m.bin_upper_bound[:_row_pass_len(m)],
+                           nan_bin=(m.num_bin - 1
+                                    if m.missing_type == MISSING_NAN else -1))
+            else:
+                table = np.zeros(_row_pass_len(m), dtype=np.int32)
+                for cat, b in m.categorical_2_bin.items():
+                    if cat >= 0:
+                        table[cat] = b
+                how = dict(table=table)
+            features.append(native.RowPassFeature(
+                src=self.real_feature_index[i], dst=g, **place, **how))
+        inf, threads = native.bin_rows_native(data, features, bins,
+                                              num_threads)
+        log.info("Host binning: rows in one native pass (%d threads, %d of "
+                 "%d columns)", threads, len(plan), len(mappers))
+        return {i: int(c) for (_, i), c in zip(plan, inf)}
 
     def _measure_occupancy(self) -> None:
         """Record construct-time row-occupancy statistics (mean/max
