@@ -324,9 +324,33 @@ class BinMapper:
                     self.missing_type = MISSING_NAN
                 cnt_in_bin[0] = int(total_sample_cnt - used_cnt)
 
+        self._finish(cnt_in_bin, total_sample_cnt, min_split_data,
+                     pre_filter)
+
+    @classmethod
+    def from_bins(cls, bounds: np.ndarray, cnt_in_bin: np.ndarray,
+                  missing_type: int, min_val: float, max_val: float,
+                  total_sample_cnt: int, min_split_data: int = 20,
+                  pre_filter: bool = False) -> "BinMapper":
+        """A numerical mapper from bins found as `find_bin` finds them
+        (native/binning.cpp lgbt_find_bins), finished by find_bin's own
+        tail."""
+        m = cls()
+        m.missing_type = missing_type
+        m.min_val, m.max_val = min_val, max_val
+        m.bin_upper_bound = bounds
+        m.num_bin = len(bounds)
+        m._finish(cnt_in_bin, total_sample_cnt, min_split_data, pre_filter)
+        return m
+
+    def _finish(self, cnt_in_bin, total_sample_cnt: int, min_split_data: int,
+                pre_filter: bool) -> None:
+        """find_bin's tail over the per-bin counts (reference
+        bin.cpp:496-521): the pre-filter, the default and the most
+        frequent bin, the sparse rate."""
         self.is_trivial = self.num_bin <= 1
         if not self.is_trivial and pre_filter and _need_filter(
-                cnt_in_bin, total_sample_cnt, min_split_data, bin_type):
+                cnt_in_bin, total_sample_cnt, min_split_data, self.bin_type):
             self.is_trivial = True
 
         if not self.is_trivial:

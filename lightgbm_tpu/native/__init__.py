@@ -3,9 +3,10 @@ shared object — no pybind11 dependency).
 
 The TPU compute path is JAX/XLA; these kernels cover the host-side
 runtime work the reference implements in C++ (bin boundary search,
-column bin conversion — src/io/bin.cpp — and the pass that bins every
-row of a dense matrix at once) where Python-loop cost is material at
-load time. The built library is named by a hash of
+column bin conversion — src/io/bin.cpp — the pass that finds the bins
+of every dense column of the construction sample at once, and the pass
+that bins every row of a dense matrix at once) where Python-loop cost is
+material at load time. The built library is named by a hash of
 binning.cpp, so only the committed source decides what is loaded — a
 stale or foreign .so in the tree is never picked up. Without a working
 compiler the pure-Python implementations are used, with a warning
@@ -84,6 +85,14 @@ def _load() -> Optional[ctypes.CDLL]:
             ctypes.POINTER(ctypes.c_double), ctypes.c_int32,
             ctypes.POINTER(ctypes.c_int32), ctypes.c_void_p, ctypes.c_int32,
             ctypes.c_int32, ctypes.POINTER(ctypes.c_int64), ctypes.c_int]
+        lib.lgbt_find_bins.restype = ctypes.c_int
+        lib.lgbt_find_bins.argtypes = [
+            ctypes.c_void_p, ctypes.c_int32, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_int64, ctypes.c_int32, ctypes.POINTER(ctypes.c_int32),
+            ctypes.POINTER(ctypes.c_int32), ctypes.c_int32, ctypes.c_int32,
+            ctypes.c_int32, ctypes.c_int32, ctypes.POINTER(ctypes.c_double),
+            ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int32),
+            ctypes.POINTER(ctypes.c_double), ctypes.c_int]
         _lib = lib
         log.info("Host binning: native (%s)", os.path.basename(so))
     except (OSError, subprocess.SubprocessError) as exc:
@@ -130,14 +139,64 @@ def values_to_bins_native(values: np.ndarray, bounds: np.ndarray):
 
 
 def row_pass_input(data) -> bool:
-    """Whether `bin_rows_native` can read `data` where it lies: a 2-D
-    aligned float32 / float64 ndarray of native byte order, any strides,
-    and the library loaded."""
+    """Whether `bin_rows_native` and `find_bins_native` can read `data`
+    where it lies: a 2-D aligned float32 / float64 ndarray of native byte
+    order, any strides, and the library loaded."""
     return (isinstance(data, np.ndarray) and data.ndim == 2
             and data.dtype in (np.dtype(np.float32), np.dtype(np.float64))
             and data.flags.aligned
             and all(s % data.itemsize == 0 for s in data.strides)
             and _load() is not None)
+
+
+class FoundBins(NamedTuple):
+    """One numerical column's bins as `BinMapper.find_bin` finds them
+    before its tail (io/binning.py `BinMapper.from_bins` finishes it)."""
+    bounds: np.ndarray       # bin upper bounds, NaN's last under MISSING_NAN
+    cnt_in_bin: np.ndarray   # int64 sampled values a bin
+    missing_type: int
+    min_val: float
+    max_val: float
+
+
+def find_bins_native(sample: np.ndarray, cols: Sequence[int],
+                     max_bins: Sequence[int], min_data_in_bin: int,
+                     use_missing: bool, zero_as_missing: bool,
+                     num_threads: int = 0):
+    """The bins of columns `cols` of the dense construction sample, each
+    with its `max_bins` entry, in ONE native pass over the sample's
+    columns (binning.cpp lgbt_find_bins). Returns ([FoundBins] in the
+    order of `cols`, threads used). The caller checks
+    `row_pass_input(sample)` first."""
+    k, n = len(cols), sample.shape[0]
+    col = np.asarray(cols, dtype=np.int32)
+    mb = np.asarray(max_bins, dtype=np.int32)
+    if (len(mb) != k or np.any((col < 0) | (col >= sample.shape[1]))
+            or np.any(mb < 2)):
+        raise ValueError("find_bins_native: the columns do not fit the "
+                         "sample")
+    width = int(mb.max(initial=2))
+    bounds = np.empty((k, width), dtype=np.float64)
+    cnt = np.empty((k, width), dtype=np.int64)
+    info = np.empty((k, 2), dtype=np.int32)
+    span = np.empty((k, 2), dtype=np.float64)
+    threads = _load().lgbt_find_bins(
+        sample.ctypes.data, int(sample.dtype == np.float64), n,
+        sample.strides[0] // sample.itemsize,
+        sample.strides[1] // sample.itemsize, k,
+        col.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+        mb.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+        int(min_data_in_bin), int(bool(use_missing)),
+        int(bool(zero_as_missing)), width,
+        bounds.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
+        cnt.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
+        info.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+        span.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
+        int(num_threads))
+    found = [FoundBins(bounds[j, :nb].copy(), cnt[j, :nb].copy(), int(mt),
+                       float(span[j, 0]), float(span[j, 1]))
+             for j, (nb, mt) in enumerate(info)]
+    return found, threads
 
 
 # a row-pass feature's place in its code column (binning.cpp enum Mode):

@@ -14,12 +14,21 @@
 // written row-major, blocks split over OpenMP threads. Its codes equal
 // the per-column path's byte for byte (tests/test_native.py).
 //
+// lgbt_find_bins finds the bins of every dense numerical column of the
+// construction sample in one pass (io/dataset.py::_find_bin_mappers_local):
+// blocks of columns over OpenMP threads, each block's values read row by
+// row so that a fetched cache line serves all of its columns, then per
+// column what io/binning.py BinMapper.find_bin does before its tail: the
+// sort, the run-merge, the zero pseudo-value, the bounds and the per-bin
+// counts. Its mappers equal find_bin's to the bit (tests/test_native.py).
+//
 // Built on demand by lightgbm_tpu/native/__init__.py:
 //   g++ -O3 -std=c++17 -fopenmp -shared -fPIC binning.cpp -o _native.so
 
 #include <algorithm>
 #include <cmath>
 #include <cstdint>
+#include <cstring>
 #include <limits>
 #include <vector>
 
@@ -333,6 +342,278 @@ int lgbt_bin_rows(const void* data, int32_t is_f64, int64_t n,
       : bin_rows(in, n, row_stride, col_stride, k, meta, bounds, width,
                  table, static_cast<uint8_t*>(out), out_cols, inf_counts,
                  num_threads);
+}
+
+}  // extern "C"
+
+namespace {
+
+constexpr double kZeroThreshold = 1e-35;  // io/binning.py K_ZERO_THRESHOLD
+
+enum Missing { kMissingNone = 0, kMissingZero = 1, kMissingNan = 2 };
+
+// io/binning.py find_bin_with_zero_as_one_bin: the negatives and the
+// positives of the sorted distinct values dv (counts cnt) searched by the
+// greedy walk apart, the bins shared by their counts, one bin about zero
+// between them. Writes at most max_bin bounds into out; returns how many.
+int zero_as_one_bin(const double* dv, const int64_t* cnt, int64_t nd,
+                    int max_bin, int64_t total, int min_data_in_bin,
+                    double* out) {
+  int64_t left_data = 0, zero_data = 0, right_data = 0, left_cnt = nd;
+  for (int64_t i = 0; i < nd; ++i) {
+    if (dv[i] <= -kZeroThreshold) {
+      left_data += cnt[i];
+      continue;
+    }
+    if (left_cnt == nd) left_cnt = i;
+    (dv[i] > kZeroThreshold ? right_data : zero_data) += cnt[i];
+  }
+  int n = 0;
+  if (left_cnt > 0 && max_bin > 1) {
+    const double share =
+        static_cast<double>(left_data) /
+        static_cast<double>(std::max<int64_t>(total - zero_data, 1));
+    const int left_max_bin =
+        std::max(1, static_cast<int>(share * (max_bin - 1)));
+    n = lgbt_greedy_find_bin(dv, cnt, left_cnt, left_max_bin, left_data,
+                             min_data_in_bin, out);
+    out[n - 1] = -kZeroThreshold;
+  }
+  int64_t right_start = left_cnt;
+  while (right_start < nd && !(dv[right_start] > kZeroThreshold)) {
+    ++right_start;
+  }
+  const int right_max_bin = max_bin - 1 - n;
+  if (right_start < nd && right_max_bin > 0) {
+    out[n++] = kZeroThreshold;
+    n += lgbt_greedy_find_bin(dv + right_start, cnt + right_start,
+                              nd - right_start, right_max_bin, right_data,
+                              min_data_in_bin, out + n);
+  } else {
+    out[n++] = std::numeric_limits<double>::infinity();
+  }
+  return n;
+}
+
+// A double's bits as a uint64 of the same order. For x neither NaN nor
+// +-0, the key of nextafter(x, +inf) is the key of x plus one.
+inline uint64_t ordered_key(double x) {
+  uint64_t u;
+  std::memcpy(&u, &x, sizeof u);
+  return (u >> 63) ? ~u : u | (uint64_t{1} << 63);
+}
+
+inline double key_value(uint64_t k) {
+  const uint64_t u = (k >> 63) ? k & ~(uint64_t{1} << 63) : ~k;
+  double x;
+  std::memcpy(&x, &u, sizeof x);
+  return x;
+}
+
+constexpr int kBits = 11, kDigits = 6, kRadix = 1 << kBits;
+
+// LSD radix sort of a[0, n) by 11-bit digits through tmp[n] and
+// hist[kDigits * kRadix]; a digit that every key shares takes no pass.
+void sort_keys(uint64_t* a, uint64_t* tmp, int64_t n, int64_t* hist) {
+  std::fill(hist, hist + kDigits * kRadix, 0);
+  for (int64_t i = 0; i < n; ++i) {
+    for (int d = 0; d < kDigits; ++d) {
+      ++hist[d * kRadix + ((a[i] >> (kBits * d)) & (kRadix - 1))];
+    }
+  }
+  uint64_t *src = a, *dst = tmp;
+  for (int d = 0; d < kDigits && n > 0; ++d) {
+    int64_t* h = hist + d * kRadix;
+    if (h[(src[0] >> (kBits * d)) & (kRadix - 1)] == n) continue;
+    int64_t sum = 0;
+    for (int b = 0; b < kRadix; ++b) {
+      const int64_t c = h[b];
+      h[b] = sum;
+      sum += c;
+    }
+    for (int64_t i = 0; i < n; ++i) {
+      dst[h[(src[i] >> (kBits * d)) & (kRadix - 1)]++] = src[i];
+    }
+    std::swap(src, dst);
+  }
+  if (src != a) std::copy(src, src + n, a);
+}
+
+// One numerical column as BinMapper.find_bin (io/binning.py) finds it,
+// from the ordered keys k[0, nk) of its values of |x| > kZeroThreshold
+// (NaN left out and counted in nan_cnt) among `total` sampled rows.
+// Sorts k in place through tmp[nk] and hist; dv and dc are scratch.
+// Writes the bounds and each bin's count (at most max_bin each), the
+// number of bins and the missing type (info[0], info[1]), the least and
+// the largest distinct value (range[0], range[1]).
+void find_column(uint64_t* k, uint64_t* tmp, int64_t* hist, int64_t nk,
+                 int64_t nan_cnt, int64_t total, int max_bin,
+                 int min_data_in_bin, int use_missing, int zero_as_missing,
+                 std::vector<double>& dv, std::vector<int64_t>& dc,
+                 double* bounds, int64_t* cnt_in_bin, int32_t* info,
+                 double* range) {
+  int missing = !use_missing      ? kMissingNone
+                : zero_as_missing ? kMissingZero
+                : nan_cnt > 0     ? kMissingNan
+                                  : kMissingNone;
+  const int64_t na_cnt = missing == kMissingNan ? nan_cnt : 0;
+  const int64_t zero_cnt = total - nk - na_cnt;
+  sort_keys(k, tmp, nk, hist);
+  // a value within one ulp of the one before (x > nextafter(prev, +inf)
+  // is false) joins its run, and the run keeps its largest value
+  dv.clear();
+  dc.clear();
+  for (int64_t i = 0; i < nk; ++i) {
+    if (i == 0 || k[i] > k[i - 1] + 1) {
+      dv.push_back(key_value(k[i]));
+      dc.push_back(1);
+    } else {
+      dv.back() = key_value(k[i]);
+      ++dc.back();
+    }
+  }
+  // the zero pseudo-value: in the middle always, at either end only if
+  // some sampled value is zero
+  if (dv.empty()) {
+    dv.push_back(0.0);
+    dc.push_back(zero_cnt);
+  } else {
+    const int64_t pos =
+        std::lower_bound(dv.begin(), dv.end(), 0.0) - dv.begin();
+    const int64_t nd = static_cast<int64_t>(dv.size());
+    bool insert = true;
+    if (pos < nd && dv[pos] == 0.0) {
+      insert = false;
+    } else if (pos == 0 || pos == nd) {
+      insert = zero_cnt > 0;
+    }
+    if (insert) {
+      dv.insert(dv.begin() + pos, 0.0);
+      dc.insert(dc.begin() + pos, zero_cnt);
+    }
+  }
+  range[0] = dv.front();
+  range[1] = dv.back();
+  const int64_t nd = static_cast<int64_t>(dv.size());
+  int nb;
+  if (missing == kMissingNan) {  // the last bin is NaN's
+    nb = zero_as_one_bin(dv.data(), dc.data(), nd, max_bin - 1,
+                         total - na_cnt, min_data_in_bin, bounds);
+    bounds[nb++] = std::numeric_limits<double>::quiet_NaN();
+  } else {
+    nb = zero_as_one_bin(dv.data(), dc.data(), nd, max_bin, total,
+                         min_data_in_bin, bounds);
+    if (missing == kMissingZero && nb == 2) missing = kMissingNone;
+  }
+  // each distinct value's count to the first bound at or above it
+  const int n_search = nb - (missing == kMissingNan ? 1 : 0);
+  std::fill(cnt_in_bin, cnt_in_bin + nb, 0);
+  int j = 0;
+  for (int64_t i = 0; i < nd; ++i) {
+    while (j + 1 < n_search && bounds[j] < dv[i]) ++j;
+    cnt_in_bin[j] += dc[i];
+  }
+  if (missing == kMissingNan) cnt_in_bin[nb - 1] = na_cnt;
+  info[0] = nb;
+  info[1] = missing;
+}
+
+template <typename In>
+int find_bins(const In* data, int64_t n, int64_t row_stride,
+              int64_t col_stride, int32_t k, const int32_t* cols,
+              const int32_t* max_bins, int min_data_in_bin, int use_missing,
+              int zero_as_missing, int32_t width, double* bounds,
+              int64_t* cnt_in_bin, int32_t* info, double* range,
+              int num_threads) {
+  // up to eight columns a block (a row of a C-ordered block is one cache
+  // line), fewer where there are fewer than eight a thread, and no more
+  // than 2^22 keys (32 MB) a thread where the sample is tall
+  constexpr int32_t kBlock = 8;
+  int used = 1;
+#ifdef _OPENMP
+  if (num_threads <= 0) num_threads = omp_get_max_threads();
+#endif
+  const int32_t per_thread = (k + std::max(num_threads, 1) - 1) /
+                             std::max(num_threads, 1);
+  const int32_t block = static_cast<int32_t>(std::max<int64_t>(
+      1, std::min<int64_t>({kBlock, per_thread,
+                            (int64_t{1} << 22) / std::max<int64_t>(n, 1)})));
+  const int32_t blocks = (k + block - 1) / block;
+#ifdef _OPENMP
+#pragma omp parallel num_threads(num_threads)
+#endif
+  {
+    std::vector<uint64_t> keys(static_cast<size_t>(block) * n), tmp(n);
+    std::vector<int64_t> hist(kDigits * kRadix);
+    std::vector<double> dv;
+    std::vector<int64_t> dc;
+    dv.reserve(n + 1);
+    dc.reserve(n + 1);
+#ifdef _OPENMP
+#pragma omp single
+    used = omp_get_num_threads();
+#pragma omp for schedule(dynamic, 1)
+#endif
+    for (int32_t blk = 0; blk < blocks; ++blk) {
+      const int32_t c0 = blk * block, w = std::min(block, k - c0);
+      const In* col[kBlock];
+      int64_t nv[kBlock], nan_cnt[kBlock];
+      for (int32_t l = 0; l < w; ++l) {
+        col[l] = data + cols[c0 + l] * col_stride;
+        nv[l] = nan_cnt[l] = 0;
+      }
+      for (int64_t r = 0; r < n; ++r) {
+        for (int32_t l = 0; l < w; ++l) {
+          const double x = static_cast<double>(col[l][r * row_stride]);
+          if (std::isnan(x)) {
+            ++nan_cnt[l];
+          } else if (std::fabs(x) > kZeroThreshold) {
+            keys[l * n + nv[l]++] = ordered_key(x);
+          }
+        }
+      }
+      for (int32_t l = 0; l < w; ++l) {
+        const int64_t c = c0 + l;
+        find_column(keys.data() + l * n, tmp.data(), hist.data(), nv[l],
+                    nan_cnt[l], n, max_bins[c], min_data_in_bin, use_missing, zero_as_missing, dv, dc,
+                    bounds + c * width, cnt_in_bin + c * width, info + c * 2,
+                    range + c * 2);
+      }
+    }
+  }
+  return used;
+}
+
+}  // namespace
+
+extern "C" {
+
+// The bins of columns cols[k] of a dense float32 (is_f64 == 0) or float64
+// sample of n rows (strides in elements), each as BinMapper.find_bin
+// finds a numerical column's with max_bins[j] bins, blocks of columns
+// over `num_threads` OpenMP threads (<= 0: the OpenMP default). Column j
+// gets its bounds and per-bin counts in row j of bounds / cnt_in_bin
+// ([k, width], width >= every max_bins[j]), its number of bins and
+// missing type in info[2 j], info[2 j + 1], its least and largest
+// distinct value in range[2 j], range[2 j + 1]. Returns the number of
+// threads that ran.
+int lgbt_find_bins(const void* data, int32_t is_f64, int64_t n,
+                   int64_t row_stride, int64_t col_stride, int32_t k,
+                   const int32_t* cols, const int32_t* max_bins,
+                   int32_t min_data_in_bin, int32_t use_missing,
+                   int32_t zero_as_missing, int32_t width, double* bounds,
+                   int64_t* cnt_in_bin, int32_t* info, double* range,
+                   int num_threads) {
+  if (is_f64) {
+    return find_bins(static_cast<const double*>(data), n, row_stride,
+                     col_stride, k, cols, max_bins, min_data_in_bin,
+                     use_missing, zero_as_missing, width, bounds, cnt_in_bin,
+                     info, range, num_threads);
+  }
+  return find_bins(static_cast<const float*>(data), n, row_stride,
+                   col_stride, k, cols, max_bins, min_data_in_bin,
+                   use_missing, zero_as_missing, width, bounds, cnt_in_bin,
+                   info, range, num_threads);
 }
 
 }  // extern "C"

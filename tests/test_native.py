@@ -337,3 +337,202 @@ def test_validation_set_codes_equal_through_either_path(monkeypatch):
                        params=params).construct()._handle.bins
     assert train._handle.bundles is not None
     np.testing.assert_array_equal(got, want)
+
+
+# --- the bin-finding pass (lgbt_find_bins) against BinMapper.find_bin ---
+
+_MAPPER_FIELDS = ("num_bin", "missing_type", "default_bin", "most_freq_bin",
+                  "sparse_rate", "is_trivial", "min_val", "max_val",
+                  "bin_type", "categorical_2_bin", "bin_2_categorical")
+
+
+def _python_bins(monkeypatch):
+    """Send this process to the pure-Python paths, as
+    LIGHTGBM_TPU_NO_NATIVE does at start-up."""
+    import lightgbm_tpu.native as native
+    monkeypatch.setenv("LIGHTGBM_TPU_NO_NATIVE", "1")
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_tried", False)
+
+
+def _bin_table(kind, seed=0):
+    """(sample, categorical columns). "edges": a column with NaN, one
+    without, zero-heavy with NaN, constant, all NaN, all negative, all
+    positive, few distinct values (<= 15), values within the zero
+    threshold, values one and three ulps apart, ties around zero, a +-inf
+    column, a categorical one and one whose most frequent value is a
+    bin's upper bound. "distinct": 200,000 distinct values,
+    alone and with NaN."""
+    from lightgbm_tpu.io.binning import K_ZERO_THRESHOLD
+    rng = np.random.RandomState(seed)
+    if kind == "distinct":
+        n = 200_000
+        X = np.empty((n, 2))
+        X[:, 0] = rng.randn(n) * 3
+        X[:, 1] = rng.randn(n)
+        X[rng.rand(n) < 0.02, 1] = np.nan
+        assert len(np.unique(X[:, 0])) == n
+        return X, set()
+    n = 3000
+    X = np.empty((n, 15))
+    X[:, 0] = rng.randn(n)
+    X[rng.rand(n) < 0.05, 0] = np.nan
+    X[:, 1] = rng.randn(n) * 3
+    X[:, 2] = np.where(rng.rand(n) < 0.6, 0.0, rng.randn(n))
+    X[rng.rand(n) < 0.05, 2] = np.nan
+    X[:, 3] = 7.0
+    X[:, 4] = np.nan
+    X[:, 5] = -np.abs(rng.randn(n)) - 0.01
+    X[:, 6] = np.abs(rng.randn(n)) + 0.01
+    X[:, 7] = rng.randint(0, 12, n) - 4.0
+    X[:, 8] = rng.choice([0.0, -0.0, K_ZERO_THRESHOLD, -K_ZERO_THRESHOLD,
+                          np.nextafter(K_ZERO_THRESHOLD, 1), 2e-35,
+                          -3e-35, 1.0], n)
+    # x, x + 1 ulp (joins x's run) and x + 3 ulps: a run of its own, and
+    # the bound between the two runs is that value itself
+    up = np.nextafter
+    x = rng.choice([-1.5, 0.25, 2.0], n)
+    X[:, 9] = np.choose(rng.randint(0, 3, n),
+                        [x, up(x, np.inf), up(up(up(x, 9), 9), 9)])
+    X[:, 10] = rng.randint(-3, 4, n) * 1e-3
+    X[:, 11] = rng.randn(n)
+    X[rng.rand(n) < 0.01, 11] = np.inf
+    X[rng.rand(n) < 0.01, 11] = -np.inf
+    X[:, 12] = rng.randint(0, 9, n)
+    X[:, 13] = rng.randn(n) * 1e6
+    X[rng.rand(n) < 0.5, 13] = 0.0
+    # most of the column at a value that is its bin's upper bound
+    X[:, 14] = np.where(rng.rand(n) < 0.85, up(up(up(2.0, 9), 9), 9),
+                        up(2.0, 9))
+    return X, {12}
+
+
+def _mappers(sample, config, cat_set):
+    from lightgbm_tpu import obs
+    from lightgbm_tpu.io.dataset import BinnedDataset
+    n, cols = sample.shape
+
+    def col_nonzeros(f):
+        return np.arange(n), np.asarray(sample[:, f], dtype=np.float64)
+
+    reg = obs.activate(obs.MetricsRegistry())
+    try:
+        mappers = BinnedDataset._find_bin_mappers_local(
+            sample, col_nonzeros, cols, n, config, cat_set)
+    finally:
+        obs.deactivate(reg)
+    return mappers, reg.counters
+
+
+@pytest.mark.parametrize("pre_filter", [True, False])
+@pytest.mark.parametrize("min_data_in_bin", [1, 3])
+@pytest.mark.parametrize("max_bin", [15, 63, 255, 300, "by_feature"])
+@pytest.mark.parametrize("missing", ["nan", "use_missing_false",
+                                     "zero_as_missing"])
+@pytest.mark.parametrize("table", ["edges", "distinct"])
+def test_find_bins_native_equals_find_bin(monkeypatch, table, missing,
+                                          max_bin, min_data_in_bin,
+                                          pre_filter):
+    """The native pass gives `BinMapper.find_bin`'s mappers to the bit,
+    every field, over missing-value handling, bin budgets (per feature
+    too), `min_data_in_bin`, the pre-filter, float32 / float64 and C /
+    Fortran / strided input at 1 and 4 threads; the categorical column
+    stays on the Python path."""
+    from lightgbm_tpu.config import Config
+    import lightgbm_tpu.native as native
+
+    if native._load() is None:
+        pytest.skip("no native toolchain")
+    X, cat_set = _bin_table(table)
+    params = {"min_data_in_bin": min_data_in_bin, "min_data_in_leaf": 20,
+              "feature_pre_filter": pre_filter,
+              "zero_as_missing": missing == "zero_as_missing",
+              "use_missing": missing != "use_missing_false"}
+    if max_bin == "by_feature":
+        params["max_bin_by_feature"] = [
+            (2, 300, 15, 63, 255, 4, 1)[f % 7] for f in range(X.shape[1])]
+    else:
+        params["max_bin"] = max_bin
+    config = Config.from_params(params)
+    with monkeypatch.context() as mp:
+        _python_bins(mp)
+        want, counted = _mappers(X, config, cat_set)
+    assert counted["dataset.find_bins_native_cols"] == 0
+    F = X.shape[1]
+    by_feature = max_bin == "by_feature"
+    n_native = sum(1 for f in range(F) if f not in cat_set and not (
+        by_feature and params["max_bin_by_feature"][f] < 2))
+    if table == "edges":
+        X32 = X.astype(np.float32)
+        inputs = {f"{dt}/{name}": data
+                  for dt, T in (("f64", X), ("f32", X32))
+                  for name, data in _layouts(T).items()}
+        # float32 input is held to the oracle on the same float32 values
+        oracle = {"f64": want}
+        with monkeypatch.context() as mp:
+            _python_bins(mp)
+            oracle["f32"] = _mappers(X32.astype(np.float64), config,
+                                     cat_set)[0]
+    else:
+        inputs = {"f64/" + k: v for k, v in _layouts(X).items()
+                  if k in ("C", "F")}
+        oracle = {"f64": want}
+    for name, data in inputs.items():
+        for threads in (1, 4):
+            config.num_threads = threads
+            got, counted = _mappers(data, config, cat_set)
+            assert counted["dataset.find_bins_native_cols"] == n_native
+            assert counted["dataset.find_bins_python_cols"] == F - n_native
+            for f, (g, w) in enumerate(zip(got, oracle[name[:3]])):
+                where = f"{name}, {threads} threads, column {f}"
+                assert g.bin_upper_bound.dtype == np.float64, where
+                assert g.bin_upper_bound.tobytes() == \
+                    w.bin_upper_bound.tobytes(), where
+                for k in _MAPPER_FIELDS:
+                    a, b = getattr(g, k), getattr(w, k)
+                    assert type(a) is type(b) and a == b, (where, k, a, b)
+
+
+def test_from_matrix_finds_bins_natively_and_trains_the_same_model(
+        monkeypatch):
+    """A dense `from_matrix` counts its numerical columns on
+    `dataset.find_bins_native_cols` and says so on its Info line, and
+    the model it trains is byte-identical to the Python path's, with
+    NaN and a categorical column in the table."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu import obs
+    from lightgbm_tpu.utils import log
+    import lightgbm_tpu.native as native
+
+    if native._load() is None:
+        pytest.skip("no native toolchain")
+    X = _train_table()
+    y = (X[:, 1] + np.nan_to_num(X[:, 0]) > 0).astype(np.float64)
+    params = {"objective": "binary", "categorical_feature": "4",
+              "num_leaves": 7, "num_threads": 2}
+
+    def train():
+        lines = []
+        log.register_log_callback(lines.append)
+        reg = obs.activate(obs.MetricsRegistry())
+        try:
+            bst = lgb.train(params, lgb.Dataset(X, label=y),
+                            num_boost_round=3)
+        finally:
+            obs.deactivate(reg)
+            log.register_log_callback(None)
+        said = [ln for ln in lines if "Host bin finding" in ln]
+        return bst.model_to_string(), reg.counters, said
+
+    model, counted, said = train()
+    assert counted["dataset.find_bins_native_cols"] == 8
+    assert counted["dataset.find_bins_python_cols"] == 1
+    assert said == ["[LightGBM-TPU] [Info] Host bin finding: columns in "
+                    "one native pass (2 threads, 8 of 9 columns)\n"]
+    with monkeypatch.context() as mp:
+        _python_bins(mp)
+        want, counted, said = train()
+    assert counted["dataset.find_bins_native_cols"] == 0
+    assert counted["dataset.find_bins_python_cols"] == 9
+    assert said == []
+    assert model == want
